@@ -76,6 +76,7 @@ from repro.assembly import mesh as amesh
 from repro.assembly.conflict import color_elements, verify_element_coloring
 from repro.roofline import cost_model
 from repro.kernels import ref, ops
+from repro.runtime import enable_compile_cache
 from benchmarks.util import steady_state, time_fn, row
 from benchmarks.suite import matrices
 
@@ -131,7 +132,7 @@ _TABLE2_CODE = """
     import numpy as np, jax, jax.numpy as jnp, time
     from repro.core import csrc, distributed as D
     from benchmarks.util import time_fn
-    mesh = jax.make_mesh((8,), ('rows',))
+    mesh = D.make_mesh(8)
     # in-cache vs out-of-cache analogs (paper splits at ws ~ cache size)
     cases = [('small_ws', 4096, 16), ('large_ws', 200000, 16)]
     rng = np.random.default_rng(0)
@@ -147,21 +148,32 @@ _TABLE2_CODE = """
 """
 
 
+def _cpu_child(code: str, timeout: int = 900) -> str:
+    """Run ``code`` in a child pinned to the CPU backend with 8 forced
+    host devices.  This parent has already touched JAX and, on a TPU
+    host, holds the chip: a child that asked for it would fail or hang.
+    So the forced-device sections measure the CPU, and say so."""
+    print("# (child process: JAX_PLATFORMS=cpu, 8 forced host devices — "
+          "CPU timings, not a device metric)")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src") + ":" + ROOT
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env,
+                         timeout=timeout)
+    if out.returncode != 0:
+        raise RuntimeError(out.stderr[-2000:])
+    return out.stdout.strip()
+
+
 def table2_accumulation(small: bool):
     print("# table2_accumulation: strategy cost on 8 shards "
           "(all-in-one=allreduce, interval=reduce_scatter, effective=halo)")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src") + ":" + ROOT
     code = _TABLE2_CODE
     if small:
         code = code.replace("200000", "20000")
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         capture_output=True, text=True, env=env,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    print(out.stdout.strip())
+    print(_cpu_child(code))
 
 
 # ---------------------------------------------------------------------------
@@ -204,7 +216,7 @@ _FIG89_CODE = """
     t1 = time_fn(seq, x)
     print(f'fig89/p1/sequential,{t1*1e6:.1f},speedup=1.00')
     for p in (2, 4, 8):
-        mesh = jax.make_mesh((p,), ('rows',))
+        mesh = D.make_mesh(p)
         fn = D.build_sharded_spmv(M, mesh, 'rows', 'halo')
         t = time_fn(fn, x)
         print(f'fig89/p{p}/halo,{t*1e6:.1f},speedup={t1/t:.2f}')
@@ -213,16 +225,8 @@ _FIG89_CODE = """
 
 def fig89_scaling(small: bool):
     print("# fig89_scaling: speedup vs shards (halo strategy, band FEM)")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src") + ":" + ROOT
-    code = _FIG89_CODE.replace("NN", "40000" if small else "400000")
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         capture_output=True, text=True, env=env,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    print(out.stdout.strip())
+    print(_cpu_child(_FIG89_CODE.replace("NN", "40000" if small
+                                         else "400000")))
 
 
 # ---------------------------------------------------------------------------
@@ -552,11 +556,12 @@ def assembly(small: bool):
             times[key] = t
             match[key] = bool(np.array_equal(vals, ref))
             est = cost_model.assembly_cost(sched, strategy, variant)
-            frac = cost_model.roofline_fraction(est, t)
+            frac = cost_model.roofline_fraction(
+                est, t, jax.devices()[0].device_kind)
             row(f"assembly/{name}/{strategy}_{variant}", t * 1e6,
                 f"build_us={t_build*1e6:.1f};ne={sched.ne};"
                 f"colors={col.num_colors};matches_serial={match[key]};"
-                f"roofline_fraction={frac:.2e}")
+                f"roofline_fraction={frac}")
             records.append({
                 "mesh": name, "ne": sched.ne, "n": sched.n,
                 "k": sched.k, "colors": int(col.num_colors),
@@ -699,17 +704,9 @@ def serving(small: bool):
     serving-smoke job asserts the mesh rows exist."""
     print("# serving: local vs mesh engines (build vs steady-state, "
           "8 shards)")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src") + ":" + ROOT
     os.makedirs(os.path.dirname(BENCH_SERVING_PATH), exist_ok=True)
-    code = _SERVING_CODE % {"out": BENCH_SERVING_PATH, "small": small}
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         capture_output=True, text=True, env=env,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    print(out.stdout.strip())
+    print(_cpu_child(_SERVING_CODE % {"out": BENCH_SERVING_PATH,
+                                      "small": small}))
 
 
 # ---------------------------------------------------------------------------
@@ -764,7 +761,8 @@ def local_gap(small: bool):
                 t = time_fn(op, x, warmup=2, repeats=5)
                 t_mm = time_fn(op, X, warmup=2, repeats=5)
                 est = cost_model.plan_cost(stats, plan)
-                frac = cost_model.roofline_fraction(est, t)
+                frac = cost_model.roofline_fraction(
+                    est, t, jax.devices()[0].device_kind)
                 per_variant[variant] = {
                     "plan": plan.key(),
                     "spmv_us": round(t * 1e6, 1),
@@ -775,7 +773,7 @@ def local_gap(small: bool):
                 }
                 row(f"local_gap/{name}/{path}/{variant}", t * 1e6,
                     f"spmm8_us={t_mm * 1e6:.1f};bound={est.bound};"
-                    f"roofline_fraction={frac:.3e}")
+                    f"roofline_fraction={frac}")
             if {"onehot", "stream"} <= set(per_variant):
                 oh, st = per_variant["onehot"], per_variant["stream"]
                 by_path[path] = {
@@ -908,6 +906,7 @@ def main() -> None:
                          "results/plans.json, then exit")
     ap.add_argument("--only", default=None)
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
     if args.tune:
         pretune(args.quick)
         return

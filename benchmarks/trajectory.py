@@ -79,7 +79,9 @@ def measure_point(quick: bool = False) -> Dict:
     import numpy as np
     from benchmarks.util import steady_state
     from repro import obs
+    import jax
     from repro.core import csrc, tuner
+    from repro.roofline import cost_model
     from repro.serve import SpmvServingEngine
 
     n, hb = (2000, 8) if quick else (8000, 16)
@@ -88,16 +90,18 @@ def measure_point(quick: bool = False) -> Dict:
     snap0 = obs.snapshot()
 
     res = tuner.tune(M, cache=cache, repeats=2 if quick else 3)
-    # per-path achieved-roofline fraction: best measured plan per path
+    # per-path achieved-roofline fraction: best measured plan per path,
+    # only on a device kind with a peak row (none for the CPU host)
     frac_by_path: Dict[str, float] = {}
-    for key, t in res.timings_s.items():
-        pred = res.predictions_s.get(key)
-        if not pred or t <= 0:
-            continue
-        path = key.split(":")[0]
-        frac = pred / t
-        if frac > frac_by_path.get(path, 0.0):
-            frac_by_path[path] = round(frac, 4)
+    if jax.devices()[0].device_kind in cost_model.DEVICE_PEAKS:
+        for key, t in res.timings_s.items():
+            pred = res.predictions_s.get(key)
+            if not pred or t <= 0:
+                continue
+            path = key.split(":")[0]
+            frac = pred / t
+            if frac > frac_by_path.get(path, 0.0):
+                frac_by_path[path] = round(frac, 4)
 
     eng = SpmvServingEngine(cache=cache)
     eng.register("traj", M)

@@ -58,6 +58,7 @@ from repro.core.paths import BUILD_COUNTS
 from repro import obs
 from repro.kernels import assembly_scatter as akern
 from repro.kernels.assembly_scatter import COLORED_VARIANTS  # noqa: F401
+from repro.runtime import jit_hoisted
 from .conflict import color_elements, element_dofs
 from .mesh import Mesh
 
@@ -422,7 +423,7 @@ def scatter_colored_percolor(sched: AssemblySchedule, ke) -> jnp.ndarray:
 
 
 def scatter_colored(sched: AssemblySchedule, ke, variant: str = "stream",
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret=None) -> jnp.ndarray:
     """Per-color batched conflict-free scatter-add: inside one color every
     target index is unique (no two elements share a DOF), so each color
     batch is a permutation write — the colorful path's execution
@@ -492,7 +493,7 @@ def values_to_csrc(sched: AssemblySchedule, vals) -> csrc.CSRC:
 
 def assemble(sched: AssemblySchedule, ke, strategy: str = "colored",
              variant: Optional[str] = None,
-             interpret: bool = True) -> csrc.CSRC:
+             interpret=None) -> csrc.CSRC:
     """Assemble the global CSRC matrix from per-element dense blocks
     ``ke`` of shape (ne, edof, edof) with the chosen accumulation
     strategy.
@@ -551,7 +552,8 @@ class AssemblyTuneResult:
     variant: str
     timings_s: Dict[str, float]        # "strategy/variant" -> measured s
     predictions_s: Dict[str, float]    # every priced candidate
-    roofline_fraction: Dict[str, float]  # predicted/measured, measured set
+    # measured set: least device time / measured (None off the peak table)
+    roofline_fraction: Dict[str, Optional[float]]
     cached: bool                       # True = PlanCache hit, nothing timed
 
     def key(self) -> str:
@@ -559,14 +561,15 @@ class AssemblyTuneResult:
 
 
 def _scatter_fn(sched: AssemblySchedule, strategy: str, variant: str):
-    """The jitted value-refresh executor of one candidate."""
+    """The jitted value-refresh executor of one candidate (the schedule's
+    packs are program arguments, not baked-in constants)."""
     if strategy == "colored":
-        return jax.jit(lambda k: scatter_colored(sched, k,
-                                                 variant=variant))
+        return jit_hoisted(lambda k: scatter_colored(sched, k,
+                                                     variant=variant))
     if strategy == "sorted":
-        return jax.jit(lambda k: scatter_sorted(sched, k))
+        return jit_hoisted(lambda k: scatter_sorted(sched, k))
     if strategy == "private":
-        return jax.jit(lambda k: scatter_private(sched, k))
+        return jit_hoisted(lambda k: scatter_private(sched, k))
     raise ValueError(f"no tunable executor for strategy {strategy!r}")
 
 
@@ -632,11 +635,13 @@ def tune_assembly(sched: AssemblySchedule, ke, cache=None,
                 outcome="measured").inc(len(timings))
 
     winner = min(timings, key=timings.get)
-    fractions = {key: cost_model.roofline_fraction(ests[key], t)
+    kind = jax.devices()[0].device_kind
+    fractions = {key: cost_model.roofline_fraction(ests[key], t, kind)
                  for key, t in timings.items() if t > 0}
     ws, wv = winner.split("/")
-    obs.gauge("assembly_roofline_fraction", strategy=ws,
-              variant=wv).set(fractions.get(winner, 0.0))
+    if fractions.get(winner) is not None:
+        obs.gauge("assembly_roofline_fraction", strategy=ws,
+                  variant=wv).set(fractions[winner])
 
     result = AssemblyTuneResult(
         strategy=ws, variant=wv, timings_s=timings,

@@ -114,14 +114,10 @@ def pack(M: CSRC, tm: int = 128, k_step: int = 1024,
     vals_u = np.zeros((nt, s), dtype=np.float32)
     col_local = np.full((nt, s), w_pad, dtype=np.int32)       # sentinel
     row_in_win = np.full((nt, s), w_pad - 1, dtype=np.int32)  # inert
-    # stable fill: slots are already row-major within each tile
-    order = np.argsort(tile_of_slot, kind="stable")
-    pos_in_tile = np.zeros_like(order)
-    fill = np.zeros(nt, dtype=np.int64)
-    for idx in order:
-        t = tile_of_slot[idx]
-        pos_in_tile[idx] = fill[t]
-        fill[t] += 1
+    # slots are row-major, so each tile's slots are consecutive: the
+    # position in the tile is the offset from the tile's first slot
+    first = np.searchsorted(tile_of_slot, np.arange(nt))
+    pos_in_tile = np.arange(tile_of_slot.size) - first[tile_of_slot]
     win_lo = (np.arange(nt) + 1) * tm - w_pad                 # original coords
     t_idx = tile_of_slot
     p_idx = pos_in_tile

@@ -53,11 +53,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-try:                                    # jax >= 0.6 top-level export
-    from jax import shard_map
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from .csrc import CSRC, bandwidth
 from .plan import ExecutionPlan
@@ -68,6 +65,15 @@ from .schedule import SpmvSchedule
 
 def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
+
+
+def make_mesh(p: int, axis: str = "rows", devices=None) -> Mesh:
+    """The 1-D mesh every builder here runs on.  Its axis is ``Auto``:
+    the builders place shard arrays with NamedSharding and constrain the
+    padded x inside jit, which ``Explicit`` axes (``jax.make_mesh``'s
+    default) refuse."""
+    return jax.make_mesh((p,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=devices)
 
 
 def _bc(v: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
@@ -100,7 +106,7 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
                          schedule: Optional[SpmvSchedule] = None,
                          cache=None,
                          plan: Optional[ExecutionPlan] = None,
-                         interpret: bool = True,
+                         interpret=None,
                          layout=None) -> Callable:
     """'allreduce' (all-in-one) and 'reduce_scatter' (per-buffer/interval)
     strategies.  x replicated, shape (n,) or (n, B); output replicated or
@@ -143,7 +149,7 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
         fs = (layout if layout is not None
               else schedule_mod.build_path_shards(M, part, req_plan,
                                                   cache=cache))
-        local_y = sup.local_fn(fs, M.n, interpret)
+        local_y = sup.local_fn(fs, M.n, interpret, req_plan.variant)
 
         def local(*args):
             x = args[-1]
@@ -172,18 +178,20 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
         in_specs = (P(axis, None),) * 5 + (P(),)
 
     # x is replicated (P() leaves trailing dims unsharded), so one
-    # shard_map serves both the (n,) and (n, B) forms.  check_rep is off
-    # on kernel-backed paths: shard_map has no replication rule for
-    # pallas_call.
+    # shard_map serves both the (n,) and (n, B) forms.  The varying-axis
+    # check is off on kernel-backed paths: pallas_call has no rule for it.
     fn = shard_map(
         local, mesh=mesh, in_specs=in_specs,
         out_specs=(P(axis) if scatter_output else P()),
-        check_rep=sup is None)
+        check_vma=sup is None)
 
-    @jax.jit
+    # the shard arrays are arguments, not constants baked into the program
+    run = jax.jit(lambda ops, x: fn(*ops, x))
+
     def apply(x):
-        return fn(*sharded, x)
+        return run(sharded, x)
 
+    apply.operands = sharded        # the placed shard arrays, for checks
     return apply
 
 
@@ -191,7 +199,7 @@ def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
                     schedule: Optional[SpmvSchedule] = None,
                     cache=None,
                     plan: Optional[ExecutionPlan] = None,
-                    interpret: bool = True,
+                    interpret=None,
                     layout=None) -> Callable:
     """'halo' (effective) strategy: x and y row-sharded; only band-width
     windows cross shard boundaries (two collective_permutes).
@@ -214,7 +222,7 @@ def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
         ns, h, n_local = sup.halo_dims(lay)
         n = M.n
         n_pad = ns * p
-        local_y = sup.local_fn(lay, n_local, interpret)
+        local_y = sup.local_fn(lay, n_local, interpret, plan.variant)
 
         def local(*args):
             x_own = args[-1]
@@ -262,26 +270,31 @@ def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
 
     def make_fn(two_d: bool):
         x_spec = P(axis, None) if two_d else P(axis)
-        # check_rep off on kernel-backed paths: shard_map has no
-        # replication rule for pallas_call
+        # varying-axis check off on kernel-backed paths: pallas_call has
+        # no rule for it
         return shard_map(
             local, mesh=mesh,
             in_specs=slot_specs + (x_spec,),
-            out_specs=x_spec, check_rep=sup is None)
+            out_specs=x_spec, check_vma=sup is None)
 
     fns = {False: make_fn(False), True: make_fn(True)}
 
+    # the shard arrays are arguments, not constants baked into the program
     @jax.jit
-    def apply(x):
+    def run(ops, x):
         two_d = x.ndim == 2
         pad = ((0, n_pad - n),) + ((0, 0),) * (x.ndim - 1)
         x_pad = jnp.pad(x, pad)
         spec = P(axis, None) if two_d else P(axis)
         x_pad = jax.lax.with_sharding_constraint(
             x_pad, jax.sharding.NamedSharding(mesh, spec))
-        y = fns[two_d](*sharded, x_pad)
+        y = fns[two_d](*ops, x_pad)
         return y[:n]
 
+    def apply(x):
+        return run(sharded, x)
+
+    apply.operands = sharded        # the placed shard arrays, for checks
     return apply
 
 
@@ -293,7 +306,7 @@ def build_sharded_spmv(M: CSRC, mesh: Mesh, axis: str = "rows",
                        schedule: Optional[SpmvSchedule] = None,
                        cache=None,
                        plan: Optional[ExecutionPlan] = None,
-                       interpret: bool = True,
+                       interpret=None,
                        layout=None) -> Callable:
     """Factory: y_fn(x) computing A·x (or A·X for (n, B) blocks) across the
     mesh axis.  ``schedule``/``cache`` reuse the precomputed artifact; with

@@ -137,11 +137,11 @@ class CandidateSpace:
     # nnz-split enumerator sweeps: small chunks bound the per-chunk row
     # window, large chunks amortize the per-program overhead
     nnzsplit_ks: Tuple[int, ...] = (2, 8)
-    # kernel body variants the Pallas-path enumerators propose: 'stream'
-    # (per-lane gather + segment-sum, bandwidth-bound) and 'onehot' (MXU
-    # one-hot contraction fallback).  Both share one pack artifact —
-    # variant is not an artifact field — so proposing both costs no extra
-    # schedule builds.
+    # body variants the windowed / nnz-split enumerators propose: 'stream'
+    # (fused XLA gather + segment-sum, bandwidth-bound) and 'onehot' (the
+    # Pallas kernel's MXU one-hot contractions).  Both share one pack
+    # artifact — variant is not an artifact field — so proposing both
+    # costs no extra schedule builds.
     variants: Tuple[str, ...] = ("stream", "onehot")
     # coloring providers the colorful enumerator proposes (core/coloring):
     # 'greedy' sequential first-fit and 'race' recursive level-groups.
@@ -178,7 +178,9 @@ class ShardSupport:
       refresh_halo    (layout, M) -> value-refreshed layout
       shard_arrays    layout -> tuple of leading-axis-p device arrays
       shard_specs     axis name -> matching shard_map PartitionSpecs
-      local_fn        (layout, n_local, interpret) -> local product
+      local_fn        (layout, n_local, interpret, variant) -> local
+                      product: the path's one-hot Pallas kernel or its
+                      fused stream form
                       fn(*shard_arrays, x) -> y  (n_local rows)
       halo_dims       halo layout -> (ns, h, n_local)
     """
@@ -257,6 +259,21 @@ def _square_feasible(plan, *, n, m, bandwidth) -> bool:
     return n == m
 
 
+def _onehot_window_fits(variant: str, w: int) -> bool:
+    """The one-hot Pallas body keeps (W, 128) masks in VMEM: only windows
+    up to ``ONEHOT_MAX_WINDOW`` compile for the chip."""
+    from repro.kernels.csrc_spmv import ONEHOT_MAX_WINDOW
+    return variant != "onehot" or w <= ONEHOT_MAX_WINDOW
+
+
+def runs_pallas(plan) -> bool:
+    """Whether the plan's local product runs a Pallas kernel (the one-hot
+    variant of the windowed and nnz-split paths); every other form is
+    plain XLA."""
+    return (plan.path in ("kernel", "flat", "nnzsplit")
+            and plan.variant == "onehot")
+
+
 def _windowed_feasible(plan, *, n, m, bandwidth) -> bool:
     """Square matrix whose padded window fits under the plan's cap — the
     bandwidth gate shared by the rectangular-grid and flat-grid kernels.
@@ -265,7 +282,7 @@ def _windowed_feasible(plan, *, n, m, bandwidth) -> bool:
     if n != m:
         return False
     w = kernel_window(plan.tm, bandwidth)
-    if w > plan.w_cap:
+    if w > plan.w_cap or not _onehot_window_fits(plan.variant, w):
         return False
     return plan.index_dtype != "int16" or w + 1 <= 32767
 
@@ -310,6 +327,8 @@ def _windowed_candidates(path, stats, space):
                         # numerically-symmetric (well-conditioned) classes
                         continue
                     for var in space.variants:
+                        if not _onehot_window_fits(var, w):
+                            continue
                         out.append(ExecutionPlan(
                             path=path, tm=tm, w_cap=space.w_cap,
                             k_step_sublanes=ks, index_dtype=idt,
@@ -329,12 +348,12 @@ def _segment_candidates(stats, space):
                           accumulation=space.accumulation)]
 
 
-def _segment_make_spmv(M, schedule, plan, *, interpret=True, coloring=None):
+def _segment_make_spmv(M, schedule, plan, *, interpret=None, coloring=None):
     from repro.kernels import ref
     return lambda x: ref.csrc_spmv(M, x)
 
 
-def _segment_make_spmm(M, schedule, plan, *, interpret=True, coloring=None):
+def _segment_make_spmm(M, schedule, plan, *, interpret=None, coloring=None):
     from repro.kernels import ref
     return lambda X: ref.csrc_spmm(M, X)
 
@@ -423,26 +442,24 @@ def _kernel_refresh(M, sched) -> dict:
     return {"pack": blockell.refresh_values(sched.pack, M)}
 
 
-def _kernel_make_spmv(M, schedule, plan, *, interpret=True, coloring=None):
+def _kernel_make_spmv(M, schedule, plan, *, interpret=None, coloring=None):
     if plan.variant == "stream":
         from repro.kernels import csrc_spmv_stream as stream_mod
         return functools.partial(stream_mod.blockell_spmv_stream,
-                                 schedule.pack, interpret=interpret,
-                                 k_step_sublanes=plan.k_step_sublanes)
+                                 schedule.pack)
     from repro.kernels import csrc_spmv as kernel_mod
     return functools.partial(kernel_mod.blockell_spmv, schedule.pack,
                              interpret=interpret,
                              k_step_sublanes=plan.k_step_sublanes)
 
 
-def _kernel_make_spmm(M, schedule, plan, *, interpret=True, coloring=None):
+def _kernel_make_spmm(M, schedule, plan, *, interpret=None, coloring=None):
     if plan.variant == "stream":
         from repro.kernels import csrc_spmv_stream as stream_mod
         return functools.partial(stream_mod.blockell_spmm_stream,
-                                 schedule.pack, interpret=interpret,
-                                 k_step_sublanes=plan.k_step_sublanes)
-    from repro.kernels import csrc_spmm as kernel_mm_mod
-    return functools.partial(kernel_mm_mod.blockell_spmm, schedule.pack,
+                                 schedule.pack)
+    from repro.kernels import csrc_spmv as kernel_mod
+    return functools.partial(kernel_mod.blockell_spmm, schedule.pack,
                              interpret=interpret,
                              k_step_sublanes=plan.k_step_sublanes)
 
@@ -538,7 +555,7 @@ def _colorful_load(meta, z) -> dict:
     }
 
 
-def _colorful_make(M, schedule, plan, *, interpret=True, coloring=None):
+def _colorful_make(M, schedule, plan, *, interpret=None, coloring=None):
     from . import schedule as schedule_mod
     slots, ptr = schedule.color_slots, schedule.color_slot_ptr
     if coloring is not None and coloring is not schedule.coloring:
@@ -646,21 +663,21 @@ def _flat_refresh(M, sched) -> dict:
     return {"flat_pack": flat_mod.refresh_flat_values(sched.flat_pack, M)}
 
 
-def _flat_make_spmv(M, schedule, plan, *, interpret=True, coloring=None):
+def _flat_make_spmv(M, schedule, plan, *, interpret=None, coloring=None):
     if plan.variant == "stream":
         from repro.kernels import csrc_spmv_stream as stream_mod
         return functools.partial(stream_mod.flat_spmv_stream,
-                                 schedule.flat_pack, interpret=interpret)
+                                 schedule.flat_pack)
     from repro.kernels import csrc_spmv_flat as flat_mod
     return functools.partial(flat_mod.flat_spmv, schedule.flat_pack,
                              interpret=interpret)
 
 
-def _flat_make_spmm(M, schedule, plan, *, interpret=True, coloring=None):
+def _flat_make_spmm(M, schedule, plan, *, interpret=None, coloring=None):
     if plan.variant == "stream":
         from repro.kernels import csrc_spmv_stream as stream_mod
         return functools.partial(stream_mod.flat_spmm_stream,
-                                 schedule.flat_pack, interpret=interpret)
+                                 schedule.flat_pack)
     from repro.kernels import csrc_spmv_flat as flat_mod
     return functools.partial(flat_mod.flat_spmm, schedule.flat_pack,
                              interpret=interpret)
@@ -710,9 +727,9 @@ def _flat_shard_specs(axis):
     return flat_mod.flat_shard_specs(axis)
 
 
-def _flat_local_fn(lay, n_local, interpret):
+def _flat_local_fn(lay, n_local, interpret, variant):
     from repro.kernels import csrc_spmv_flat as flat_mod
-    return flat_mod.flat_local_fn(lay, n_local, interpret)
+    return flat_mod.flat_local_fn(lay, n_local, interpret, variant)
 
 
 def _flat_halo_dims(lay):
@@ -877,24 +894,32 @@ def _nnzsplit_refresh(M, sched) -> dict:
         sched.nnzsplit_pack, M)}
 
 
-def _nnzsplit_make_spmv(M, schedule, plan, *, interpret=True, coloring=None):
+def _nnzsplit_onehot_fits(pack, plan):
+    # the chunk row window is known only once packed; one that does not
+    # fit the one-hot body is a pack-time infeasibility, like w_cap
+    if not _onehot_window_fits(plan.variant, pack.r_pad):
+        raise ValueError(f"chunk row window {pack.r_pad} too wide for "
+                         "the one-hot kernel")
+
+
+def _nnzsplit_make_spmv(M, schedule, plan, *, interpret=None, coloring=None):
     if plan.variant == "stream":
         from repro.kernels import csrc_spmv_stream as stream_mod
         return functools.partial(stream_mod.nnzsplit_spmv_stream,
-                                 schedule.nnzsplit_pack,
-                                 interpret=interpret)
+                                 schedule.nnzsplit_pack)
     from repro.kernels import csrc_spmv_nnzsplit as nz_mod
+    _nnzsplit_onehot_fits(schedule.nnzsplit_pack, plan)
     return functools.partial(nz_mod.nnzsplit_spmv, schedule.nnzsplit_pack,
                              interpret=interpret)
 
 
-def _nnzsplit_make_spmm(M, schedule, plan, *, interpret=True, coloring=None):
+def _nnzsplit_make_spmm(M, schedule, plan, *, interpret=None, coloring=None):
     if plan.variant == "stream":
         from repro.kernels import csrc_spmv_stream as stream_mod
         return functools.partial(stream_mod.nnzsplit_spmm_stream,
-                                 schedule.nnzsplit_pack,
-                                 interpret=interpret)
+                                 schedule.nnzsplit_pack)
     from repro.kernels import csrc_spmv_nnzsplit as nz_mod
+    _nnzsplit_onehot_fits(schedule.nnzsplit_pack, plan)
     return functools.partial(nz_mod.nnzsplit_spmm, schedule.nnzsplit_pack,
                              interpret=interpret)
 
@@ -943,9 +968,9 @@ def _nnzsplit_shard_specs(axis):
     return nz_mod.nnzsplit_shard_specs(axis)
 
 
-def _nnzsplit_local_fn(lay, n_local, interpret):
+def _nnzsplit_local_fn(lay, n_local, interpret, variant):
     from repro.kernels import csrc_spmv_nnzsplit as nz_mod
-    return nz_mod.nnzsplit_local_fn(lay, n_local, interpret)
+    return nz_mod.nnzsplit_local_fn(lay, n_local, interpret, variant)
 
 
 def _nnzsplit_halo_dims(lay):

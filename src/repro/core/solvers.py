@@ -129,7 +129,7 @@ def bicgstab(spmv: Callable, b: jnp.ndarray,
 
 
 def cg_solve(M, b: jnp.ndarray, *, plan=None, cache=None,
-             autotune: bool = False, interpret: bool = True,
+             autotune: bool = False, interpret=None,
              x0: Optional[jnp.ndarray] = None, tol: float = 1e-6,
              maxiter: int = 1000, precondition: bool = True,
              **tune_kw) -> Tuple[SolveResult, object]:

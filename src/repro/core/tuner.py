@@ -703,7 +703,7 @@ def tune(M: CSRC,
          measure: Optional[Callable] = None,
          warmup: int = 1,
          repeats: int = 3,
-         interpret: bool = True,
+         interpret=None,
          save: bool = True,
          value_dtype_tol: float = VALUE_DTYPE_TOL,
          predict: bool = True,
@@ -726,8 +726,9 @@ def tune(M: CSRC,
     matrix), plus each distinct path's best-predicted candidate so a
     cross-path mispricing can never exclude a path from measurement.  The cache entry records ``predicted_us`` per ranked
     candidate plus the winner's ``predicted_ms`` / ``measured_ms`` /
-    ``roofline_fraction`` (fraction of the analytic roofline the
-    measured time achieved).  ``predict=False`` measures the full pool
+    ``roofline_fraction`` (least time the measuring device's peaks allow
+    over the measured time; ``None`` on a device kind without a peak
+    row).  ``predict=False`` measures the full pool
     (the oracle mode the pruned tuner is validated against in tests).
 
     Candidates with a reduced ``value_dtype`` must additionally match the
@@ -846,9 +847,14 @@ def tune(M: CSRC,
         roofline_entry: Optional[Dict[str, float]] = None
         est = est_by_key.get(best_plan.key())
         if est is not None and best_raw:
-            winner_frac = est.predicted_s / best_raw
-            obs.gauge("tuner_winner_roofline_fraction",
-                      path=best_plan.path).set(winner_frac)
+            import jax
+            from repro.roofline import cost_model
+            # None off the peak table: no share is made up for the CPU
+            winner_frac = cost_model.roofline_fraction(
+                est, best_raw, jax.devices()[0].device_kind)
+            if winner_frac is not None:
+                obs.gauge("tuner_winner_roofline_fraction",
+                          path=best_plan.path).set(winner_frac)
             roofline_entry = {
                 "predicted_ms": round(est.predicted_s * 1e3, 6),
                 "measured_ms": round(best_raw * 1e3, 6),
@@ -888,7 +894,7 @@ def tune_mesh(M: CSRC, p: int,
               measure: Optional[Callable] = None,
               warmup: int = 1,
               repeats: int = 3,
-              interpret: bool = True,
+              interpret=None,
               save: bool = True,
               nrhs_options=(1,)) -> TuneResult:
     """The mesh-aware tuning mode: measure distributed candidates on an
@@ -908,7 +914,7 @@ def tune_mesh(M: CSRC, p: int,
     makes the mode testable on one device with a 1-wide mesh.
     """
     import jax
-    from .distributed import build_sharded_spmv
+    from .distributed import build_sharded_spmv, make_mesh
 
     fp = mesh_fingerprint(fingerprint(M), p)
     if cache is not None:
@@ -924,7 +930,7 @@ def tune_mesh(M: CSRC, p: int,
                 f"mesh-aware tuning for p={p} needs {p} devices, this "
                 f"process sees {ndev}; relaunch with XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={p}")
-        mesh = jax.make_mesh((p,), (axis,))
+        mesh = make_mesh(p, axis)
 
     stats = stats_of(M)
     cands = (candidates if candidates is not None
@@ -1003,7 +1009,7 @@ def plan_for(M: CSRC,
 def mesh_plan_for(M: CSRC, p: int,
                   cache: Optional[PlanCache] = None,
                   autotune: bool = False,
-                  interpret: bool = True,
+                  interpret=None,
                   **tune_kw) -> ExecutionPlan:
     """The distributed plan to serve this matrix with on a p-way mesh.
 

@@ -7,20 +7,18 @@ behind in PR 7.  This module is the streaming formulation of the
 scatter-add ``vals[targets[g]] += ke.flat[g]``, consuming the
 per-color slot packs the AssemblySchedule precomputes:
 
-  colored-batch   one grid program per color class.  Within a color no
-                  two contributions share a target (the conflict-free
-                  coloring invariant), so a program's segment-sum is a
-                  permutation write; programs accumulate into the same
-                  revisited output block.  Two bodies, dispatched like
-                  the SpMV variants:
-                    stream   per-lane ``jnp.take`` gather of the
-                             contribution values + one ``segment_sum``
-                             over the target stream — O(1) work/slot,
-                             bandwidth-bound;
-                    onehot   targets realized as an (S, TS) one-hot
-                             mask contracted on the MXU per output tile
-                             — the Mosaic-safe compiled-TPU fallback,
-                             compute-bound by construction.
+  colored-batch   the per-color (C, Lmax) slot/target packs, in two
+                  variants dispatched like the SpMV variants:
+                    stream   one fused XLA gather of the contribution
+                             values + one ``segment_sum`` over the target
+                             stream — O(1) work/slot, bandwidth-bound.
+                             XLA on every backend: Mosaic lowers neither a
+                             1-D gather nor a scatter-add;
+                    onehot   the gather in XLA, then a Pallas grid over
+                             (output tile, contribution chunk) realizing
+                             the targets as a (TILE, CHUNK) one-hot mask
+                             contracted on the MXU — compute-bound by
+                             construction.
   sorted-slot     the arXiv:2012.00585 analogue: contributions are
                   pre-sorted by destination at schedule-build time, so
                   the whole assembly is ONE color-free gather +
@@ -31,15 +29,9 @@ Sentinel discipline (shared with csrc_spmv_stream): padded pack entries
 carry slot sentinel G (one past the last contribution — the gather reads
 an appended zero) and target sentinel ``size`` (one past the last real
 segment — the segment-sum drops it).  Index streams arrive int16 when
-the schedule's overflow gate allowed it and are upcast in-register.
-
-In interpret mode (the CPU backend of this repo's tests and benches) the
-emulated Pallas grid costs ~1 ms/step, so the stream variant evaluates
-the identical per-color computation as one fused XLA expression over all
-(color, slot) pairs — same slots summed into the same segments, so for
-dyadic element values the result is bit-identical to the in-grid bodies
-and to the serial ``np.add.at`` oracle (tests assert equality, not
-closeness).
+the schedule's overflow gate allowed it and are upcast before use.  For
+dyadic element values every executor is bit-identical to the serial
+``np.add.at`` oracle (tests assert equality, not closeness).
 """
 from __future__ import annotations
 
@@ -48,10 +40,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# output-tile width of the one-hot body: each (color, tile) program
-# contracts an (S, TILE) mask on the MXU
+from repro.kernels.csrc_spmv import HIGHEST, LANE_CONTRACT
+from repro.runtime import interpret_mode
+
+# output-tile width and contribution-chunk length of the one-hot body:
+# each (tile, chunk) program contracts a (TILE, CHUNK) mask on the MXU
 ONEHOT_TILE = 512
+ONEHOT_CHUNK = 1024
 COLORED_VARIANTS = ("stream", "onehot")
 
 
@@ -67,15 +64,14 @@ def _padded_contribs(kflat) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Fused XLA executors (the interpret-mode / CPU route)
+# Fused XLA executors (the stream variant and the sorted-slot strategy)
 # ---------------------------------------------------------------------------
 
 def colored_scatter_fused(color_slots, color_targets, kflat,
                           size: int) -> jnp.ndarray:
-    """All color batches as one gather + one segment-sum: the same
-    (slot, target) pairs the in-grid bodies process per color, evaluated
-    grid-free.  Target sentinel ``size`` routes padding to the drop
-    segment one past the vector end."""
+    """All color batches as one gather + one segment-sum over the packs'
+    (slot, target) pairs.  Target sentinel ``size`` routes padding to the
+    drop segment one past the vector end."""
     kpad = _padded_contribs(kflat)
     slots = jnp.asarray(color_slots).astype(jnp.int32).reshape(-1)
     tgts = jnp.asarray(color_targets).astype(jnp.int32).reshape(-1)
@@ -96,117 +92,75 @@ def sorted_scatter(sorted_perm, sorted_targets, kflat,
 
 
 # ---------------------------------------------------------------------------
-# In-grid Pallas bodies (one program per color / per (color, tile))
+# One-hot Pallas kernel (grid = output tiles x contribution chunks)
 # ---------------------------------------------------------------------------
 
-def _colored_kernel_stream(slots_ref, tgts_ref, kvals_ref, out_ref, *,
-                           size_pad: int):
-    """grid = (C,): gather this color's contributions, segment-sum them
-    into the full output block (revisited across colors)."""
-    c = pl.program_id(0)
-    slots = slots_ref[0].astype(jnp.int32)        # (L,), sentinel == G
-    tgts = tgts_ref[0].astype(jnp.int32)          # (L,), sentinel == size
-    contribs = jnp.take(kvals_ref[...], slots)
-    win = jax.ops.segment_sum(contribs, tgts, num_segments=size_pad)
+def _onehot_kernel(contrib_ref, tgt_ref, out_ref, *, tile: int):
+    """One (output tile, contribution chunk) program: the (TILE, LC)
+    one-hot of the chunk's tile-local targets contracted with its
+    contributions.  Out-of-tile targets (including the sentinel) match no
+    iota row and add nothing; the tile's block is revisited across the
+    inner chunk axis."""
+    t = pl.program_id(0)
+    local = tgt_ref[...] - t * tile                         # (1, LC)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (tile, local.shape[1]), 0)
+    onehot = (iota == local).astype(jnp.float32)            # (TILE, LC)
+    win = jax.lax.dot_general(contrib_ref[...], onehot, LANE_CONTRACT,
+                              precision=HIGHEST,
+                              preferred_element_type=jnp.float32)
 
-    @pl.when(c == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = win
 
-    @pl.when(c != 0)
+    @pl.when(pl.program_id(1) != 0)
     def _acc():
-        out_ref[...] = out_ref[...] + win
+        out_ref[...] += win
 
 
-def _colored_kernel_onehot(slots_ref, tgts_ref, kvals_ref, out_ref, *,
-                           tile: int):
-    """grid = (C, NT): the scatter as an MXU contraction.  The (TILE, L)
-    one-hot of this tile's local targets is contracted with the color's
-    contribution vector; out-of-tile targets (including the sentinel)
-    match no iota row and contribute zero."""
-    c = pl.program_id(0)
-    t = pl.program_id(1)
-    slots = slots_ref[0].astype(jnp.int32)               # (L,)
-    local = tgts_ref[0].astype(jnp.int32) - t * tile     # (L,)
-    contribs = jnp.take(kvals_ref[...], slots)
-    length = local.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tile, length), 0)
-    onehot = (iota == local[None, :]).astype(jnp.float32)   # (TILE, L)
-    win = jax.lax.dot_general(
-        onehot, contribs[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]           # (TILE,)
-
-    @pl.when(c == 0)
-    def _init():
-        out_ref[...] = win
-
-    @pl.when(c != 0)
-    def _acc():
-        out_ref[...] = out_ref[...] + win
-
-
-def colored_scatter_grid(color_slots, color_targets, kflat, size: int,
-                         variant: str = "stream",
-                         interpret: bool = True) -> jnp.ndarray:
-    """The colored-batch kernel through the Pallas grid (both variants).
-
-    Inputs are the schedule's (C, L) packs; the contribution table is
-    padded with the sentinel zero and lane-aligned.  The output block is
-    revisited across the color axis (standard revisited-output
-    accumulation), then sliced back to ``size`` — the drop segment and
-    the alignment pad fall off."""
-    if variant not in COLORED_VARIANTS:
-        raise ValueError(
-            f"variant {variant!r} not in {COLORED_VARIANTS}")
-    slots = jnp.asarray(color_slots)
-    tgts = jnp.asarray(color_targets)
-    num_colors, length = slots.shape
+def colored_scatter_onehot(color_slots, color_targets, kflat, size: int,
+                           interpret=None) -> jnp.ndarray:
+    """The one-hot variant: the (C, Lmax) packs' contributions are gathered
+    in XLA (Mosaic has no 1-D gather), cut into ONEHOT_CHUNK-lane chunks,
+    and scattered by the Pallas kernel as MXU contractions per output
+    tile.  O(size · C·Lmax) work: compute-bound by construction, the
+    cost model prices it so."""
     kpad = _padded_contribs(kflat)
-    g_pad = _round_up(kpad.shape[0], 128)
-    kpad = jnp.pad(kpad, (0, g_pad - kpad.shape[0]))
-
-    if variant == "stream":
-        size_pad = _round_up(size + 1, 128)
-        out = pl.pallas_call(
-            functools.partial(_colored_kernel_stream, size_pad=size_pad),
-            grid=(num_colors,),
-            in_specs=[
-                pl.BlockSpec((1, length), lambda c: (c, 0)),   # slots
-                pl.BlockSpec((1, length), lambda c: (c, 0)),   # targets
-                pl.BlockSpec((g_pad,), lambda c: (0,)),        # contribs
-            ],
-            out_specs=pl.BlockSpec((size_pad,), lambda c: (0,)),
-            out_shape=jax.ShapeDtypeStruct((size_pad,), jnp.float32),
-            interpret=interpret,
-        )(slots, tgts, kpad)
-        return out[:size]
-
+    slots = jnp.asarray(color_slots).astype(jnp.int32).reshape(-1)
+    tgts = jnp.asarray(color_targets).astype(jnp.int32).reshape(-1)
+    contribs = jnp.take(kpad, slots)
+    chunk = min(ONEHOT_CHUNK, _round_up(contribs.shape[0], 128))
+    g_pad = _round_up(contribs.shape[0], chunk)
+    contribs = jnp.pad(contribs, (0, g_pad - contribs.shape[0]))
+    tgts = jnp.pad(tgts, (0, g_pad - tgts.shape[0]), constant_values=size)
+    nch = g_pad // chunk
     size_pad = _round_up(size + 1, ONEHOT_TILE)
     nt = size_pad // ONEHOT_TILE
+    chunk_spec = pl.BlockSpec((None, 1, chunk), lambda t, c: (c, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_colored_kernel_onehot, tile=ONEHOT_TILE),
-        grid=(num_colors, nt),
-        in_specs=[
-            pl.BlockSpec((1, length), lambda c, t: (c, 0)),    # slots
-            pl.BlockSpec((1, length), lambda c, t: (c, 0)),    # targets
-            pl.BlockSpec((g_pad,), lambda c, t: (0,)),         # contribs
-        ],
-        out_specs=pl.BlockSpec((ONEHOT_TILE,), lambda c, t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((size_pad,), jnp.float32),
-        interpret=interpret,
-    )(slots, tgts, kpad)
-    return out[:size]
+        functools.partial(_onehot_kernel, tile=ONEHOT_TILE),
+        grid=(nt, nch),
+        in_specs=[chunk_spec, chunk_spec],
+        out_specs=pl.BlockSpec((None, 1, ONEHOT_TILE),
+                               lambda t, c: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nt, 1, ONEHOT_TILE), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
+    )(contribs.reshape(nch, 1, chunk), tgts.reshape(nch, 1, chunk))
+    return out.reshape(-1)[:size]
 
 
 def colored_scatter(color_slots, color_targets, kflat, size: int,
                     variant: str = "stream",
-                    interpret: bool = True) -> jnp.ndarray:
-    """Variant dispatch, mirroring the SpMV stream modules: the stream
-    variant in interpret mode takes the grid-free fused route (the
-    emulated grid's per-step cost dwarfs the kernel math); everything
-    else runs the in-grid bodies."""
-    if variant == "stream" and interpret:
+                    interpret=None) -> jnp.ndarray:
+    """Variant dispatch: 'stream' is the fused XLA form on every backend,
+    'onehot' the Pallas kernel."""
+    if variant not in COLORED_VARIANTS:
+        raise ValueError(
+            f"variant {variant!r} not in {COLORED_VARIANTS}")
+    if variant == "stream":
         return colored_scatter_fused(color_slots, color_targets, kflat,
                                      size)
-    return colored_scatter_grid(color_slots, color_targets, kflat, size,
-                                variant=variant, interpret=interpret)
+    return colored_scatter_onehot(color_slots, color_targets, kflat, size,
+                                  interpret=interpret)

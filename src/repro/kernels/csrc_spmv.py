@@ -17,13 +17,17 @@ TPU adaptation of the paper's parallel CSRC SpMV (docs/DESIGN.md §4):
     paper's one-fewer-load optimization — here it saves 4 of ~16 streamed
     bytes/slot, directly visible in the memory roofline term).
 
-Grid: (NT, NK); k-step block = (KS, 128) slots; x stays whole in VMEM
-(the per-shard x slice after row partitioning; callers enforce the VMEM cap).
-Output block (1, W) is revisited across kt (revisited-output accumulation,
-standard Pallas reduction pattern).
+One body serves SpMV and SpMM: x is a (B, ·) row block (B = 1 for SpMV),
+so every operand keeps slots on lanes and the block shapes satisfy the
+Mosaic tiling rule (last two dims full or divisible by 8×128):
 
-Validated in interpret mode on CPU (tests/test_kernels_spmv.py); BlockSpecs
-are MXU/VPU aligned (last dim 128) for the TPU target.
+    slot streams   (NT, NK·KS, 128), block (·, KS, 128)
+    x windows      (NT, B, W) gathered in XLA before the call, block (·, B, W)
+    output windows (NT, B, W), revisited across the k-step axis
+
+The diagonal term and the window gather are plain XLA around the call.
+The flat-grid kernel (csrc_spmv_flat.py) shares ``onehot_step`` and the
+window helpers; tests/test_tpu_compile.py compiles both for a v5e.
 """
 from __future__ import annotations
 
@@ -32,161 +36,149 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.blockell import BlockEll, pad_x, overlap_add
+from repro.core.blockell import BlockEll, overlap_add, overlap_add_mm
+from repro.runtime import interpret_mode
 
+# Widest x window the one-hot body fits in the default scoped VMEM of a
+# v5e: the two (W, 128) masks of every sublane row stay live across the
+# unrolled k-step (W = 4096 asks ~28 MiB against the 16 MiB limit).
+ONEHOT_MAX_WINDOW = 2048
 
-def _kernel(vals_l_ref, vals_u_ref, col_ref, row_ref, ad_ref, x_ref,
-            out_ref, *, tm: int, w_pad: int, num_symmetric: bool):
-    b = pl.program_id(0)
-    kt = pl.program_id(1)
-
-    # ---- x window for this row tile: padded coords [(b+1)*tm, +W) ----
-    start = (b + 1) * tm
-    xw = jax.lax.dynamic_slice(x_ref[...], (start,), (w_pad,))  # (W,)
-
-    # int32 or int16 stream (plan.index_dtype); upcast for the iota compare
-    cols = col_ref[0].astype(jnp.int32)   # (KS, 128), sentinel == W
-    rows = row_ref[0].astype(jnp.int32)   # (KS, 128) in [W-tm, W)
-    vl = vals_l_ref[0]                    # (KS, 128) f32
-    vu = vl if num_symmetric else vals_u_ref[0]
-
-    ks = cols.shape[0]
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (ks, 128, w_pad), 2)
-    # one-hot over the window; sentinel (== W) produces a zero row
-    oh_cols = (cols[..., None] == iota_w).astype(vl.dtype)      # (KS,128,W)
-    oh_rows = (rows[..., None] == iota_w).astype(vl.dtype)
-
-    # gather x[j] and x[i] via one-hot contraction over W
-    xg = jax.lax.dot_general(
-        oh_cols.reshape(ks * 128, w_pad), xw[:, None],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]               # (KS*128,)
-    xi = jax.lax.dot_general(
-        oh_rows.reshape(ks * 128, w_pad), xw[:, None],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
-
-    contrib_to_rows = vl.reshape(-1) * xg      # al[p]*x[ja[p]]  -> y[i]
-    contrib_to_cols = vu.reshape(-1) * xi      # au[p]*x[i]      -> y[ja[p]]
-
-    # scatter via the transposed one-hots: (W, S) @ (S,)
-    win = jax.lax.dot_general(
-        oh_rows.reshape(ks * 128, w_pad), contrib_to_rows[:, None],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]               # (W,)
-    win = win + jax.lax.dot_general(
-        oh_cols.reshape(ks * 128, w_pad), contrib_to_cols[:, None],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
-
-    @pl.when(kt == 0)
-    def _init():
-        # diagonal: tile rows are the last TM entries of the window
-        diag = ad_ref[0] * jax.lax.dynamic_slice(xw, (w_pad - tm,), (tm,))
-        base = jnp.zeros((w_pad,), jnp.float32)
-        base = jax.lax.dynamic_update_slice(
-            base, diag, (w_pad - tm,))
-        out_ref[0] = base + win
-
-    @pl.when(kt != 0)
-    def _acc():
-        out_ref[0] = out_ref[0] + win
+HIGHEST = jax.lax.Precision.HIGHEST
+# (B, L) x (W, L) -> (B, W): contract the lane dim of both operands
+LANE_CONTRACT = (((1,), (1,)), ((), ()))
 
 
-def _kernel_stream(vals_l_ref, vals_u_ref, col_ref, row_ref, ad_ref, x_ref,
-                   out_ref, *, tm: int, w_pad: int, num_symmetric: bool):
-    """Streaming variant: per-lane gather + segment-sum scatter.
+def onehot_step(vals_l_ref, vals_u_ref, col_ref, row_ref, xw, *,
+                w_pad: int, num_symmetric: bool) -> jnp.ndarray:
+    """(B, W) window partial of one (KS, 128) k-step block.
 
-    Avoids the (KS, 128, W) one-hot tensors entirely — O(1) work per slot
-    instead of O(W), so streamed bytes/slot sit at the format's 12-16 B
-    floor and the kernel is bandwidth-bound (the regime the paper requires
-    for CSRC SpMV).  The padding sentinel (col == W) is clamped into range
-    for the gather — inert because padded slot values are zero — and
-    dropped by the segment-sum scatter (id out of range).  Selected by
-    ``ExecutionPlan.variant == 'stream'``; the one-hot body stays the
-    Mosaic-safe fallback for compiled TPU, which has no native scatter.
+    Each sublane row k builds two (W, 128) masks by comparing its column
+    and row offsets with a sublane iota, gathers x through them
+    (xw @ mask -> (B, 128)), scales by the slot values, and scatters back
+    through the same masks contracted on their lane dim.  Contractions
+    run at HIGHEST precision: the masks are exact, the products stay
+    float32.  Index and value streams may be 16-bit; they are widened
+    in-register.
     """
-    b = pl.program_id(0)
-    kt = pl.program_id(1)
-    start = (b + 1) * tm
-    xw = jax.lax.dynamic_slice(x_ref[...], (start,), (w_pad,))  # (W,)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (w_pad, 128), 0)
+    win = jnp.zeros((xw.shape[0], w_pad), jnp.float32)
+    for k in range(col_ref.shape[0]):
+        row = pl.ds(k, 1)
+        oh_c = (iota == col_ref[row, :].astype(jnp.int32)).astype(jnp.float32)
+        oh_r = (iota == row_ref[row, :].astype(jnp.int32)).astype(jnp.float32)
+        vl = vals_l_ref[row, :].astype(jnp.float32)              # (1, 128)
+        vu = vl if num_symmetric else vals_u_ref[row, :].astype(jnp.float32)
+        xg = jnp.dot(xw, oh_c, precision=HIGHEST,
+                     preferred_element_type=jnp.float32)         # x[ja[p]]
+        xi = jnp.dot(xw, oh_r, precision=HIGHEST,
+                     preferred_element_type=jnp.float32)         # x[i]
+        win += jax.lax.dot_general(vl * xg, oh_r, LANE_CONTRACT,
+                                   precision=HIGHEST,
+                                   preferred_element_type=jnp.float32)
+        win += jax.lax.dot_general(vu * xi, oh_c, LANE_CONTRACT,
+                                   precision=HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    return win
 
-    cols = col_ref[0].astype(jnp.int32).reshape(-1)   # (S,), sentinel == W
-    rows = row_ref[0].astype(jnp.int32).reshape(-1)   # (S,) in [W-tm, W)
-    vl = vals_l_ref[0].reshape(-1)
-    vu = vl if num_symmetric else vals_u_ref[0].reshape(-1)
 
-    xg = jnp.take(xw, jnp.minimum(cols, w_pad - 1))   # x[ja[p]]
-    xi = jnp.take(xw, rows)                           # x[i]
+def x_windows(pack, X: jnp.ndarray) -> jnp.ndarray:
+    """(NT, B, W) x windows of a windowed pack for X (n, B).
 
-    contrib_to_rows = vl * xg      # al[p]*x[ja[p]]  -> y[i]
-    contrib_to_cols = vu * xi      # au[p]*x[i]      -> y[ja[p]]
+    Window b covers padded coordinates [(b+1)·TM, (b+1)·TM + W), i.e. the
+    W/TM row tiles b+1 .. b+W/TM of x left-padded by W — a concatenation
+    of W/TM shifted slices, no gather."""
+    nt, tm, w = pack.nt, pack.tm, pack.w_pad
+    r = w // tm
+    xt = jnp.pad(X.astype(jnp.float32).T, ((0, 0), (w, nt * tm - pack.n)))
+    tiles = xt.reshape(X.shape[1], r + nt, tm)
+    win = jnp.concatenate([tiles[:, 1 + j:1 + j + nt] for j in range(r)],
+                          axis=2)
+    return jnp.swapaxes(win, 0, 1)
 
-    win = jax.ops.segment_sum(contrib_to_rows.astype(jnp.float32), rows,
-                              num_segments=w_pad)
-    win = win + jax.ops.segment_sum(contrib_to_cols.astype(jnp.float32),
-                                    cols, num_segments=w_pad)
 
-    @pl.when(kt == 0)
+def with_diagonal(pack, wins: jnp.ndarray, xw: jnp.ndarray) -> jnp.ndarray:
+    """Add the diagonal term to (NT, B, W) windows: tile b's own rows are
+    the last TM entries of its window."""
+    tm = pack.tm
+    d = pack.ad.astype(jnp.float32)[:, None, :] * xw[:, :, -tm:]
+    return wins + jnp.pad(d, ((0, 0), (0, 0), (pack.w_pad - tm, 0)))
+
+
+def accumulate(pack, wins: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """Overlap-add (NT, B, W) windows into y of x's rank."""
+    if x.ndim == 1:
+        return overlap_add(pack, wins[:, 0, :])
+    return overlap_add_mm(pack, jnp.swapaxes(wins, 1, 2))
+
+
+def _kernel(vals_l_ref, vals_u_ref, col_ref, row_ref, x_ref, out_ref, *,
+            w_pad: int, num_symmetric: bool):
+    win = onehot_step(vals_l_ref, vals_u_ref, col_ref, row_ref, x_ref[...],
+                      w_pad=w_pad, num_symmetric=num_symmetric)
+
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        diag = ad_ref[0] * jax.lax.dynamic_slice(xw, (w_pad - tm,), (tm,))
-        base = jnp.zeros((w_pad,), jnp.float32)
-        base = jax.lax.dynamic_update_slice(
-            base, diag, (w_pad - tm,))
-        out_ref[0] = base + win
+        out_ref[...] = win
 
-    @pl.when(kt != 0)
+    @pl.when(pl.program_id(1) != 0)
     def _acc():
-        out_ref[0] = out_ref[0] + win
+        out_ref[...] += win
 
 
-_BODIES = {"onehot": _kernel, "stream": _kernel_stream}
+def blockell_windows(pack: BlockEll, X: jnp.ndarray,
+                     k_step_sublanes: int = 8,
+                     interpret=None) -> jnp.ndarray:
+    """Per-tile (NT, B, W) windows of A·X for X (n, B), diagonal included,
+    before the overlap-add."""
+    nt, s = pack.vals_l.shape
+    ks = k_step_sublanes
+    assert s % (ks * 128) == 0, "slot count must divide the k-step"
+    nk = s // (ks * 128)
+    xw = x_windows(pack, X)
+    nrhs = X.shape[1]
+
+    def slots(a):
+        return a.reshape(nt, nk * ks, 128)
+
+    slot_spec = pl.BlockSpec((None, ks, 128), lambda b, kt: (b, kt, 0))
+    win_spec = pl.BlockSpec((None, nrhs, pack.w_pad), lambda b, kt: (b, 0, 0))
+    wins = pl.pallas_call(
+        functools.partial(_kernel, w_pad=pack.w_pad,
+                          num_symmetric=pack.num_symmetric),
+        grid=(nt, nk),
+        in_specs=[slot_spec] * 4 + [win_spec],
+        out_specs=win_spec,
+        out_shape=jax.ShapeDtypeStruct((nt, nrhs, pack.w_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_mode(interpret),
+    )(slots(pack.vals_l), slots(pack.vals_u), slots(pack.col_local),
+      slots(pack.row_in_win), xw)
+    return with_diagonal(pack, wins, xw)
 
 
 def blockell_spmv_windows(pack: BlockEll, x: jnp.ndarray,
                           k_step_sublanes: int = 8,
-                          interpret: bool = True,
-                          variant: str = "onehot") -> jnp.ndarray:
-    """Run the kernel; returns per-tile windows (NT, W) before accumulation."""
-    nt, s = pack.vals_l.shape
-    assert s % (k_step_sublanes * 128) == 0, (
-        "slot count must divide the k-step")
-    nk = s // (k_step_sublanes * 128)
-    ks = k_step_sublanes
-    x_full = pad_x(pack, x.astype(jnp.float32))
-
-    def reshape3(a):
-        return a.reshape(nt, nk * ks, 128)
-
-    grid = (nt, nk)
-    slot_spec = pl.BlockSpec((1, ks, 128), lambda b, kt: (b, kt, 0))
-    out = pl.pallas_call(
-        functools.partial(_BODIES[variant], tm=pack.tm, w_pad=pack.w_pad,
-                          num_symmetric=pack.num_symmetric),
-        grid=grid,
-        in_specs=[
-            slot_spec,                                      # vals_l
-            slot_spec,                                      # vals_u
-            slot_spec,                                      # col_local
-            slot_spec,                                      # row_in_win
-            pl.BlockSpec((1, pack.tm), lambda b, kt: (b, 0)),   # ad
-            pl.BlockSpec(x_full.shape, lambda b, kt: (0,)),     # x (whole)
-        ],
-        out_specs=pl.BlockSpec((1, pack.w_pad), lambda b, kt: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, pack.w_pad), jnp.float32),
-        interpret=interpret,
-    )(reshape3(pack.vals_l), reshape3(pack.vals_u),
-      reshape3(pack.col_local), reshape3(pack.row_in_win),
-      pack.ad, x_full)
-    return out
+                          interpret=None) -> jnp.ndarray:
+    """SpMV windows (NT, W) before accumulation."""
+    return blockell_windows(pack, x[:, None], k_step_sublanes,
+                            interpret)[:, 0, :]
 
 
-def blockell_spmv(pack: BlockEll, x: jnp.ndarray,
-                  interpret: bool = True,
-                  k_step_sublanes: int = 8,
-                  variant: str = "onehot") -> jnp.ndarray:
+def blockell_spmv(pack: BlockEll, x: jnp.ndarray, interpret=None,
+                  k_step_sublanes: int = 8) -> jnp.ndarray:
     """Full product: kernel windows + effective accumulation."""
-    wins = blockell_spmv_windows(pack, x, k_step_sublanes=k_step_sublanes,
-                                 interpret=interpret, variant=variant)
-    return overlap_add(pack, wins)
+    return accumulate(pack, blockell_windows(pack, x[:, None],
+                                             k_step_sublanes, interpret), x)
+
+
+def blockell_spmm(pack: BlockEll, X: jnp.ndarray, k_step_sublanes: int = 8,
+                  interpret=None) -> jnp.ndarray:
+    """Y = A @ X for X (n, B); the one-hot contractions become genuine MXU
+    matmuls, so arithmetic intensity rises with B."""
+    assert X.shape[0] == pack.n
+    return accumulate(pack, blockell_windows(pack, X, k_step_sublanes,
+                                             interpret), X)

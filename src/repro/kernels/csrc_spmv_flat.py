@@ -37,7 +37,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.csrc import CSRC, bandwidth, row_of_slot
-from repro.core.blockell import _round_up, overlap_add, overlap_add_mm
+from repro.core.blockell import _round_up
+from repro.kernels.csrc_spmv import (accumulate, onehot_step, with_diagonal,
+                                     x_windows)
+from repro.runtime import interpret_mode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,16 +108,16 @@ def _flat_arrays(ros, ja, al, au, *, nt: int, tm: int, w_pad: int,
     first[starts] = 1
 
     win_lo = (np.arange(nt) + 1) * tm - w_pad
-    fill = np.zeros(nt, np.int64)
-    for idx in np.argsort(tile_of_slot, kind="stable"):
-        t = int(tile_of_slot[idx])
-        q = int(fill[t]); fill[t] += 1
-        j = int(starts[t]) + q // step
-        pos = q % step
-        vals_l[j, pos] = al[idx]
-        vals_u[j, pos] = au[idx]
-        col_local[j, pos] = int(ja[idx]) - int(win_lo[t])
-        row_in_win[j, pos] = int(ros[idx]) - int(win_lo[t])
+    # stable tile order; q = a slot's position within its tile
+    order = np.argsort(tile_of_slot, kind="stable")
+    t = tile_of_slot[order]
+    q = np.arange(t.size) - np.searchsorted(t, t)
+    j = starts[t] + q // step
+    pos = q % step
+    vals_l[j, pos] = al[order]
+    vals_u[j, pos] = au[order]
+    col_local[j, pos] = ja[order] - win_lo[t]
+    row_in_win[j, pos] = ros[order] - win_lo[t]
     return vals_l, vals_u, col_local, row_in_win, tile_of_step, first, total
 
 
@@ -188,235 +191,63 @@ def refresh_flat_values(pack: FlatBlockEll, M: CSRC) -> FlatBlockEll:
 
 
 def _kernel(tile_ref, first_ref, vals_l_ref, vals_u_ref, col_ref, row_ref,
-            ad_ref, x_ref, out_ref, *, tm: int, w_pad: int,
-            num_symmetric: bool):
+            x_ref, out_ref, *, w_pad: int, num_symmetric: bool):
     j = pl.program_id(0)
-    b = tile_ref[j]
-    start = (b + 1) * tm
-    xw = jax.lax.dynamic_slice(x_ref[...], (start,), (w_pad,))
-
-    cols = col_ref[0].astype(jnp.int32)
-    rows = row_ref[0].astype(jnp.int32)
-    vl = vals_l_ref[0]
-    vu = vl if num_symmetric else vals_u_ref[0]
-    ks = cols.shape[0]
-    s = ks * 128
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (ks, 128, w_pad), 2)
-    oh_cols = (cols[..., None] == iota_w).astype(vl.dtype).reshape(s, w_pad)
-    oh_rows = (rows[..., None] == iota_w).astype(vl.dtype).reshape(s, w_pad)
-    xg = jax.lax.dot_general(oh_cols, xw[:, None], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)[:, 0]
-    xi = jax.lax.dot_general(oh_rows, xw[:, None], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)[:, 0]
-    c_rows = vl.reshape(-1) * xg
-    c_cols = vu.reshape(-1) * xi
-    win = jax.lax.dot_general(oh_rows, c_rows[:, None],
-                              (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)[:, 0]
-    win = win + jax.lax.dot_general(oh_cols, c_cols[:, None],
-                                    (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)[:, 0]
+    win = onehot_step(vals_l_ref, vals_u_ref, col_ref, row_ref, x_ref[...],
+                      w_pad=w_pad, num_symmetric=num_symmetric)
 
     @pl.when(first_ref[j] == 1)
     def _init():
-        diag = ad_ref[0] * jax.lax.dynamic_slice(xw, (w_pad - tm,), (tm,))
-        base = jnp.zeros((w_pad,), jnp.float32)
-        base = jax.lax.dynamic_update_slice(base, diag, (w_pad - tm,))
-        out_ref[0] = base + win
+        out_ref[...] = win
 
     @pl.when(first_ref[j] != 1)
     def _acc():
-        out_ref[0] = out_ref[0] + win
+        out_ref[...] += win
 
 
-def _kernel_stream(tile_ref, first_ref, vals_l_ref, vals_u_ref, col_ref,
-                   row_ref, ad_ref, x_ref, out_ref, *, tm: int, w_pad: int,
-                   num_symmetric: bool):
-    """Streaming variant (see csrc_spmv._kernel_stream): per-lane gather +
-    segment-sum scatter instead of the (S, W) one-hot contractions."""
-    j = pl.program_id(0)
-    b = tile_ref[j]
-    start = (b + 1) * tm
-    xw = jax.lax.dynamic_slice(x_ref[...], (start,), (w_pad,))
-
-    cols = col_ref[0].astype(jnp.int32).reshape(-1)   # (S,), sentinel == W
-    rows = row_ref[0].astype(jnp.int32).reshape(-1)
-    vl = vals_l_ref[0].reshape(-1)
-    vu = vl if num_symmetric else vals_u_ref[0].reshape(-1)
-
-    xg = jnp.take(xw, jnp.minimum(cols, w_pad - 1))
-    xi = jnp.take(xw, rows)
-    c_rows = vl * xg
-    c_cols = vu * xi
-    win = jax.ops.segment_sum(c_rows.astype(jnp.float32), rows,
-                              num_segments=w_pad)
-    win = win + jax.ops.segment_sum(c_cols.astype(jnp.float32), cols,
-                                    num_segments=w_pad)
-
-    @pl.when(first_ref[j] == 1)
-    def _init():
-        diag = ad_ref[0] * jax.lax.dynamic_slice(xw, (w_pad - tm,), (tm,))
-        base = jnp.zeros((w_pad,), jnp.float32)
-        base = jax.lax.dynamic_update_slice(base, diag, (w_pad - tm,))
-        out_ref[0] = base + win
-
-    @pl.when(first_ref[j] != 1)
-    def _acc():
-        out_ref[0] = out_ref[0] + win
-
-
-_BODIES = {"onehot": _kernel, "stream": _kernel_stream}
+def flat_windows(pack: FlatBlockEll, X: jnp.ndarray,
+                 interpret=None) -> jnp.ndarray:
+    """Per-tile (NT, B, W) windows of A·X for X (n, B), diagonal included:
+    the rectangular kernel's body over the flat step list, each step's
+    x/output window picked by its prefetched tile id."""
+    xw = x_windows(pack, X)
+    nrhs = X.shape[1]
+    slot_spec = pl.BlockSpec((None, pack.ks, 128),
+                             lambda j, tile, first: (j, 0, 0))
+    win_spec = pl.BlockSpec((None, nrhs, pack.w_pad),
+                            lambda j, tile, first: (tile[j], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(pack.total_steps,),
+        in_specs=[slot_spec] * 4 + [win_spec],
+        out_specs=win_spec,
+    )
+    wins = pl.pallas_call(
+        functools.partial(_kernel, w_pad=pack.w_pad,
+                          num_symmetric=pack.num_symmetric),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((pack.nt, nrhs, pack.w_pad),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(interpret),
+    )(pack.tile_of_step, pack.first_of_tile,
+      pack.vals_l, pack.vals_u, pack.col_local, pack.row_in_win, xw)
+    return with_diagonal(pack, wins, xw)
 
 
 def flat_spmv(pack: FlatBlockEll, x: jnp.ndarray,
-              interpret: bool = True,
-              variant: str = "onehot") -> jnp.ndarray:
-    x_full = jnp.pad(x.astype(jnp.float32),
-                     (pack.w_pad, pack.n_pad - pack.n))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(pack.total_steps,),
-        in_specs=[
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.tm), lambda j, tile, first: (tile[j], 0)),
-            pl.BlockSpec(x_full.shape, lambda j, tile, first: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, pack.w_pad),
-                               lambda j, tile, first: (tile[j], 0)),
-    )
-    wins = pl.pallas_call(
-        functools.partial(_BODIES[variant], tm=pack.tm, w_pad=pack.w_pad,
-                          num_symmetric=pack.num_symmetric),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((pack.nt, pack.w_pad), jnp.float32),
-        interpret=interpret,
-    )(pack.tile_of_step, pack.first_of_tile,
-      pack.vals_l, pack.vals_u, pack.col_local, pack.row_in_win,
-      pack.ad, x_full)
-    return overlap_add(pack, wins)
-
-
-def _kernel_mm(tile_ref, first_ref, vals_l_ref, vals_u_ref, col_ref,
-               row_ref, ad_ref, x_ref, out_ref, *, tm: int, w_pad: int,
-               nrhs: int, num_symmetric: bool):
-    j = pl.program_id(0)
-    b = tile_ref[j]
-    start = (b + 1) * tm
-    xw = jax.lax.dynamic_slice(x_ref[...], (start, 0), (w_pad, nrhs))
-
-    cols = col_ref[0].astype(jnp.int32)
-    rows = row_ref[0].astype(jnp.int32)
-    vl = vals_l_ref[0]
-    vu = vl if num_symmetric else vals_u_ref[0]
-    ks = cols.shape[0]
-    s = ks * 128
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (ks, 128, w_pad), 2)
-    oh_cols = (cols[..., None] == iota_w).astype(vl.dtype).reshape(s, w_pad)
-    oh_rows = (rows[..., None] == iota_w).astype(vl.dtype).reshape(s, w_pad)
-
-    xg = jax.lax.dot_general(oh_cols, xw, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # (S, B)
-    xi = jax.lax.dot_general(oh_rows, xw, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    c_rows = vl.reshape(s, 1) * xg
-    c_cols = vu.reshape(s, 1) * xi
-    win = jax.lax.dot_general(oh_rows, c_rows, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    win = win + jax.lax.dot_general(oh_cols, c_cols,
-                                    (((0,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-
-    @pl.when(first_ref[j] == 1)
-    def _init():
-        diag = ad_ref[0][:, None] * jax.lax.dynamic_slice(
-            xw, (w_pad - tm, 0), (tm, nrhs))
-        base = jnp.zeros((w_pad, nrhs), jnp.float32)
-        base = jax.lax.dynamic_update_slice(base, diag, (w_pad - tm, 0))
-        out_ref[0] = base + win
-
-    @pl.when(first_ref[j] != 1)
-    def _acc():
-        out_ref[0] = out_ref[0] + win
-
-
-def _kernel_mm_stream(tile_ref, first_ref, vals_l_ref, vals_u_ref, col_ref,
-                      row_ref, ad_ref, x_ref, out_ref, *, tm: int,
-                      w_pad: int, nrhs: int, num_symmetric: bool):
-    """Streaming multi-RHS variant: per-lane row gather of the (W, B)
-    window + segment-sum scatter — O(B) work per slot."""
-    j = pl.program_id(0)
-    b = tile_ref[j]
-    start = (b + 1) * tm
-    xw = jax.lax.dynamic_slice(x_ref[...], (start, 0), (w_pad, nrhs))
-
-    cols = col_ref[0].astype(jnp.int32).reshape(-1)
-    rows = row_ref[0].astype(jnp.int32).reshape(-1)
-    vl = vals_l_ref[0].reshape(-1)
-    vu = vl if num_symmetric else vals_u_ref[0].reshape(-1)
-
-    xg = jnp.take(xw, jnp.minimum(cols, w_pad - 1), axis=0)   # (S, B)
-    xi = jnp.take(xw, rows, axis=0)
-    c_rows = vl[:, None] * xg
-    c_cols = vu[:, None] * xi
-    win = jax.ops.segment_sum(c_rows.astype(jnp.float32), rows,
-                              num_segments=w_pad)
-    win = win + jax.ops.segment_sum(c_cols.astype(jnp.float32), cols,
-                                    num_segments=w_pad)
-
-    @pl.when(first_ref[j] == 1)
-    def _init():
-        diag = ad_ref[0][:, None] * jax.lax.dynamic_slice(
-            xw, (w_pad - tm, 0), (tm, nrhs))
-        base = jnp.zeros((w_pad, nrhs), jnp.float32)
-        base = jax.lax.dynamic_update_slice(base, diag, (w_pad - tm, 0))
-        out_ref[0] = base + win
-
-    @pl.when(first_ref[j] != 1)
-    def _acc():
-        out_ref[0] = out_ref[0] + win
-
-
-_BODIES_MM = {"onehot": _kernel_mm, "stream": _kernel_mm_stream}
+              interpret=None) -> jnp.ndarray:
+    return accumulate(pack, flat_windows(pack, x[:, None], interpret), x)
 
 
 def flat_spmm(pack: FlatBlockEll, X: jnp.ndarray,
-              interpret: bool = True,
-              variant: str = "onehot") -> jnp.ndarray:
+              interpret=None) -> jnp.ndarray:
     """Y = A @ X for X (n, B) — the multi-RHS flat-grid product (batched
     serving / block-Krylov shape) with the same per-tile-exact step layout
     as flat_spmv."""
-    n, nrhs = X.shape
-    assert n == pack.n
-    x_full = jnp.pad(X.astype(jnp.float32),
-                     ((pack.w_pad, pack.n_pad - pack.n), (0, 0)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(pack.total_steps,),
-        in_specs=[
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j, tile, first: (j, 0, 0)),
-            pl.BlockSpec((1, pack.tm), lambda j, tile, first: (tile[j], 0)),
-            pl.BlockSpec(x_full.shape, lambda j, tile, first: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, pack.w_pad, nrhs),
-                               lambda j, tile, first: (tile[j], 0, 0)),
-    )
-    wins = pl.pallas_call(
-        functools.partial(_BODIES_MM[variant], tm=pack.tm, w_pad=pack.w_pad,
-                          nrhs=nrhs, num_symmetric=pack.num_symmetric),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((pack.nt, pack.w_pad, nrhs),
-                                       jnp.float32),
-        interpret=interpret,
-    )(pack.tile_of_step, pack.first_of_tile,
-      pack.vals_l, pack.vals_u, pack.col_local, pack.row_in_win,
-      pack.ad, x_full)
-    return overlap_add_mm(pack, wins)
+    assert X.shape[0] == pack.n
+    return accumulate(pack, flat_windows(pack, X, interpret), X)
 
 
 # ---------------------------------------------------------------------------
@@ -751,8 +582,7 @@ def flat_shard_specs(axis: str):
             P(axis, None, None))
 
 
-def flat_local_fn(fs, n_local: int, interpret: bool,
-                  variant: str = "onehot"):
+def flat_local_fn(fs, n_local: int, interpret=None, variant="onehot"):
     """Shard-local flat-grid product: rebuild the shard's FlatBlockEll from
     the shard_map-sliced stacked arrays and run the Pallas kernel (SpMV or
     SpMM by x rank).  ``fs`` is a FlatShards or FlatHalo layout."""
@@ -764,9 +594,13 @@ def flat_local_fn(fs, n_local: int, interpret: bool,
             row_in_win=row[0], ad=ad[0], tile_of_step=tile[0],
             first_of_tile=first[0],
             num_symmetric=fs.num_symmetric, pad_ratio=1.0)
+        if variant == "stream":
+            from repro.kernels import csrc_spmv_stream as stream_mod
+            return (stream_mod.flat_spmm_stream(pk, x) if x.ndim == 2
+                    else stream_mod.flat_spmv_stream(pk, x))
         if x.ndim == 2:
-            return flat_spmm(pk, x, interpret=interpret, variant=variant)
-        return flat_spmv(pk, x, interpret=interpret, variant=variant)
+            return flat_spmm(pk, x, interpret=interpret)
+        return flat_spmv(pk, x, interpret=interpret)
 
     return local_y
 

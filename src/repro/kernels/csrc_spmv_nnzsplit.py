@@ -21,9 +21,10 @@ Execution.  ``x[src]`` is gathered outside the kernel (a single
 contiguous stream read; unstructured matrices have no window to exploit,
 so an in-kernel one-hot gather would be O(S·n)).  The Pallas grid is 1-D
 over chunks; each program reduces its S products into an ``r_pad``-wide
-partial row vector with one one-hot matmul (MXU-friendly, no in-kernel
-scatter) and writes its own output row — no cross-program accumulation,
-so no first-of-tile bookkeeping.  A host-side fix-up pass scatter-adds
+partial row vector with one one-hot matmul per sublane row of the chunk
+(MXU-friendly, no in-kernel scatter) and writes its own output row — no
+cross-program accumulation, so no first-of-tile bookkeeping.  An XLA
+fix-up pass scatter-adds
 the per-chunk partials at ``chunk_row0[c] + r`` — rows split across a
 chunk boundary are merged here — and the diagonal term closes the
 product.  All float32 sums are plain adds, so for dyadic values the
@@ -46,10 +47,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from repro.core.csrc import CSRC, bandwidth, row_of_slot
 from repro.core.blockell import _round_up
+from repro.kernels.csrc_spmv import HIGHEST, LANE_CONTRACT
+from repro.runtime import interpret_mode
 
 
 def _combined_stream(M: CSRC):
@@ -184,106 +188,52 @@ def refresh_nnzsplit_values(pack: NnzSplitPack, M: CSRC) -> NnzSplitPack:
 
 
 # ---------------------------------------------------------------------------
-# Kernels: one program per chunk, one one-hot matmul per product
+# Kernel: one program per chunk, one one-hot matmul per sublane row
 # ---------------------------------------------------------------------------
 
 def _kernel(vals_ref, lrow_ref, xg_ref, out_ref, *, r_pad: int):
-    lr = lrow_ref[0].astype(jnp.int32)        # (KS, 128)
-    ks = lr.shape[0]
-    s = ks * 128
-    c = vals_ref[0].reshape(-1).astype(jnp.float32) * xg_ref[0].reshape(-1)
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (ks, 128, r_pad), 2)
-    oh = (lr[..., None] == iota_r).astype(jnp.float32).reshape(s, r_pad)
-    out_ref[0] = jax.lax.dot_general(oh, c[:, None],
-                                     (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)[:, 0]
-
-
-def _kernel_stream(vals_ref, lrow_ref, xg_ref, out_ref, *, r_pad: int):
-    """Streaming variant: segment-sum over the chunk-local rows instead of
-    the (S, r_pad) one-hot contraction — O(1) work per stream entry.
-    Padding entries carry val=0 and an in-range lrow, so they add exact
-    zeros (same invariant the one-hot body relies on)."""
-    lr = lrow_ref[0].astype(jnp.int32).reshape(-1)     # (S,)
-    c = vals_ref[0].reshape(-1).astype(jnp.float32) * xg_ref[0].reshape(-1)
-    out_ref[0] = jax.ops.segment_sum(c, lr, num_segments=r_pad)
-
-
-_BODIES = {"onehot": _kernel, "stream": _kernel_stream}
-
-
-def nnzsplit_spmv(pack: NnzSplitPack, x: jnp.ndarray,
-                  interpret: bool = True,
-                  variant: str = "onehot") -> jnp.ndarray:
-    x = x.astype(jnp.float32)
-    xg = x[pack.src.astype(jnp.int32)].reshape(pack.num_chunks, pack.ks, 128)
-    partial = pl.pallas_call(
-        functools.partial(_BODIES[variant], r_pad=pack.r_pad),
-        grid=(pack.num_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, pack.ks, 128), lambda j: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, pack.r_pad), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((pack.num_chunks, pack.r_pad),
-                                       jnp.float32),
-        interpret=interpret,
-    )(pack.vals, pack.lrow, xg)
-    y_pad = jnp.zeros(pack.n + pack.r_pad, jnp.float32
-                      ).at[pack.fixup_idx].add(partial.reshape(-1))
-    return y_pad[:pack.n] + pack.ad.astype(jnp.float32) * x
-
-
-def _kernel_mm(vals_ref, lrow_ref, xg_ref, out_ref, *, r_pad: int,
-               nrhs: int):
-    lr = lrow_ref[0].astype(jnp.int32)
-    ks = lr.shape[0]
-    s = ks * 128
-    c = vals_ref[0].reshape(s, 1).astype(jnp.float32) * xg_ref[0]  # (S, B)
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (ks, 128, r_pad), 2)
-    oh = (lr[..., None] == iota_r).astype(jnp.float32).reshape(s, r_pad)
-    out_ref[0] = jax.lax.dot_general(oh, c, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-
-
-def _kernel_mm_stream(vals_ref, lrow_ref, xg_ref, out_ref, *, r_pad: int,
-                      nrhs: int):
-    """Streaming multi-RHS variant: B-wide segment-sum scatter."""
-    lr = lrow_ref[0].astype(jnp.int32).reshape(-1)
-    s = lr.shape[0]
-    c = vals_ref[0].reshape(s, 1).astype(jnp.float32) * xg_ref[0]  # (S, B)
-    out_ref[0] = jax.ops.segment_sum(c, lr, num_segments=r_pad)
-
-
-_BODIES_MM = {"onehot": _kernel_mm, "stream": _kernel_mm_stream}
+    """(B, r_pad) partial of one chunk: each sublane row's (r_pad, 128)
+    row mask, contracted on lanes with that row's products."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (r_pad, 128), 0)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for k in range(lrow_ref.shape[0]):
+        row = pl.ds(k, 1)
+        oh = (iota == lrow_ref[row, :].astype(jnp.int32)).astype(jnp.float32)
+        c = vals_ref[row, :].astype(jnp.float32) * xg_ref[k]       # (B, 128)
+        acc += jax.lax.dot_general(c, oh, LANE_CONTRACT, precision=HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    out_ref[...] = acc
 
 
 def nnzsplit_spmm(pack: NnzSplitPack, X: jnp.ndarray,
-                  interpret: bool = True,
-                  variant: str = "onehot") -> jnp.ndarray:
-    """Y = A @ X for X (n, B): same chunk layout, B-wide partials."""
+                  interpret=None) -> jnp.ndarray:
+    """Y = A @ X for X (n, B).  x[src] is gathered in XLA and laid out
+    (C, KS, B, 128) so every block keeps stream entries on lanes."""
     n, nrhs = X.shape
     assert n == pack.n
     X = X.astype(jnp.float32)
-    s = pack.s
-    xg = X[pack.src.astype(jnp.int32), :].reshape(pack.num_chunks, s, nrhs)
+    nc, ks = pack.num_chunks, pack.ks
+    xg = X[pack.src.astype(jnp.int32)].reshape(nc, ks, 128, nrhs)
+    stream_spec = pl.BlockSpec((None, ks, 128), lambda j: (j, 0, 0))
     partial = pl.pallas_call(
-        functools.partial(_BODIES_MM[variant], r_pad=pack.r_pad, nrhs=nrhs),
-        grid=(pack.num_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, pack.ks, 128), lambda j: (j, 0, 0)),
-            pl.BlockSpec((1, pack.ks, 128), lambda j: (j, 0, 0)),
-            pl.BlockSpec((1, s, nrhs), lambda j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, pack.r_pad, nrhs), lambda j: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((pack.num_chunks, pack.r_pad, nrhs),
-                                       jnp.float32),
-        interpret=interpret,
-    )(pack.vals, pack.lrow, xg)
-    y_pad = jnp.zeros((pack.n + pack.r_pad, nrhs), jnp.float32
-                      ).at[pack.fixup_idx].add(partial.reshape(-1, nrhs))
-    return y_pad[:pack.n] + pack.ad.astype(jnp.float32)[:, None] * X
+        functools.partial(_kernel, r_pad=pack.r_pad),
+        grid=(nc,),
+        in_specs=[stream_spec, stream_spec,
+                  pl.BlockSpec((None, ks, nrhs, 128), lambda j: (j, 0, 0, 0))],
+        out_specs=pl.BlockSpec((None, nrhs, pack.r_pad), lambda j: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nc, nrhs, pack.r_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret_mode(interpret),
+    )(pack.vals, pack.lrow, jnp.swapaxes(xg, 2, 3))
+    y_pad = jnp.zeros((n + pack.r_pad, nrhs), jnp.float32).at[
+        pack.fixup_idx].add(jnp.swapaxes(partial, 1, 2).reshape(-1, nrhs))
+    return y_pad[:n] + pack.ad.astype(jnp.float32)[:, None] * X
+
+
+def nnzsplit_spmv(pack: NnzSplitPack, x: jnp.ndarray,
+                  interpret=None) -> jnp.ndarray:
+    return nnzsplit_spmm(pack, x[:, None], interpret)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -524,8 +474,7 @@ def nnzsplit_shard_specs(axis: str):
             P(axis, None), P(axis, None), P(axis, None), P(axis, None))
 
 
-def nnzsplit_local_fn(lay, n_local: int, interpret: bool,
-                      variant: str = "onehot"):
+def nnzsplit_local_fn(lay, n_local: int, interpret=None, variant="onehot"):
     """Shard-local product: rebuild the shard's pack from the shard_map
     slices (leading axis 1) and dispatch SpMV/SpMM on x's rank."""
     def fn(vals, lrow, src, chunk_row0, fixup_idx, ad, x):
@@ -534,9 +483,13 @@ def nnzsplit_local_fn(lay, n_local: int, interpret: bool,
             r_pad=lay.r_pad, vals=vals[0], lrow=lrow[0], src=src[0],
             chunk_row0=chunk_row0[0], fixup_idx=fixup_idx[0], ad=ad[0],
             num_symmetric=lay.num_symmetric, pad_ratio=1.0)
+        if variant == "stream":
+            from repro.kernels import csrc_spmv_stream as stream_mod
+            return (stream_mod.nnzsplit_spmm_stream(pk, x) if x.ndim == 2
+                    else stream_mod.nnzsplit_spmv_stream(pk, x))
         if x.ndim == 2:
-            return nnzsplit_spmm(pk, x, interpret=interpret, variant=variant)
-        return nnzsplit_spmv(pk, x, interpret=interpret, variant=variant)
+            return nnzsplit_spmm(pk, x, interpret=interpret)
+        return nnzsplit_spmv(pk, x, interpret=interpret)
     return fn
 
 

@@ -6,26 +6,17 @@ contractions — O(W) work per slot, which is why the tuned local path sat
 whole premise is that CSRC SpMV is *memory-bound*: per slot the kernel
 must stream 12-16 bytes (value + local index [+ transpose value]) and do
 O(1) arithmetic.  This module is that streaming formulation, selected by
-``ExecutionPlan.variant == 'stream'``:
+``ExecutionPlan.variant == 'stream'``: the same per-tile-window
+computation as the one-hot kernels, evaluated as one fused XLA expression
+over all (tile, slot) pairs — one gather + one segment-sum per product
+term, then the unchanged ``overlap_add`` accumulation.  It runs as XLA on
+every backend: Mosaic lowers neither a 1-D gather nor a scatter-add, so
+the streaming form has no in-grid Pallas body.
 
-  * on the compiled TPU target (``interpret=False``) it dispatches to the
-    in-kernel streaming bodies of csrc_spmv/csrc_spmm/csrc_spmv_flat/
-    csrc_spmv_nnzsplit (`variant='stream'`): per-lane ``jnp.take`` over
-    the VMEM x window + segment-sum over the precomputed lane offsets,
-    inside the same grid/BlockSpec structure as the one-hot bodies;
-  * in interpret mode (the CPU backend of this repo's tests and benches)
-    the Pallas grid is *emulated* step by step — per-step slicing installs
-    a fixed cost that dwarfs the O(S) kernel math (measured ~1 ms/step
-    against ~30 µs of useful work).  There the same per-tile-window
-    computation is evaluated as one fused XLA expression over all (tile,
-    slot) pairs: one gather + one segment-sum per product term, then the
-    unchanged ``overlap_add`` accumulation.  No grid, no emulation floor.
-
-Both routes compute the per-tile windows defined by the one-hot oracle —
-the same slots summed into the same window positions — so for dyadic
-values the results are bit-identical to the one-hot kernels (the order of
-float additions is the only difference; tests/test_stream_variant.py
-asserts equality).
+It computes the per-tile windows defined by the one-hot kernels — the
+same slots summed into the same window positions — so for dyadic values
+the results are bit-identical to them (the order of float additions is
+the only difference; tests/test_stream_variant.py asserts equality).
 
 Sentinel discipline (shared with the packers): padded slots carry value 0
 and column sentinel ``w_pad``; the fused gather clamps the sentinel into
@@ -39,10 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.blockell import BlockEll, pad_x, overlap_add, overlap_add_mm
-from repro.kernels import csrc_spmv as rect_mod
-from repro.kernels import csrc_spmm as rect_mm_mod
-from repro.kernels import csrc_spmv_flat as flat_mod
-from repro.kernels import csrc_spmv_nnzsplit as nz_mod
 from repro.kernels.csrc_spmv_flat import FlatBlockEll
 from repro.kernels.csrc_spmv_nnzsplit import NnzSplitPack
 
@@ -113,13 +100,7 @@ def _rect_streams(pack: BlockEll):
     return nt, vl, vu, idx
 
 
-def blockell_spmv_stream(pack: BlockEll, x: jnp.ndarray,
-                         k_step_sublanes: int = 8,
-                         interpret: bool = True) -> jnp.ndarray:
-    if not interpret:
-        return rect_mod.blockell_spmv(pack, x, interpret=False,
-                                      k_step_sublanes=k_step_sublanes,
-                                      variant="stream")
+def blockell_spmv_stream(pack: BlockEll, x: jnp.ndarray) -> jnp.ndarray:
     nt, vl, vu, idx = _rect_streams(pack)
     x_full = pad_x(pack, x.astype(jnp.float32))
     wins = _windowed_product(x_full, vl, vu, *idx, nt=nt, w_pad=pack.w_pad)
@@ -128,13 +109,7 @@ def blockell_spmv_stream(pack: BlockEll, x: jnp.ndarray,
     return overlap_add(pack, wins)
 
 
-def blockell_spmm_stream(pack: BlockEll, X: jnp.ndarray,
-                         k_step_sublanes: int = 8,
-                         interpret: bool = True) -> jnp.ndarray:
-    if not interpret:
-        return rect_mm_mod.blockell_spmm(pack, X, interpret=False,
-                                         k_step_sublanes=k_step_sublanes,
-                                         variant="stream")
+def blockell_spmm_stream(pack: BlockEll, X: jnp.ndarray) -> jnp.ndarray:
     assert X.shape[0] == pack.n
     nt, vl, vu, idx = _rect_streams(pack)
     x_full = jnp.pad(X.astype(jnp.float32),
@@ -158,11 +133,7 @@ def _flat_streams(pack: FlatBlockEll):
     return vl, vu, idx
 
 
-def flat_spmv_stream(pack: FlatBlockEll, x: jnp.ndarray,
-                     interpret: bool = True) -> jnp.ndarray:
-    if not interpret:
-        return flat_mod.flat_spmv(pack, x, interpret=False,
-                                  variant="stream")
+def flat_spmv_stream(pack: FlatBlockEll, x: jnp.ndarray) -> jnp.ndarray:
     vl, vu, idx = _flat_streams(pack)
     x_full = jnp.pad(x.astype(jnp.float32),
                      (pack.w_pad, pack.n_pad - pack.n))
@@ -173,11 +144,7 @@ def flat_spmv_stream(pack: FlatBlockEll, x: jnp.ndarray,
     return overlap_add(pack, wins)
 
 
-def flat_spmm_stream(pack: FlatBlockEll, X: jnp.ndarray,
-                     interpret: bool = True) -> jnp.ndarray:
-    if not interpret:
-        return flat_mod.flat_spmm(pack, X, interpret=False,
-                                  variant="stream")
+def flat_spmm_stream(pack: FlatBlockEll, X: jnp.ndarray) -> jnp.ndarray:
     assert X.shape[0] == pack.n
     vl, vu, idx = _flat_streams(pack)
     x_full = jnp.pad(X.astype(jnp.float32),
@@ -200,11 +167,7 @@ def _chunk_segments(pack: NnzSplitPack):
     return seg
 
 
-def nnzsplit_spmv_stream(pack: NnzSplitPack, x: jnp.ndarray,
-                         interpret: bool = True) -> jnp.ndarray:
-    if not interpret:
-        return nz_mod.nnzsplit_spmv(pack, x, interpret=False,
-                                    variant="stream")
+def nnzsplit_spmv_stream(pack: NnzSplitPack, x: jnp.ndarray) -> jnp.ndarray:
     x = x.astype(jnp.float32)
     xg = x[pack.src.astype(jnp.int32)]
     c = (pack.vals.reshape(-1).astype(jnp.float32) * xg)
@@ -216,11 +179,7 @@ def nnzsplit_spmv_stream(pack: NnzSplitPack, x: jnp.ndarray,
     return y_pad[:pack.n] + pack.ad.astype(jnp.float32) * x
 
 
-def nnzsplit_spmm_stream(pack: NnzSplitPack, X: jnp.ndarray,
-                         interpret: bool = True) -> jnp.ndarray:
-    if not interpret:
-        return nz_mod.nnzsplit_spmm(pack, X, interpret=False,
-                                    variant="stream")
+def nnzsplit_spmm_stream(pack: NnzSplitPack, X: jnp.ndarray) -> jnp.ndarray:
     n, nrhs = X.shape
     assert n == pack.n
     X = X.astype(jnp.float32)

@@ -35,27 +35,28 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.csrc import CSRC
 from repro.core import paths as paths_mod
 from repro.core import schedule as schedule_mod
 from repro.core.plan import ExecutionPlan
+from repro.runtime import jit_hoisted
 from . import ref
 
 
 class SpmvOperator:
     """A prepared y = A·x / Y = A·X for repeated application.
 
-    Builds (or fetches from ``cache``) the schedule once, jits once per RHS
-    rank; call like a function with x of shape (m,) or (m, r).  ``path`` is
+    Builds (or fetches from ``cache``) the schedule once, compiles once per
+    input shape with the schedule's arrays as program arguments; call like
+    a function with x of shape (m,) or (m, r).  ``path`` is
     one of 'auto' | 'kernel' | 'segment' | 'colorful'; or pass ``plan=`` /
     use :meth:`from_plan` to pin every degree of freedom.
     """
 
     def __init__(self, M: CSRC, path: str = "auto", tm: int = 128,
-                 w_cap: int = 4096, interpret: bool = True,
+                 w_cap: int = 4096, interpret=None,
                  coloring=None, k_step: int = 1024,
                  plan: Optional[ExecutionPlan] = None,
                  schedule: Optional["schedule_mod.SpmvSchedule"] = None,
@@ -126,8 +127,8 @@ class SpmvOperator:
             spmm_fn = entry.make_spmm(
                 M, schedule, self.plan, interpret=self.interpret,
                 coloring=coloring)
-        self._fn = jax.jit(spmv_fn)
-        self._fn_mm = jax.jit(spmm_fn)
+        self._fn = jit_hoisted(spmv_fn)
+        self._fn_mm = jit_hoisted(spmm_fn)
 
     def update_values(self, M: CSRC) -> "SpmvOperator":
         """Value-refresh fast path: swap in a matrix with **identical
@@ -142,7 +143,7 @@ class SpmvOperator:
 
     @classmethod
     def from_plan(cls, M: CSRC, plan: ExecutionPlan,
-                  interpret: bool = True, coloring=None, cache=None,
+                  interpret=None, coloring=None, cache=None,
                   schedule=None) -> "SpmvOperator":
         """Strict construction: the plan's path is executed as given (a
         'kernel' plan whose window does not fit raises ValueError).  Pass
@@ -154,6 +155,10 @@ class SpmvOperator:
         if x.ndim == 2:
             return self._fn_mm(x)
         return self._fn(x)
+
+    def lower(self, x: jnp.ndarray):
+        """The lowered program ``self(x)`` runs (HLO inspection)."""
+        return (self._fn_mm if x.ndim == 2 else self._fn).lower(x)
 
     @property
     def flops_per_call(self) -> int:
@@ -168,7 +173,7 @@ class SpmvOperator:
 
 
 def spmv(M: CSRC, x: jnp.ndarray, path: str = "auto",
-         interpret: bool = True,
+         interpret=None,
          plan: Optional[ExecutionPlan] = None) -> jnp.ndarray:
     """One-shot convenience wrapper."""
     return SpmvOperator(M, path=path, interpret=interpret, plan=plan)(x)

@@ -11,18 +11,25 @@ separate axis — the roofline charges them separately).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the model code constrains shardings inside jit, which
+    # Explicit axes (jax.make_mesh's default) refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(devices: int = 8, model: int = 2):
     """Small mesh over fake devices for subprocess tests."""
     data = devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline denominators)

@@ -16,24 +16,33 @@ evaluated on MatrixStats instead of a built pack:
           ops roofline/hlo_cost.py now counts) and the dot_general
           contractions, 2·S·W·nrhs flops each — which is exactly why
           one-hot is compute-bound and stream is not;
-  predicted_s = max(bytes / HBM_BW, flops / PEAK_FLOPS_BF16), the chip
-          roofline of repro.launch.mesh (the same constants
-          roofline/analysis.py prices whole serving configs with).
+  predicted_s = max(bytes / HBM_BW, flops / PEAK_FLOPS_BF16), priced on
+          the target chip's peaks (the v5e row of ``DEVICE_PEAKS``).
 
-Absolute times are TPU-scale and the tests run in interpret mode on CPU,
-so predictions are used for *ranking* (measure only the top-K) and for
-the achieved-roofline observability ratio, never as a substitute for
-measurement.  ``roofline_fraction = predicted_s / measured_s`` — the
-fraction of the analytic roofline a measured plan actually achieved
-(1.0 = at the roofline; interpret-mode CPU numbers are far below).
+Predictions are used for *ranking* (measure only the top-K), never as a
+substitute for measurement.  ``roofline_fraction`` — the least time the
+measuring device could take for the candidate's bytes and flops over the
+measured time — is recorded only for a device kind listed in
+``DEVICE_PEAKS``; on any other device (the CPU host) it is ``None``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core.plan import ExecutionPlan, kernel_window, LANES
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+
+# Published peaks per chip, keyed by ``jax.Device.device_kind``.  A
+# roofline share is computed only against a row of this table.
+DEVICE_PEAKS: Dict[str, Dict[str, object]] = {
+    "TPU v5 lite": {
+        "hbm_bytes_per_s": 819e9,
+        "bf16_flops_per_s": 197e12,
+        "source": "Google Cloud documentation, 'TPU v5e'",
+    },
+}
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["hbm_bytes_per_s"]
+PEAK_FLOPS_BF16 = DEVICE_PEAKS["TPU v5 lite"]["bf16_flops_per_s"]
 
 
 def _round_up(v: int, m: int) -> int:
@@ -195,11 +204,17 @@ def rank_plans(stats, plans: Sequence[ExecutionPlan]
     return priced
 
 
-def roofline_fraction(est: CostEstimate, measured_s: float) -> float:
-    """Fraction of the analytic roofline the measured time achieved."""
-    if measured_s <= 0:
-        return 0.0
-    return est.predicted_s / measured_s
+def roofline_fraction(est: CostEstimate, measured_s: float,
+                      device_kind: str) -> Optional[float]:
+    """Least time ``device_kind`` could take for the candidate's bytes and
+    flops, over the measured time; ``None`` for a device kind without a
+    peak row (no number is made up from another chip's peaks)."""
+    peaks = DEVICE_PEAKS.get(device_kind)
+    if peaks is None or measured_s <= 0:
+        return None
+    least = max(est.bytes / peaks["hbm_bytes_per_s"],
+                est.flops / peaks["bf16_flops_per_s"])
+    return least / measured_s
 
 
 # ---------------------------------------------------------------------------

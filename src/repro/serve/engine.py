@@ -190,7 +190,7 @@ class SpmvServingEngine:
     """
 
     def __init__(self, cache=None, autotune: bool = False,
-                 interpret: bool = True, max_batch: int = 64,
+                 interpret=None, max_batch: int = 64,
                  mesh_p: Optional[int] = None,
                  serve_nrhs: Optional[int] = None):
         from repro.core.tuner import PlanCache

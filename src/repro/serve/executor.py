@@ -68,7 +68,7 @@ class LocalExecutor(SpmvExecutor):
     kind = "local"
 
     def __init__(self, M: CSRC, plan: ExecutionPlan, cache=None,
-                 interpret: bool = True):
+                 interpret=None):
         from repro.kernels.ops import SpmvOperator
         self.M = M
         self.op = SpmvOperator.from_plan(M, plan, interpret=interpret,
@@ -102,7 +102,7 @@ class MeshExecutor(SpmvExecutor):
     kind = "mesh"
 
     def __init__(self, M: CSRC, plan: ExecutionPlan, mesh=None,
-                 cache=None, interpret: bool = True, axis: str = "rows"):
+                 cache=None, interpret=None, axis: str = "rows"):
         if plan.strategy != "mesh":
             raise ValueError(
                 f"MeshExecutor needs a strategy='mesh' plan, got "
@@ -116,7 +116,8 @@ class MeshExecutor(SpmvExecutor):
                     f"sees {ndev}; relaunch with XLA_FLAGS="
                     f"--xla_force_host_platform_device_count={p} or "
                     "register a local plan")
-            mesh = jax.make_mesh((p,), (axis,))
+            from repro.core.distributed import make_mesh
+            mesh = make_mesh(p, axis)
         self.plan = plan
         self.mesh = mesh
         self.axis = axis
@@ -171,6 +172,11 @@ class MeshExecutor(SpmvExecutor):
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         # reduce_scatter pads y to p equal intervals; serve the true rows
         return self._fn(x)[:self.M.n]
+
+    def sharded_operands(self):
+        """The device arrays the shard_map consumes, one shard per mesh
+        device — what a placement check inspects."""
+        return tuple(self._fn.operands)
 
     def update_values(self, M: CSRC) -> "MeshExecutor":
         """Same-structure value refresh on the mesh: schedule value

@@ -40,7 +40,7 @@ def mesh_available(p: Optional[int]) -> bool:
 
 
 def resolve_plan(M: CSRC, cache=None, autotune: bool = False,
-                 interpret: bool = True,
+                 interpret=None,
                  mesh_p: Optional[int] = None,
                  nrhs: int = 1) -> ExecutionPlan:
     """The plan to serve this matrix with, honoring a mesh request when
@@ -66,7 +66,7 @@ def resolve_plan(M: CSRC, cache=None, autotune: bool = False,
 
 
 def build_executor(M: CSRC, plan: ExecutionPlan, cache=None,
-                   interpret: bool = True, mesh=None,
+                   interpret=None, mesh=None,
                    axis: str = "rows") -> SpmvExecutor:
     """Executor for a resolved plan (strategy field dispatch)."""
     if plan.strategy == "mesh":

@@ -23,20 +23,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-try:                                    # jax >= 0.6 top-level export
-    from jax import shard_map
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-
-def _pcast_varying(tree, axis: str):
-    """jax.lax.pcast(..., to="varying") where available; identity on jax
-    versions whose shard_map has no varying-axis types (<= 0.4.x)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return tree
-    return pcast(tree, (axis,), to="varying")
 
 
 def pipeline_apply(layer_fn: Callable, stacked_params, x_microbatches,
@@ -97,8 +85,7 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x_microbatches,
 
         outputs0 = jnp.zeros((m,) + xs.shape[1:], xs.dtype)
         # carries become stage-varying inside the body; mark the initials
-        # (jax >= 0.6 varying-axis typing; older shard_map needs no mark)
-        init = _pcast_varying((zero, outputs0), axis)
+        init = jax.lax.pcast((zero, outputs0), (axis,), to="varying")
         (_, outputs), _ = jax.lax.scan(tick, init, jnp.arange(ticks))
         # collect: outputs live on the last stage only
         return jax.lax.psum(jnp.where(stage_id == s - 1, outputs, 0.0),

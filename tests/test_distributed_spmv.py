@@ -29,7 +29,7 @@ def test_all_strategies_match_dense():
     print(run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import csrc, distributed as D
-        mesh = jax.make_mesh((8,), ('rows',))
+        mesh = D.make_mesh(8, 'rows')
         M = csrc.fem_band(512, 20, seed=1)
         A = csrc.to_dense(M)
         x = np.random.default_rng(0).standard_normal(512).astype(np.float32)
@@ -47,7 +47,7 @@ def test_halo_rejects_wide_band():
     print(run_with_devices("""
         import jax
         from repro.core import csrc, distributed as D
-        mesh = jax.make_mesh((8,), ('rows',))
+        mesh = D.make_mesh(8, 'rows')
         M = csrc.fem_band(64, 32, seed=0)   # band 32 > 64/8 rows per shard
         try:
             D.build_spmv_halo(M, mesh, 'rows')
@@ -65,7 +65,7 @@ def test_all_strategies_flat_kernel_match_dense():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import csrc, distributed as D
         from repro.core.plan import ExecutionPlan
-        mesh = jax.make_mesh((8,), ('rows',))
+        mesh = D.make_mesh(8, 'rows')
         M = csrc.skewed_band(512, 24, 3, seed=2)
         A = csrc.to_dense(M)
         rng = np.random.default_rng(0)
@@ -96,7 +96,7 @@ def test_all_strategies_nnzsplit_match_dense():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import csrc, distributed as D
         from repro.core.plan import ExecutionPlan
-        mesh = jax.make_mesh((8,), ('rows',))
+        mesh = D.make_mesh(8, 'rows')
         rng = np.random.default_rng(0)
         plan = ExecutionPlan(path='nnzsplit', k_step_sublanes=2)
         cases = [(csrc.powerlaw_laplacian(512, seed=1),
@@ -127,7 +127,7 @@ def test_auto_strategy_selection():
     print(run_with_devices("""
         import jax
         from repro.core import csrc, distributed as D
-        mesh = jax.make_mesh((4,), ('rows',))
+        mesh = D.make_mesh(4, 'rows')
         # banded -> halo; unbanded -> reduce_scatter
         banded = csrc.fem_band(256, 8, seed=0)
         unbanded = csrc.random_symmetric_pattern(256, 4, seed=0)
@@ -149,7 +149,7 @@ def test_distributed_cg_solver():
     print(run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import csrc, distributed as D, solvers
-        mesh = jax.make_mesh((4,), ('rows',))
+        mesh = D.make_mesh(4, 'rows')
         M = csrc.poisson2d(16)      # 256, SPD
         fn = D.build_sharded_spmv(M, mesh, 'rows', 'allreduce')
         A = csrc.to_dense(M)
@@ -167,9 +167,10 @@ def test_compressed_psum():
     print(run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp, functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
+        from repro.core import distributed as D
         from repro.optim.compress import compressed_psum
-        mesh = jax.make_mesh((8,), ('d',))
+        mesh = D.make_mesh(8, 'd')
         g = np.random.default_rng(0).standard_normal((8, 64)).astype('float32')
         for mode, tol in (('float32', 1e-6), ('bfloat16', 2e-2), ('int8', 5e-2)):
             fn = shard_map(functools.partial(compressed_psum, axis_name='d', mode=mode),

@@ -353,7 +353,7 @@ class TestFlatTuning:
 class TestFlatDistributedSingleShard:
     @pytest.mark.parametrize("strategy", D.STRATEGIES)
     def test_all_strategies_match_dense(self, strategy):
-        mesh = jax.make_mesh((1,), ("rows",))
+        mesh = D.make_mesh(1)
         M = _skewed(192, 24, seed=2)
         A = csrc.to_dense(M)
         plan = ExecutionPlan(path="flat", tm=32)
@@ -370,11 +370,30 @@ class TestFlatDistributedSingleShard:
         np.testing.assert_allclose(Y, refm, rtol=2e-4,
                                    atol=2e-4 * max(1, np.abs(refm).max()))
 
+    @pytest.mark.parametrize("strategy", D.STRATEGIES)
+    def test_stream_plan_runs_the_stream_form_per_shard(self, strategy):
+        """A stream plan runs the fused XLA form shard-locally: no Pallas
+        call in the program, the same product as the one-hot kernel."""
+        mesh = D.make_mesh(1)
+        M = _skewed(192, 24, seed=2)
+        x = jnp.asarray(np.random.default_rng(1).standard_normal(
+            M.n).astype(np.float32))
+        ys = {}
+        for variant in ("stream", "onehot"):
+            fn = D.build_sharded_spmv(
+                M, mesh, "rows", strategy,
+                plan=ExecutionPlan(path="flat", tm=32, variant=variant))
+            jaxpr = str(jax.make_jaxpr(fn)(x))
+            assert ("pallas_call" in jaxpr) == (variant == "onehot")
+            ys[variant] = np.asarray(fn(x))[:M.n]
+        np.testing.assert_allclose(ys["stream"], ys["onehot"], rtol=1e-5,
+                                   atol=1e-5 * np.abs(ys["onehot"]).max())
+
     def test_shard_layouts_are_memoized(self):
         """Repeated builder calls (serving restarts) are zero-precompute:
         the schedule comes from the cache, the per-shard flat layouts
         from their memos."""
-        mesh = jax.make_mesh((1,), ("rows",))
+        mesh = D.make_mesh(1)
         M = _skewed(160, 16, seed=3)
         plan = ExecutionPlan(path="flat", tm=32)
         cache = tuner.PlanCache()

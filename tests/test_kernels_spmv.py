@@ -119,7 +119,7 @@ def test_property_kernel_matches_dense(n, band, seed, sym):
 @pytest.mark.parametrize("nrhs", [1, 4, 8])
 def test_spmm_kernel_matches_dense(nrhs):
     """Multi-RHS Pallas kernel vs dense, across RHS widths."""
-    from repro.kernels.csrc_spmm import blockell_spmm
+    from repro.kernels.csrc_spmv import blockell_spmm
     M = csrc.fem_band(200, 12, seed=11)
     pack = blockell.pack(M, tm=16)
     A = csrc.to_dense(M)
@@ -133,7 +133,7 @@ def test_spmm_kernel_matches_dense(nrhs):
 
 
 def test_spmm_kernel_symmetric_stream():
-    from repro.kernels.csrc_spmm import blockell_spmm
+    from repro.kernels.csrc_spmv import blockell_spmm
     M = csrc.fem_band(128, 8, seed=12, numeric_symmetric=True)
     pack = blockell.pack(M, tm=16)
     A = csrc.to_dense(M)

@@ -330,7 +330,7 @@ class TestNnzSplitTuning:
 class TestNnzSplitDistributedSingleShard:
     @pytest.mark.parametrize("strategy", D.STRATEGIES)
     def test_all_strategies_bit_identical_to_dense(self, strategy):
-        mesh = jax.make_mesh((1,), ("rows",))
+        mesh = D.make_mesh(1)
         M = _unstructured(seed=15)
         A = np.asarray(csrc.to_dense(M), np.float64)
         plan = ExecutionPlan(path="nnzsplit", k_step_sublanes=2)
@@ -345,7 +345,7 @@ class TestNnzSplitDistributedSingleShard:
             Y, (A @ X.astype(np.float64)).astype(np.float32))
 
     def test_shard_layouts_are_memoized(self):
-        mesh = jax.make_mesh((1,), ("rows",))
+        mesh = D.make_mesh(1)
         M = _unstructured(seed=16)
         plan = ExecutionPlan(path="nnzsplit", k_step_sublanes=2)
         cache = tuner.PlanCache()

@@ -21,7 +21,8 @@ def test_pipeline_matches_sequential():
     code = """
         import numpy as np, jax, jax.numpy as jnp
         from repro.train.pipeline import pipeline_apply
-        mesh = jax.make_mesh((4,), ('stage',))
+        mesh = jax.make_mesh((4,), ('stage',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         L, D, M, B = 8, 16, 6, 3
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (L, D, D)) * (D ** -0.5)
@@ -55,7 +56,8 @@ def test_pipeline_collectives_are_permutes():
         import jax, jax.numpy as jnp
         from repro.train.pipeline import pipeline_apply
         from repro.roofline.hlo_cost import analyze_hlo
-        mesh = jax.make_mesh((4,), ('stage',))
+        mesh = jax.make_mesh((4,), ('stage',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         L, D, M, B = 8, 16, 6, 3
         w = jax.random.normal(jax.random.PRNGKey(0), (L, D, D))
         def layer_fn(p, x): return jnp.tanh(x @ p['w'])

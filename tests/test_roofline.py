@@ -63,9 +63,10 @@ def test_collectives_counted_in_sharded_module():
     code = """
         import jax, jax.numpy as jnp, functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
+        from repro.core.distributed import make_mesh
         from repro.roofline.hlo_cost import analyze_hlo
-        mesh = jax.make_mesh((8,), ('d',))
+        mesh = make_mesh(8, 'd')
         def inner(x):
             def body(c, _):
                 return jax.lax.psum(c, 'd'), None

@@ -482,8 +482,10 @@ def test_enumerate_proposes_int16_where_pack_supports_it():
 
 def test_int16_infeasible_when_window_overflows():
     from repro.core.plan import feasible
+    # the stream variant: a one-hot plan this wide is refused earlier, by
+    # the kernel's VMEM window gate
     wide = ExecutionPlan(path="kernel", tm=128, w_cap=1 << 20,
-                         index_dtype="int16")
+                         index_dtype="int16", variant="stream")
     assert feasible(dataclasses.replace(wide, index_dtype="int32"),
                     n=60000, m=60000, bandwidth=40000)
     assert not feasible(wide, n=60000, m=60000, bandwidth=40000)

@@ -57,7 +57,8 @@ def test_dryrun_cell_on_test_mesh():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.launch.dryrun import lower_cell
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rec = lower_cell("qwen1.5-0.5b", "train_4k", mesh, "4x2",
                          verbose=False)
         assert rec["status"] == "ok", rec
