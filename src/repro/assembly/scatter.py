@@ -58,7 +58,7 @@ from repro.core.paths import BUILD_COUNTS
 from repro import obs
 from repro.kernels import assembly_scatter as akern
 from repro.kernels.assembly_scatter import COLORED_VARIANTS  # noqa: F401
-from repro.runtime import jit_hoisted
+from repro.runtime import jit_hoisted, to_host
 from .conflict import color_elements, element_dofs
 from .mesh import Mesh
 
@@ -484,11 +484,13 @@ def scatter_serial(sched: AssemblySchedule, ke) -> np.ndarray:
 
 def values_to_csrc(sched: AssemblySchedule, vals) -> csrc.CSRC:
     """Split the unified value vector back into (ad, al, au) and wrap the
-    schedule's structure — the O(k) value-refresh constructor."""
-    vals = np.asarray(vals, np.float32)
-    n, k = sched.n, sched.k
-    return csrc.from_assembly(n, sched.ia, sched.ja,
-                              vals[:n], vals[n:n + k], vals[n + k:])
+    schedule's structure — the O(k) value-refresh constructor.  Device
+    values come to the host and go back up as the matrix's streams."""
+    with obs.span("assembly.to_csrc"):
+        vals = np.asarray(to_host(vals, "to_csrc"), np.float32)
+        n, k = sched.n, sched.k
+        return csrc.from_assembly(n, sched.ia, sched.ja,
+                                  vals[:n], vals[n:n + k], vals[n + k:])
 
 
 def assemble(sched: AssemblySchedule, ke, strategy: str = "colored",
@@ -510,17 +512,19 @@ def assemble(sched: AssemblySchedule, ke, strategy: str = "colored",
     t0 = time.perf_counter()
     with obs.span("assembly.value_refresh", strategy=strategy,
                   variant=variant):
-        if strategy == "colored":
-            vals = scatter_colored(sched, ke, variant=variant,
-                                   interpret=interpret)
-        elif strategy == "sorted":
-            vals = scatter_sorted(sched, ke)
-        elif strategy == "private":
-            vals = scatter_private(sched, ke)
-        else:
-            vals = scatter_serial(sched, ke)
-        # values_to_csrc materializes the device values, so the span and
-        # the histogram cover the actual scatter work
+        with obs.span("assembly.scatter"):
+            if strategy == "colored":
+                vals = scatter_colored(sched, ke, variant=variant,
+                                       interpret=interpret)
+            elif strategy == "sorted":
+                vals = scatter_sorted(sched, ke)
+            elif strategy == "private":
+                vals = scatter_private(sched, ke)
+            else:
+                vals = scatter_serial(sched, ke)
+            # wait here, so the scatter's device work stays in its span
+            # and ``assembly.to_csrc`` times the round trip alone
+            vals = jax.block_until_ready(vals)
         M = values_to_csrc(sched, vals)
     BUILD_COUNTS.inc("assembly_value_refresh")
     obs.histogram("assembly_scatter_seconds", strategy=strategy,
@@ -639,9 +643,6 @@ def tune_assembly(sched: AssemblySchedule, ke, cache=None,
     fractions = {key: cost_model.roofline_fraction(ests[key], t, kind)
                  for key, t in timings.items() if t > 0}
     ws, wv = winner.split("/")
-    if fractions.get(winner) is not None:
-        obs.gauge("assembly_roofline_fraction", strategy=ws,
-                  variant=wv).set(fractions[winner])
 
     result = AssemblyTuneResult(
         strategy=ws, variant=wv, timings_s=timings,

@@ -30,6 +30,8 @@ from typing import Optional, Tuple
 import numpy as np
 import jax.numpy as jnp
 
+from repro.runtime import to_host
+
 Array = np.ndarray
 
 
@@ -280,30 +282,32 @@ def to_dense(M: CSRC) -> Array:
     return A
 
 
-def row_of_slot(M: CSRC) -> Array:
-    """Expand ia to a per-slot row index (host-side helper)."""
-    ia = np.asarray(M.ia)
+def row_of_slot(M: CSRC, site: Optional[str] = None) -> Array:
+    """Expand ia to a per-slot row index (host-side helper).  ``site``,
+    here and below, names the per-call path whose device-to-host copies
+    ``host_copy_bytes_total`` counts (``runtime.to_host``)."""
+    ia = to_host(M.ia, site)
     return np.repeat(np.arange(M.n, dtype=np.int32), np.diff(ia))
 
 
-def bandwidth(M: CSRC) -> int:
+def bandwidth(M: CSRC, site: Optional[str] = None) -> int:
     """Maximum |i - j| over stored off-diagonal entries (paper §4.2 discusses
     band structure as the locality driver)."""
     if M.k == 0:
         return 0
-    ros = row_of_slot(M)
-    return int(np.max(ros - np.asarray(M.ja)))
+    ros = row_of_slot(M, site)
+    return int(np.max(ros - to_host(M.ja, site)))
 
 
-def nnz_per_row(M: CSRC) -> Array:
+def nnz_per_row(M: CSRC, site: Optional[str] = None) -> Array:
     """Full (both halves + diag + rect tail) non-zeros per row — the load
     balance metric used for nnz-guided partitioning."""
     n = M.n
-    ia = np.asarray(M.ia)
+    ia = to_host(M.ia, site)
     lower = np.diff(ia)
     upper = np.zeros(n, dtype=np.int64)
-    np.add.at(upper, np.asarray(M.ja), 1)
-    rect = np.diff(np.asarray(M.iar))
+    np.add.at(upper, to_host(M.ja, site), 1)
+    rect = np.diff(to_host(M.iar, site))
     return lower + upper + rect + 1
 
 

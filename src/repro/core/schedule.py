@@ -46,6 +46,8 @@ from typing import Optional
 import numpy as np
 import jax.numpy as jnp
 
+from repro import obs
+from repro.runtime import to_host
 from . import paths as paths_mod
 from .blockell import BlockEll
 from .coloring import Coloring
@@ -164,9 +166,10 @@ def value_digest(M: CSRC) -> str:
     values rebuilds instead of silently reusing another matrix's values.
     """
     h = hashlib.sha1()
-    for a in (M.ia, M.ja, M.ad, M.al, M.au, M.iar, M.jar, M.ar):
-        arr = np.ascontiguousarray(np.asarray(a))
-        h.update(arr.tobytes())
+    with obs.span("schedule.value_digest"):
+        for a in (M.ia, M.ja, M.ad, M.al, M.au, M.iar, M.jar, M.ar):
+            arr = np.ascontiguousarray(to_host(a, "value_digest"))
+            h.update(arr.tobytes())
     return h.hexdigest()[:16]
 
 
@@ -181,8 +184,10 @@ def structure_digest(M: CSRC) -> str:
     """
     h = hashlib.sha1()
     h.update(np.asarray([M.n, M.m], np.int64).tobytes())
-    for a in (M.ia, M.ja, M.iar, M.jar):
-        h.update(np.ascontiguousarray(np.asarray(a)).tobytes())
+    with obs.span("schedule.structure_digest"):
+        for a in (M.ia, M.ja, M.iar, M.jar):
+            h.update(np.ascontiguousarray(
+                to_host(a, "structure_digest")).tobytes())
     return h.hexdigest()[:16]
 
 
@@ -232,7 +237,6 @@ def build_schedule(M: CSRC, plan: ExecutionPlan, p: int = 8,
     plans on rectangular matrices.
     """
     from .tuner import fingerprint as _fingerprint   # local: avoid cycle
-    from repro import obs
 
     entry = paths_mod.get_path(plan.path)
     # build the path artifact first: infeasible plans raise before any
@@ -270,16 +274,17 @@ def refresh_schedule(sched: SpmvSchedule, M: CSRC) -> SpmvSchedule:
     reuse the artifact as-is.  Raises ValueError when the structures do
     not actually match.
     """
-    if structure_digest(M) != sched.structure_digest:
-        raise ValueError(
-            "refresh_schedule: matrix structure differs from the "
-            "schedule's; a full rebuild (build_schedule) is required")
-    entry = paths_mod.get_path(sched.plan.path)
-    BUILD_COUNTS.inc("value_refresh")
-    fields = ({} if entry.refresh_values is None
-              else entry.refresh_values(M, sched))
-    return dataclasses.replace(sched, value_digest=value_digest(M),
-                               **fields)
+    with obs.span("schedule.value_refresh"):
+        if structure_digest(M) != sched.structure_digest:
+            raise ValueError(
+                "refresh_schedule: matrix structure differs from the "
+                "schedule's; a full rebuild (build_schedule) is required")
+        entry = paths_mod.get_path(sched.plan.path)
+        BUILD_COUNTS.inc("value_refresh")
+        fields = ({} if entry.refresh_values is None
+                  else entry.refresh_values(M, sched))
+        return dataclasses.replace(sched, value_digest=value_digest(M),
+                                   **fields)
 
 
 def schedule_for(M: CSRC, plan: ExecutionPlan, cache=None, p: int = 8,
@@ -296,29 +301,30 @@ def schedule_for(M: CSRC, plan: ExecutionPlan, cache=None, p: int = 8,
     """
     from .tuner import fingerprint as _fingerprint
 
-    if coloring is not None or cache is None:
-        return build_schedule(M, plan, p=p, coloring=coloring)
-    fp = _fingerprint(M)
-    vd = value_digest(M)
-    hit = cache.get_schedule(fp, vd, plan, p)
-    if hit is not None:
-        return hit
-    base = cache.find_schedule_by_structure(fp, structure_digest(M), plan, p)
-    if base is not None:
-        from repro import obs
-        obs.counter("plan_cache_lookups_total", kind="schedule",
-                    outcome="refresh").inc()
-        sched = refresh_schedule(base, M)
-        # the refreshed generation supersedes the base in memory (one
-        # schedule per structure, not one per step); the npz already on
-        # disk keeps serving fresh processes, so skip re-compressing a
-        # full artifact per time step
-        cache.drop_schedule(base, remove_file=False)
-        cache.put_schedule(sched, persist=False)
-    else:
-        sched = build_schedule(M, plan, p=p)
-        cache.put_schedule(sched)
-    return sched
+    with obs.span("schedule.resolve"):
+        if coloring is not None or cache is None:
+            return build_schedule(M, plan, p=p, coloring=coloring)
+        fp = _fingerprint(M)
+        vd = value_digest(M)
+        hit = cache.get_schedule(fp, vd, plan, p)
+        if hit is not None:
+            return hit
+        base = cache.find_schedule_by_structure(fp, structure_digest(M),
+                                                plan, p)
+        if base is not None:
+            obs.counter("plan_cache_lookups_total", kind="schedule",
+                        outcome="refresh").inc()
+            sched = refresh_schedule(base, M)
+            # the refreshed generation supersedes the base in memory (one
+            # schedule per structure, not one per step); the npz already
+            # on disk keeps serving fresh processes, so skip
+            # re-compressing a full artifact per time step
+            cache.drop_schedule(base, remove_file=False)
+            cache.put_schedule(sched, persist=False)
+        else:
+            sched = build_schedule(M, plan, p=p)
+            cache.put_schedule(sched)
+        return sched
 
 
 # ---------------------------------------------------------------------------

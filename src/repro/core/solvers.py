@@ -27,6 +27,8 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 class SolveResult(NamedTuple):
     x: jnp.ndarray
@@ -71,6 +73,8 @@ def cg(spmv: Callable, b: jnp.ndarray, x0: Optional[jnp.ndarray] = None,
         return (res_of(r) > tol) & (k < maxiter)
 
     def body(state):
+        # runs only while the loop is traced: one count a trace
+        obs.count("solver_traces_total")
         x, r, p, rz, k, _ = state
         ap = spmv(p)
         alpha = rz / jnp.maximum(_dot(p, ap), 1e-30)
@@ -147,10 +151,13 @@ def cg_solve(M, b: jnp.ndarray, *, plan=None, cache=None,
     from repro.core import tuner as _tuner
     from repro.kernels.ops import SpmvOperator
 
-    if plan is None:
-        plan = _tuner.plan_for(M, cache=cache, autotune=autotune,
-                               interpret=interpret, **tune_kw)
-    op = SpmvOperator.from_plan(M, plan, interpret=interpret, cache=cache)
-    res = cg(op, b, x0=x0, tol=tol, maxiter=maxiter,
-             diag=M.ad if precondition else None)
+    with obs.span("solver.cg_solve"):
+        if plan is None:
+            plan = _tuner.plan_for(M, cache=cache, autotune=autotune,
+                                   interpret=interpret, **tune_kw)
+        op = SpmvOperator.from_plan(M, plan, interpret=interpret,
+                                    cache=cache)
+        with obs.span("solver.dispatch"):
+            res = cg(op, b, x0=x0, tol=tol, maxiter=maxiter,
+                     diag=M.ad if precondition else None)
     return res, op

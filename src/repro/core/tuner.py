@@ -115,12 +115,14 @@ def fingerprint(M: CSRC) -> str:
     plus a digest of the nnz-per-row histogram and symmetry flag.  Two
     matrices of the same class (same generator, same size) share a key, so
     solvers and the serve engine never re-tune a known class."""
-    w = nnz_per_row(M)
-    hist = np.bincount(np.minimum(w, 255).astype(np.int64), minlength=256)
-    h = hashlib.sha1()
-    h.update(hist.astype(np.int64).tobytes())
-    h.update(bytes([int(M.numerically_symmetric)]))
-    band = csrc_bandwidth(M)
+    with obs.span("tune.fingerprint"):
+        w = nnz_per_row(M, site="fingerprint")
+        hist = np.bincount(np.minimum(w, 255).astype(np.int64),
+                           minlength=256)
+        h = hashlib.sha1()
+        h.update(hist.astype(np.int64).tobytes())
+        h.update(bytes([int(M.numerically_symmetric)]))
+        band = csrc_bandwidth(M, site="fingerprint")
     return f"n{M.n}m{M.m}k{M.k}b{band}-{h.hexdigest()[:12]}"
 
 
@@ -817,11 +819,6 @@ def tune(M: CSRC,
                     pool.append(p)
             pruned = len(ranked) - len(pool)
             obs.counter("tuner_candidates_pruned_total").inc(pruned)
-            if ranked:
-                # predict-then-measure savings: fraction of the feasible
-                # pool the roofline ranking removed from the clock
-                obs.gauge("tuner_predict_measure_savings").set(
-                    pruned / len(ranked))
         best_plan, best_t, best_raw, best_op = None, float("inf"), None, None
         for p in pool:
             with obs.span("tune.measure", plan=p.key()):
@@ -852,9 +849,6 @@ def tune(M: CSRC,
             # None off the peak table: no share is made up for the CPU
             winner_frac = cost_model.roofline_fraction(
                 est, best_raw, jax.devices()[0].device_kind)
-            if winner_frac is not None:
-                obs.gauge("tuner_winner_roofline_fraction",
-                          path=best_plan.path).set(winner_frac)
             roofline_entry = {
                 "predicted_ms": round(est.predicted_s * 1e3, 6),
                 "measured_ms": round(best_raw * 1e3, 6),
@@ -989,21 +983,22 @@ def plan_for(M: CSRC,
     cache), ``autotune=False`` falls back to the measurement-free
     heuristic (still cached, so the decision is stable across calls).
     """
-    if autotune:
-        # tune() performs the cache probe itself — probing here too would
-        # double-count misses and fingerprint twice
-        return tune(M, cache=cache, **tune_kw).plan
-    fp = fingerprint(M)
-    if cache is not None:
-        hit = cache.get(fp)
-        if hit is not None:
-            return hit
-    plan = heuristic_plan(stats_of(M))
-    if cache is not None:
-        cache.put(fp, plan)
-        if cache.path is not None:
-            cache.save()
-    return plan
+    with obs.span("tune.resolve"):
+        if autotune:
+            # tune() performs the cache probe itself — probing here too
+            # would double-count misses and fingerprint twice
+            return tune(M, cache=cache, **tune_kw).plan
+        fp = fingerprint(M)
+        if cache is not None:
+            hit = cache.get(fp)
+            if hit is not None:
+                return hit
+        plan = heuristic_plan(stats_of(M))
+        if cache is not None:
+            cache.put(fp, plan)
+            if cache.path is not None:
+                cache.save()
+        return plan
 
 
 def mesh_plan_for(M: CSRC, p: int,

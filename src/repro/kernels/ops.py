@@ -37,6 +37,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.csrc import CSRC
 from repro.core import paths as paths_mod
 from repro.core import schedule as schedule_mod
@@ -102,33 +103,35 @@ class SpmvOperator:
     def _bind(self, M: CSRC, schedule, coloring=None):
         """Install the schedule and (re)build both jit'd executors through
         the registry — shared by construction and ``update_values``."""
-        self.M = M
-        self.schedule = schedule
-        self.pack = next(
-            (pk for pk in (schedule.pack, schedule.flat_pack,
-                           schedule.nnzsplit_pack) if pk is not None),
-            None)
-        self.coloring = schedule.coloring if coloring is None else coloring
+        with obs.span("kernels.bind"):
+            self.M = M
+            self.schedule = schedule
+            self.pack = next(
+                (pk for pk in (schedule.pack, schedule.flat_pack,
+                               schedule.nnzsplit_pack) if pk is not None),
+                None)
+            self.coloring = (schedule.coloring if coloring is None
+                             else coloring)
 
-        # registry dispatch: the path's KernelPath entry builds both
-        # executors from the schedule artifact (no per-path if chain here)
-        try:
-            entry = paths_mod.get_path(self.path)
-        except KeyError as e:
-            raise ValueError(str(e)) from None
-        spmv_fn = entry.make_spmv(
-            M, schedule, self.plan, interpret=self.interpret,
-            coloring=coloring)
-        if entry.make_spmm is entry.make_spmv:
-            # one factory registered for both shapes (e.g. colorful):
-            # construct once, share the executor
-            spmm_fn = spmv_fn
-        else:
-            spmm_fn = entry.make_spmm(
+            # registry dispatch: the path's KernelPath entry builds both
+            # executors from the schedule artifact (no per-path if chain)
+            try:
+                entry = paths_mod.get_path(self.path)
+            except KeyError as e:
+                raise ValueError(str(e)) from None
+            spmv_fn = entry.make_spmv(
                 M, schedule, self.plan, interpret=self.interpret,
                 coloring=coloring)
-        self._fn = jit_hoisted(spmv_fn)
-        self._fn_mm = jit_hoisted(spmm_fn)
+            if entry.make_spmm is entry.make_spmv:
+                # one factory registered for both shapes (e.g. colorful):
+                # construct once, share the executor
+                spmm_fn = spmv_fn
+            else:
+                spmm_fn = entry.make_spmm(
+                    M, schedule, self.plan, interpret=self.interpret,
+                    coloring=coloring)
+            self._fn = jit_hoisted(spmv_fn)
+            self._fn_mm = jit_hoisted(spmm_fn)
 
     def update_values(self, M: CSRC) -> "SpmvOperator":
         """Value-refresh fast path: swap in a matrix with **identical

@@ -1,10 +1,10 @@
 """repro.obs — dependency-free metrics + tracing spine.
 
 One process-global :data:`REGISTRY` of labeled Counter/Gauge/Histogram
-families, nested wall-clock :func:`span`\\ s with a ring-buffer trace log,
-JSON + Prometheus exporters, and a ``snapshot()/diff`` API so tests and
-benchmarks assert on deltas.  See docs/DESIGN.md §9 for the metric-name
-table and label conventions.
+families, nested :func:`span`\\ s recorded in a ring-buffer trace log and
+as profiler host annotations, JSON + Prometheus exporters, and a
+``snapshot()/diff`` API so tests and benchmarks assert on deltas.  See
+docs/DESIGN.md §9 for the metric-name table and label conventions.
 
 Quickstart::
 
@@ -34,12 +34,20 @@ from .metrics import (DEFAULT_BUCKETS, MAX_CARDINALITY, OVERFLOW_LABEL,
                       quantile_from_counts, set_enabled)
 from .provenance import (MISMATCH_FIELDS, env_mismatches,
                          environment_provenance, git_sha)
-from .tracing import (Span, clear_trace, set_trace_capacity, span, trace)
+from .tracing import (Span, clear_trace, set_trace_capacity, span, tally,
+                      trace)
 
 
 def counter(name: str, _help: str = "", **labels) -> Counter:
     """Counter child of the global registry for these label values."""
     return REGISTRY.counter(name, _help=_help, **labels)
+
+
+def count(name: str, v: float = 1.0, **labels):
+    """Increment counter ``name`` and tally ``v`` under ``name`` on every
+    open span, so a span's record holds what its subtree added."""
+    REGISTRY.counter(name, **labels).inc(v)
+    tally(name, v)
 
 
 def gauge(name: str, _help: str = "", **labels) -> Gauge:

@@ -6,7 +6,7 @@ children are addressed by label values:
 
     obs.counter("plan_cache_lookups_total", kind="plan", outcome="hit").inc()
     obs.histogram("serve_execute_seconds", matrix_id=m, path=p).observe(dt)
-    obs.gauge("tuner_winner_roofline_fraction", path="kernel").set(0.31)
+    obs.counter("host_copy_bytes_total", site="to_csrc").inc(nbytes)
 
 Design constraints (docs/DESIGN.md §9):
 

@@ -15,6 +15,9 @@ taken as options, and the compile helpers every executor shares.
   jit_hoisted      ``jax.jit`` for executors that close over large
                    arrays: the arrays become program arguments, not
                    constants baked into the executable.
+  to_host          ``np.asarray``; given a site on a per-call host path,
+                   a device array's bytes are counted in
+                   ``host_copy_bytes_total{site}``.
 """
 from __future__ import annotations
 
@@ -23,7 +26,10 @@ from pathlib import Path
 from typing import Optional
 
 import jax
+import numpy as np
 from jax.extend.core import ClosedJaxpr, jaxpr_as_fun
+
+from repro import obs
 
 CHECKOUT = Path(__file__).resolve().parents[2]
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -83,3 +89,13 @@ def jit_hoisted(fn):
 
     call.lower = lower
     return call
+
+
+def to_host(a, site: Optional[str] = None) -> np.ndarray:
+    """``np.asarray(a)``.  With a ``site``, a device array's bytes are
+    counted under ``host_copy_bytes_total{site}``; a host array copies
+    nothing."""
+    if site is not None and isinstance(a, jax.Array):
+        obs.count("host_copy_bytes_total", a.size * a.dtype.itemsize,
+                  site=site)
+    return np.asarray(a)

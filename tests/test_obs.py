@@ -127,6 +127,246 @@ def test_spans_disabled_are_noops():
     assert not [t for t in obs.trace() if t["name"] == "ghost"]
 
 
+def test_nested_spans_carry_parent_ids():
+    obs.clear_trace()
+    with obs.span("outer"):
+        with obs.span("first"):
+            with obs.span("deep"):
+                pass
+        with obs.span("second"):
+            pass
+    recs = {r["name"]: r for r in obs.trace()}
+    outer = recs["outer"]
+    assert outer["parent_id"] is None and outer["depth"] == 0
+    assert recs["first"]["parent_id"] == outer["id"]
+    assert recs["second"]["parent_id"] == outer["id"]
+    assert recs["deep"]["parent_id"] == recs["first"]["id"]
+    assert len({r["id"] for r in recs.values()}) == 4
+    # t0 is on the perf_counter clock, children inside their parent
+    assert outer["t0"] <= recs["first"]["t0"] <= recs["deep"]["t0"]
+    assert (recs["second"]["t0"] + recs["second"]["duration_s"]
+            <= outer["t0"] + outer["duration_s"])
+    assert outer["t0"] <= time.perf_counter()
+
+
+def test_count_tallies_on_every_open_span():
+    obs.clear_trace()
+    s0 = obs.snapshot()
+    with obs.span("outer"):
+        obs.count("tally_probe_total", 3, site="a")
+        with obs.span("inner"):
+            obs.count("tally_probe_total", 4, site="b")
+    obs.count("tally_probe_total", 5, site="a")     # no span open
+    recs = {r["name"]: r for r in obs.trace()}
+    assert recs["outer"]["counts"] == {"tally_probe_total": 7}
+    assert recs["inner"]["counts"] == {"tally_probe_total": 4}
+    d = obs.snapshot().diff(s0)
+    assert d.value("tally_probe_total", site="a") == 8
+    assert d.value("tally_probe_total", site="b") == 4
+
+
+def _host_events(log_dir):
+    """Every host event name in the one ``.xplane.pb`` under log_dir,
+    with its stats."""
+    import glob
+    import os
+    import jax
+    (f,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                     recursive=True)
+    pd = jax.profiler.ProfileData.from_file(f)
+    return [(e.name, {k: v for k, v in e.stats}) for p in pd.planes
+            if p.name.startswith("/host") for ln in p.lines
+            for e in ln.events]
+
+
+def test_span_is_a_profiler_host_event(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("tests.profiled", plan="segment"):
+            with obs.span("tests.profiled_inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    events = dict(_host_events(tmp_path))
+    # the event keeps the bare span name; labels ride as its metadata
+    assert events["tests.profiled"].get("plan") == "segment"
+    assert "tests.profiled_inner" in events
+
+
+def test_span_that_raises_closes_its_profiler_event(tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with pytest.raises(ValueError):
+            with obs.span("tests.raised"):
+                raise ValueError("x")
+    finally:
+        jax.profiler.stop_trace()
+    assert "tests.raised" in dict(_host_events(tmp_path))
+
+
+def test_disabled_span_reaches_neither_sink(tmp_path):
+    import jax
+    obs.clear_trace()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.disabled():
+            with obs.span("tests.ghost_profiled"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert not obs.trace("tests.ghost_profiled")
+    assert "tests.ghost_profiled" not in dict(_host_events(tmp_path))
+
+
+def test_obs_imports_and_spans_without_jax():
+    import subprocess
+    import sys
+    import os
+    code = (
+        "import sys\n"
+        "from repro import obs\n"
+        "with obs.span('no.jax'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "print(obs.trace()[0]['name'])\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(os.getcwd(), "src"),
+         os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", code],
+                         capture_output=True, text=True, env=env,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "no.jax"
+
+
+# ---------------------------------------------------------------------------
+# per-call spans and counters: solver, tuner, schedule, kernels, assembly
+# ---------------------------------------------------------------------------
+
+def _tree(recs, parent=None):
+    """(name, children) of the records under ``parent``, in start order."""
+    kids = sorted((r for r in recs if r["parent_id"] == parent),
+                  key=lambda r: r["t0"])
+    return [(r["name"], _tree(recs, r["id"])) for r in kids]
+
+
+FP = ("tune.fingerprint", [])
+RESOLVED_PLAN = ("tune.resolve", [FP])
+CG_CALL_TAIL = [("kernels.bind", []), ("solver.dispatch", [])]
+
+
+def _segment_solve(M, b, cache):
+    from repro.core import solvers
+    from repro.core.plan import ExecutionPlan
+    return solvers.cg_solve(M, b, cache=cache, autotune=True,
+                            candidates=[ExecutionPlan(path="segment")],
+                            tol=0.0, maxiter=3)
+
+
+def test_cg_solve_span_tree_and_one_trace_a_call():
+    import jax.numpy as jnp
+    from repro.core import csrc, tuner
+    M = csrc.poisson2d(8)
+    cache = tuner.PlanCache()
+    b = jnp.ones(M.n, jnp.float32)
+    _segment_solve(M, b, cache)                 # tunes, builds, caches
+    obs.clear_trace()
+    s0 = obs.snapshot()
+    for _ in range(2):
+        _segment_solve(M, b, cache)
+    call = ("solver.cg_solve",
+            [RESOLVED_PLAN,
+             ("schedule.resolve", [FP, ("schedule.value_digest", [])])]
+            + CG_CALL_TAIL)
+    assert _tree(obs.trace()) == [call, call]
+    d = obs.snapshot().diff(s0)
+    assert d.value("solver_traces_total") == 2
+    # every span's record tallies what its subtree copied and traced
+    (root, _) = obs.trace("solver.cg_solve")
+    assert root["counts"]["solver_traces_total"] == 1
+    assert root["counts"]["host_copy_bytes_total"] == (
+        d.total("host_copy_bytes_total") / 2)
+
+
+def test_host_copy_bytes_of_fingerprint_and_digests():
+    from repro.core import csrc, schedule, tuner
+    M = csrc.poisson2d(8)
+    s0 = obs.snapshot()
+    tuner.fingerprint(M)
+    schedule.value_digest(M)
+    schedule.structure_digest(M)
+    d = obs.snapshot().diff(s0)
+
+    def nb(*arrays):
+        return sum(a.size * a.dtype.itemsize for a in arrays)
+    assert d.value("host_copy_bytes_total", site="fingerprint") == (
+        2 * nb(M.ia, M.ja) + nb(M.iar))
+    assert d.value("host_copy_bytes_total", site="value_digest") == nb(
+        M.ia, M.ja, M.ad, M.al, M.au, M.iar, M.jar, M.ar)
+    assert d.value("host_copy_bytes_total", site="structure_digest") == nb(
+        M.ia, M.ja, M.iar, M.jar)
+    # without a site the host helpers copy uncounted
+    s1 = obs.snapshot()
+    csrc.nnz_per_row(M)
+    csrc.bandwidth(M)
+    assert obs.snapshot().diff(s1).total("host_copy_bytes_total") == 0
+    # host arrays copy nothing
+    s1 = obs.snapshot()
+    tuner.fingerprint(csrc.CSRC(**{
+        f: (np.asarray(getattr(M, f)) if f not in ("n", "m") else
+            getattr(M, f)) for f in ("n", "m", "ad", "ia", "ja", "al",
+                                     "au", "iar", "jar", "ar")}))
+    assert obs.snapshot().diff(s1).total("host_copy_bytes_total") == 0
+
+
+def test_assemble_spans_and_value_copy():
+    from repro.assembly import (assemble, assembly_schedule_for,
+                                mesh as amesh)
+    mesh = amesh.grid_tri(5)
+    sched = assembly_schedule_for(mesh)
+    ke = amesh.poisson_stiffness(mesh, mass=0.5)
+    obs.clear_trace()
+    s0 = obs.snapshot()
+    assemble(sched, ke, strategy="sorted")
+    assert _tree(obs.trace()) == [("assembly.value_refresh",
+                                   [("assembly.scatter", []),
+                                    ("assembly.to_csrc", [])])]
+    d = obs.snapshot().diff(s0)
+    assert d.value("host_copy_bytes_total", site="to_csrc") == (
+        4 * (sched.n + 2 * sched.k))
+
+
+def test_cg_solve_on_new_values_refreshes_the_schedule():
+    import jax.numpy as jnp
+    from repro.assembly import (assemble, assembly_schedule_for,
+                                mesh as amesh)
+    from repro.core import tuner
+    mesh = amesh.grid_tri(5)
+    sched = assembly_schedule_for(mesh)
+    cache = tuner.PlanCache()
+
+    def step(mass):
+        M = assemble(sched, amesh.poisson_stiffness(mesh, mass=mass),
+                     strategy="sorted")
+        return _segment_solve(M, jnp.ones(M.n, jnp.float32), cache)
+    step(0.5)
+    obs.clear_trace()
+    step(0.75)
+    digests = [("schedule.structure_digest", []),
+               ("schedule.value_digest", [])]
+    solve = ("solver.cg_solve",
+             [RESOLVED_PLAN,
+              ("schedule.resolve",
+               [FP, ("schedule.value_digest", []),
+                ("schedule.structure_digest", []),
+                ("schedule.value_refresh", digests)])]
+             + CG_CALL_TAIL)
+    assert _tree(obs.trace())[1] == solve
+
+
 # ---------------------------------------------------------------------------
 # snapshot / diff / exporters
 # ---------------------------------------------------------------------------
