@@ -372,6 +372,99 @@ register_path(KernelPath(
 
 
 # ---------------------------------------------------------------------------
+# 'ell' — row-padded CSRC product: the row's own term as a dense reduction,
+# the transpose term as the one scatter-add (square matrices)
+# ---------------------------------------------------------------------------
+
+# Padding gate: the planes hold n·W slots (W = the most lower slots of any
+# row); above this multiple of k the padding costs more than the row
+# term's gather and scatter-add it saves, and the path is neither
+# proposed nor built.
+ELL_PAD_MAX = 1.5
+
+
+def ell_padding_ok(n: int, width: int, k: int) -> bool:
+    return n * width <= ELL_PAD_MAX * k
+
+
+def _ell_candidates(stats, space):
+    if stats.n != stats.m or not ell_padding_ok(stats.n,
+                                                stats.lower_row_max,
+                                                stats.k):
+        return []
+    return [ExecutionPlan(path="ell", w_cap=space.w_cap,
+                          partition=space.partition,
+                          accumulation=space.accumulation)]
+
+
+def _ell_build(M, plan, coloring=None) -> dict:
+    import numpy as np
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    if not M.is_square:
+        raise ValueError(
+            "ell path pads the square CSRC part only; "
+            "use 'segment' for rectangular matrices")
+    width = int(np.diff(np.asarray(M.ia)).max(initial=0))
+    if not ell_padding_ok(M.n, width, M.k):
+        raise ValueError(f"ell planes of {M.n}x{width} pad more than "
+                         f"{ELL_PAD_MAX}x the {M.k} lower slots")
+    BUILD_COUNTS.inc("ell_pack")
+    return {"ell_pack": ell_mod.pack_ell(M)}
+
+
+def _ell_save(sched):
+    import numpy as np
+    pk = sched.ell_pack
+    meta = {"ell_pack": {"n": pk.n, "width": pk.width}}
+    arrays = dict(ell_ja=np.asarray(pk.ja), ell_al=np.asarray(pk.al),
+                  ell_plane_of_slot=np.asarray(pk.plane_of_slot))
+    if pk.au is not None:
+        arrays["ell_au"] = np.asarray(pk.au)
+    return meta, arrays
+
+
+def _ell_load(meta, z) -> dict:
+    import jax.numpy as jnp
+    from repro.kernels.csrc_spmv_ell import EllPack
+    pm = meta["ell_pack"]
+    files = getattr(z, "files", z)
+    return {"ell_pack": EllPack(
+        n=pm["n"], width=pm["width"], ja=jnp.asarray(z["ell_ja"]),
+        al=jnp.asarray(z["ell_al"]),
+        au=jnp.asarray(z["ell_au"]) if "ell_au" in files else None,
+        plane_of_slot=jnp.asarray(z["ell_plane_of_slot"]))}
+
+
+def _ell_refresh(M, sched) -> dict:
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return {"ell_pack": ell_mod.refresh_ell_values(sched.ell_pack, M)}
+
+
+def _ell_make_spmv(M, schedule, plan, *, interpret=None, coloring=None):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return functools.partial(ell_mod.ell_spmv, schedule.ell_pack, M.ad)
+
+
+def _ell_make_spmm(M, schedule, plan, *, interpret=None, coloring=None):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return functools.partial(ell_mod.ell_spmm, schedule.ell_pack, M.ad)
+
+
+register_path(KernelPath(
+    name="ell",
+    feasible=_square_feasible,
+    candidates=_ell_candidates,
+    artifact_fields=_empty_fields,
+    build_artifact=_ell_build,
+    save_artifact=_ell_save,
+    load_artifact=_ell_load,
+    make_spmv=_ell_make_spmv,
+    make_spmm=_ell_make_spmm,
+    refresh_values=_ell_refresh,
+))
+
+
+# ---------------------------------------------------------------------------
 # 'kernel' — rectangular-grid block-ELL Pallas kernel (banded matrices)
 # ---------------------------------------------------------------------------
 

@@ -93,6 +93,7 @@ class SpmvSchedule:
     color_slot_ptr: Optional[np.ndarray] = None
     flat_pack: Optional[object] = None       # 'flat' path (FlatBlockEll)
     nnzsplit_pack: Optional[object] = None   # 'nnzsplit' path (NnzSplitPack)
+    ell_pack: Optional[object] = None        # 'ell' path (EllPack)
     # exact-structure digest (ia/ja/iar/jar only — values excluded): the
     # key of the value-refresh fast path (refresh_schedule)
     structure_digest: str = ""

@@ -96,6 +96,7 @@ class MatrixStats:
     nnz_row_mean: float
     nnz_row_dev: float            # std of nnz per row (load-balance driver)
     numerically_symmetric: bool
+    lower_row_max: int            # most lower slots of any row (ell width)
 
 
 def stats_of(M: CSRC) -> MatrixStats:
@@ -107,6 +108,7 @@ def stats_of(M: CSRC) -> MatrixStats:
         nnz_row_mean=float(w.mean()),
         nnz_row_dev=float(w.std()),
         numerically_symmetric=bool(M.numerically_symmetric),
+        lower_row_max=int(np.diff(np.asarray(M.ia)).max(initial=0)),
     )
 
 
@@ -349,14 +351,17 @@ class PlanCache:
         {"version": 1,
          "entries": {"<fingerprint>": {"plan": {...ExecutionPlan fields...},
                                        "best_us": 12.3,
-                                       "timings_us": {"<plan key>": 12.3}}}}
+                                       "timings_us": {"<plan key>": 12.3},
+                                       "pool_paths": ["ell", "segment"]}}}
 
     A ``get`` hit returns the stored plan without any re-measurement; the
     hit/miss counters let tests (and ops dashboards) assert that.  Entries
     carry a ``measured`` flag: heuristic (unmeasured) plans cached by
     ``plan_for(autotune=False)`` are visible to heuristic lookups but do
     NOT satisfy ``tune()``, which would otherwise report a never-measured
-    plan as the argmin.
+    plan as the argmin.  Nor does a measured entry whose ``pool_paths``
+    lacks a path the current pool offers (or an entry older than the
+    record): it never measured that path, so ``tune()`` measures again.
 
     Next to each plan the cache stores the **schedule artifact**
     (core/schedule.py): the block-ELL pack, row partition/halo ranges, and
@@ -384,6 +389,8 @@ class PlanCache:
         self.shard_layouts: Dict[str, object] = {}
         self.shard_layout_hits = 0
         self.shard_layout_misses = 0
+        # (fingerprint, RHS widths) -> paths the enumerated pool offers
+        self.offered_paths: Dict[tuple, frozenset] = {}
         if path is not None and os.path.exists(path):
             self._read(path)
 
@@ -408,10 +415,17 @@ class PlanCache:
         os.replace(tmp, path)
         self.path = path
 
-    def get(self, fp: str,
-            require_measured: bool = False) -> Optional[ExecutionPlan]:
+    def get(self, fp: str, require_measured: bool = False,
+            offered: Optional[Callable[[], frozenset]] = None
+            ) -> Optional[ExecutionPlan]:
+        """The stored plan, or None.  ``offered`` gives the paths the
+        caller's candidate pool offers now: an entry whose recorded pool
+        lacks one of them (or records none) never measured that path, and
+        counts as a miss."""
         e = self.entries.get(fp)
-        if e is None or (require_measured and not e.get("measured")):
+        if e is None or (require_measured and not e.get("measured")) or (
+                offered is not None
+                and not offered() <= set(e.get("pool_paths", ()))):
             self.misses += 1
             obs.counter("plan_cache_lookups_total", kind="plan",
                         outcome="miss").inc()
@@ -432,8 +446,11 @@ class PlanCache:
     def put(self, fp: str, plan: ExecutionPlan,
             timings_s: Optional[Dict[str, float]] = None,
             predictions_s: Optional[Dict[str, float]] = None,
-            roofline: Optional[Dict[str, float]] = None):
-        """``predictions_s`` (plan key -> analytic seconds) and
+            roofline: Optional[Dict[str, float]] = None,
+            pool_paths: Optional[frozenset] = None):
+        """``pool_paths`` records the paths of the candidate pool a
+        measured plan won against (``get(offered=...)`` reads it).
+        ``predictions_s`` (plan key -> analytic seconds) and
         ``roofline`` ({'predicted_ms', 'measured_ms', 'roofline_fraction'}
         of the winner) are the predict-then-measure provenance: the cache
         records what the cost model claimed next to what the clock said —
@@ -442,6 +459,8 @@ class PlanCache:
         entry: Dict = {"plan": plan.to_dict(),
                        "measured": bool(timings_s),
                        "env": dict(obs.environment_provenance())}
+        if pool_paths is not None:
+            entry["pool_paths"] = sorted(pool_paths)
         if timings_s:
             entry["timings_us"] = {k: round(v * 1e6, 3)
                                    for k, v in timings_s.items()}
@@ -698,6 +717,22 @@ class TuneResult:
     roofline_fraction: Optional[float] = None
 
 
+def _offered_paths(M: CSRC, fp: str, cache: PlanCache, candidates,
+                   nrhs_options) -> frozenset:
+    """The paths the candidate pool of this tuning request offers.  The
+    enumerated pool's are kept per (fingerprint, RHS widths) in the
+    cache: computing the statistics on every hit would put host work on
+    each call's path."""
+    if candidates is not None:
+        return frozenset(p.path for p in candidates)
+    key = (fp, tuple(nrhs_options))
+    if key not in cache.offered_paths:
+        cache.offered_paths[key] = frozenset(
+            p.path for p in enumerate_plans(stats_of(M),
+                                            nrhs_options=key[1]))
+    return cache.offered_paths[key]
+
+
 def tune(M: CSRC,
          cache: Optional[PlanCache] = None,
          x: Optional[np.ndarray] = None,
@@ -752,9 +787,14 @@ def tune(M: CSRC,
     from repro.kernels.ops import SpmvOperator   # local: avoid import cycle
 
     fp = fingerprint(M)
+
+    def offered():
+        return _offered_paths(M, fp, cache, candidates, nrhs_options)
+
     if cache is not None and not mesh_ps:
-        # a heuristic (unmeasured) entry must not satisfy a tune request
-        hit = cache.get(fp, require_measured=True)
+        # a heuristic (unmeasured) entry must not satisfy a tune request,
+        # nor one measured before a path of the current pool existed
+        hit = cache.get(fp, require_measured=True, offered=offered)
         if hit is not None:
             return TuneResult(plan=hit, fingerprint=fp, timings_s={},
                               cached=True)
@@ -784,7 +824,7 @@ def tune(M: CSRC,
 
     cached_local = False
     if cache is not None and mesh_ps:
-        hit = cache.get(fp, require_measured=True)
+        hit = cache.get(fp, require_measured=True, offered=offered)
     else:
         hit = None
 
@@ -856,7 +896,8 @@ def tune(M: CSRC,
             }
         if cache is not None:
             cache.put(fp, best_plan, timings, predictions_s=predictions,
-                      roofline=roofline_entry)
+                      roofline=roofline_entry,
+                      pool_paths=frozenset(p.path for p in cands))
             # store the winner's schedule next to the plan: serving
             # processes constructing this (matrix, plan) never re-pack or
             # re-color

@@ -19,6 +19,11 @@ Registered paths (core/paths.py):
   * 'flat'     flat-grid block-ELL Pallas kernel — per-tile-exact k-steps,
     no cross-tile ELL padding (skewed row-length matrices);
   * 'segment'  segment-sum jnp path (any matrix, incl. the rectangular tail);
+  * 'ell'      row-padded CSRC product: the paper's local-buffer strategy
+    restricted to the one term that can race.  The row's own term is a
+    dense reduction over slot-major (W, n) planes; only the transpose
+    term is scatter-added (square matrices whose padding n·W stays under
+    ``paths.ELL_PAD_MAX``·k);
   * 'colorful' the paper's §3.2 color-by-color permutation writes, over the
     schedule's precomputed per-color slot batches.
 
@@ -103,7 +108,8 @@ class SpmvOperator:
     def _bind(self, M: CSRC, schedule, coloring=None):
         """Install the schedule and (re)build both jit'd executors through
         the registry — shared by construction and ``update_values``."""
-        with obs.span("kernels.bind"):
+        with obs.span("kernels.bind", path=self.path):
+            obs.count("spmv_bind_total", path=self.path)
             self.M = M
             self.schedule = schedule
             self.pack = next(

@@ -6,7 +6,9 @@ ahead of time at the widths ``chip_smoke.py`` runs: fem_band(2**20, 16)
 (n = 1 048 576 rows, bandwidth 16, ~10 k-step slots per row) for the SpMV
 and SpMM kernels, and grid_tet(48) for the assembly grid.  Interpret-mode
 tests cannot see what this catches: block shapes that break the 8×128
-tiling rule, VMEM over-subscription, ops Mosaic cannot lower.
+tiling rule, VMEM over-subscription, ops Mosaic cannot lower.  The
+row-padded ``ell`` product, plain XLA, is compiled at the hpcg27 cell's
+104³ rows, the main path of both benchmark cells.
 
 The topology is described inside a module-scoped fixture (never at
 import), and everything is compiled from ShapeDtypeStructs in this
@@ -23,6 +25,7 @@ from repro.core.blockell import BlockEll
 from repro.kernels import assembly_scatter as akern
 from repro.kernels.csrc_spmv import (ONEHOT_MAX_WINDOW, blockell_spmm,
                                      blockell_spmv)
+from repro.kernels.csrc_spmv_ell import EllPack, ell_spmm, ell_spmv
 from repro.kernels.csrc_spmv_flat import FlatBlockEll, flat_spmm, flat_spmv
 from repro.kernels.csrc_spmv_nnzsplit import (NnzSplitPack, nnzsplit_spmm,
                                               nnzsplit_spmv)
@@ -31,6 +34,8 @@ N = 2 ** 20                 # chip_smoke phase A/B rows
 SERVE_NRHS = 8              # SpmvServingEngine.serve_nrhs default
 TET_SIZE = 1_707_697        # grid_tet(48): n + 2k of the unified vector
 TET_CONTRIBS = 10_616_832   # grid_tet(48): ne * edof^2
+HPCG_ROWS = 104 ** 3        # the hpcg27 cell's 27-point stencil
+HPCG_WIDTH = 13             # its most lower slots of a row
 
 
 @pytest.fixture(scope="module")
@@ -136,6 +141,26 @@ def test_nnzsplit_kernel_compiles(shape, ks, r_pad, nrhs, idt):
         shape((N,), jnp.float32),
         shape((N, nrhs) if nrhs > 1 else (N,), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("nrhs", [1, SERVE_NRHS])
+def test_ell_product_compiles(shape, nrhs):
+    """The row-padded product is plain XLA: one gather with a reduction
+    over the planes and one scatter-add."""
+    n, w = HPCG_ROWS, HPCG_WIDTH
+
+    def run(ja, al, ad, x):
+        pk = EllPack(n=n, width=w, ja=ja, al=al, au=None,
+                     plane_of_slot=None)
+        if x.ndim == 2:
+            return ell_spmm(pk, ad, x)
+        return ell_spmv(pk, ad, x)
+
+    txt = _compiled_text(run, shape((w, n), jnp.int32),
+                         shape((w, n), jnp.float32), shape((n,), jnp.float32),
+                         shape((n, nrhs) if nrhs > 1 else (n,),
+                               jnp.float32))
+    assert "scatter" in txt and "gather" in txt
 
 
 def test_assembly_onehot_grid_compiles(shape):
