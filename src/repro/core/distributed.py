@@ -56,6 +56,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
+from repro import obs
+
 from .csrc import CSRC, bandwidth
 from .plan import ExecutionPlan
 from . import paths as paths_mod
@@ -79,6 +81,15 @@ def make_mesh(p: int, axis: str = "rows", devices=None) -> Mesh:
 def _bc(v: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Broadcast per-slot/per-row values over RHS columns when x is (n, B)."""
     return v[:, None] if x.ndim == 2 else v
+
+
+def _place(arrays, mesh: Mesh, spec: P):
+    """The shard layout's arrays put on the mesh, their bytes counted in
+    ``mesh_place_bytes_total{site="layout"}``."""
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree_util.tree_leaves(arrays))
+    obs.count("mesh_place_bytes_total", nbytes, site="layout")
+    return jax.device_put(arrays, jax.sharding.NamedSharding(mesh, spec))
 
 
 def _schedule(M: CSRC, p: int, accumulation: str,
@@ -155,9 +166,7 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
             x = args[-1]
             return reduce_y(local_y(*args), x.ndim)
 
-        sharded = jax.device_put(
-            sup.shard_arrays(fs),
-            jax.sharding.NamedSharding(mesh, P(axis)))
+        sharded = _place(sup.shard_arrays(fs), mesh, P(axis))
         in_specs = tuple(sup.shard_specs(axis)) + (P(),)
     else:
         ss = (layout if layout is not None
@@ -172,9 +181,8 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
                                         ja[0], num_segments=n)
             return reduce_y(y, x.ndim)
 
-        sharded = jax.device_put(
-            (ss.row_idx, ss.ja, ss.al, ss.au, ss.ad_shard),
-            jax.sharding.NamedSharding(mesh, P(axis, None)))
+        sharded = _place((ss.row_idx, ss.ja, ss.al, ss.au, ss.ad_shard),
+                         mesh, P(axis, None))
         in_specs = (P(axis, None),) * 5 + (P(),)
 
     # x is replicated (P() leaves trailing dims unsharded), so one
@@ -236,9 +244,7 @@ def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
                 y_ext[:h], axis, [(i, (i - 1) % p) for i in range(p)])
             return y_ext[h:].at[-h:].add(from_right)
 
-        sharded = jax.device_put(
-            sup.shard_arrays(lay),
-            jax.sharding.NamedSharding(mesh, P(axis)))
+        sharded = _place(sup.shard_arrays(lay), mesh, P(axis))
         slot_specs = tuple(sup.shard_specs(axis))
     else:
         lay = (layout if layout is not None
@@ -263,9 +269,8 @@ def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
                 y_ext[:h], axis, [(i, (i - 1) % p) for i in range(p)])
             return y_ext[h:].at[-h:].add(from_right)
 
-        sharded = jax.device_put(
-            (lay.row_loc, lay.col_rel, lay.al, lay.au, lay.ad),
-            jax.sharding.NamedSharding(mesh, P(axis, None)))
+        sharded = _place((lay.row_loc, lay.col_rel, lay.al, lay.au, lay.ad),
+                         mesh, P(axis, None))
         slot_specs = (P(axis, None),) * 5
 
     def make_fn(two_d: bool):

@@ -574,14 +574,14 @@ def build_halo_layout(M: CSRC, p: int, cache=None) -> HaloLayout:
     col_rel = np.full((p, smax), ns + h - 1, np.int32)   # inert target
     al_s = np.zeros((p, smax), np.float32)
     au_s = np.zeros((p, smax), np.float32)
-    fill = np.zeros(p, np.int64)
-    for idx in np.argsort(shard_of_slot, kind="stable"):
-        t = int(shard_of_slot[idx])
-        q = int(fill[t]); fill[t] += 1
-        row_loc[t, q] = int(ros[idx]) - t * ns
-        col_rel[t, q] = int(ja[idx]) - (t * ns - h)      # in [0, ns+h)
-        al_s[t, q] = al_np[idx]
-        au_s[t, q] = au_np[idx]
+    # each shard's slots in slot order, at consecutive places of its row
+    order = np.argsort(shard_of_slot, kind="stable")
+    t = shard_of_slot[order]
+    q = np.arange(order.size) - (np.cumsum(counts) - counts)[t]
+    row_loc[t, q] = ros[order] - t * ns
+    col_rel[t, q] = ja[order] - (t * ns - h)             # in [0, ns+h)
+    al_s[t, q] = al_np[order]
+    au_s[t, q] = au_np[order]
     ad_pad = np.zeros(n_pad, np.float32)
     ad_pad[:n] = np.asarray(M.ad)
     out = HaloLayout(p=p, ns=ns, h=h, n_pad=n_pad,

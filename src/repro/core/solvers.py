@@ -136,6 +136,7 @@ def cg_solve(M, b: jnp.ndarray, *, plan=None, cache=None,
              autotune: bool = False, interpret=None,
              x0: Optional[jnp.ndarray] = None, tol: float = 1e-6,
              maxiter: int = 1000, precondition: bool = True,
+             mesh_p: Optional[int] = None,
              **tune_kw) -> Tuple[SolveResult, object]:
     """Matrix-level CG: builds the SpMV operator through the plan/tuner
     subsystem instead of a hard-coded path.
@@ -147,7 +148,15 @@ def cg_solve(M, b: jnp.ndarray, *, plan=None, cache=None,
     runs block CG through one batched SpMM per iteration.  Returns
     ``(SolveResult, operator)`` — the operator exposes the concrete plan
     it ran as ``op.plan`` and the artifact as ``op.schedule``.
+
+    ``mesh_p=p`` solves on a p-device mesh instead (:func:`_cg_solve_mesh`)
+    and returns the serving engine's ``MeshExecutor`` as the operator.
     """
+    if mesh_p is not None:
+        return _cg_solve_mesh(M, b, mesh_p, plan=plan, cache=cache,
+                              autotune=autotune, interpret=interpret,
+                              x0=x0, tol=tol, maxiter=maxiter,
+                              precondition=precondition, **tune_kw)
     from repro.core import tuner as _tuner
     from repro.kernels.ops import SpmvOperator
 
@@ -161,3 +170,41 @@ def cg_solve(M, b: jnp.ndarray, *, plan=None, cache=None,
             res = cg(op, b, x0=x0, tol=tol, maxiter=maxiter,
                      diag=M.ad if precondition else None)
     return res, op
+
+
+def _cg_solve_mesh(M, b, p: int, *, plan, cache, autotune, interpret, x0,
+                   tol, maxiter, precondition,
+                   **tune_kw) -> Tuple[SolveResult, object]:
+    """``cg_solve`` on a p-device mesh (docs/DESIGN.md §2).
+
+    The plan comes from ``tuner.mesh_plan_for`` (cache key
+    ``<fingerprint>@p<p>``; ``autotune=True`` measures the strategies
+    with ``tune_mesh``), the product from the serving engine's
+    ``MeshExecutor``, kept placed in ``cache`` across calls.  b, x0 and
+    the Jacobi diagonal are row-sharded over the mesh, padded to a
+    multiple of p rows, so the products exchange only what their
+    strategy needs and CG's dots become cross-chip reductions.  A b
+    already placed so moves nothing."""
+    from repro.core import tuner as _tuner
+    from repro.serve.executor import mesh_executor_for
+
+    with obs.span("solver.cg_solve", mesh_p=p):
+        if plan is None:
+            plan = _tuner.mesh_plan_for(M, p, cache=cache,
+                                        autotune=autotune,
+                                        interpret=interpret, **tune_kw)
+        elif plan.strategy != "mesh" or plan.mesh_p != p:
+            raise ValueError(f"plan {plan.key()} is not a {p}-way mesh plan")
+        with obs.span("kernels.bind", path=plan.path,
+                      strategy=plan.accumulation):
+            ex = mesh_executor_for(M, plan, cache=cache, interpret=interpret)
+        with obs.span("solver.place"):
+            b = ex.place(b)
+            x0 = None if x0 is None else ex.place(x0)
+            diag = ex.diagonal() if precondition else None
+        with obs.span("solver.dispatch"):
+            res = cg(ex.apply_rows, b, x0=x0, tol=tol, maxiter=maxiter,
+                     diag=diag)
+            if ex.n_rows != M.n:
+                res = res._replace(x=res.x[:M.n])
+    return res, ex

@@ -389,6 +389,9 @@ class PlanCache:
         self.shard_layouts: Dict[str, object] = {}
         self.shard_layout_hits = 0
         self.shard_layout_misses = 0
+        # (fingerprint, plan key) -> (value digest, placed MeshExecutor),
+        # kept by serve.executor.mesh_executor_for
+        self.mesh_executors: Dict[tuple, tuple] = {}
         # (fingerprint, RHS widths) -> paths the enumerated pool offers
         self.offered_paths: Dict[tuple, frozenset] = {}
         if path is not None and os.path.exists(path):
@@ -1053,17 +1056,18 @@ def mesh_plan_for(M: CSRC, p: int,
     ``autotune=True`` measures on an actual mesh (``tune_mesh``);
     ``autotune=False`` falls back to the collective-bytes heuristic
     (cached, so the decision is stable across calls)."""
-    if autotune:
-        return tune_mesh(M, p, cache=cache, interpret=interpret,
-                         **tune_kw).plan
-    fp = mesh_fingerprint(fingerprint(M), p)
-    if cache is not None:
-        hit = cache.get(fp)
-        if hit is not None:
-            return hit
-    plan = heuristic_mesh_plan(stats_of(M), p)
-    if cache is not None:
-        cache.put(fp, plan)
-        if cache.path is not None:
-            cache.save()
-    return plan
+    with obs.span("tune.resolve"):
+        if autotune:
+            return tune_mesh(M, p, cache=cache, interpret=interpret,
+                             **tune_kw).plan
+        fp = mesh_fingerprint(fingerprint(M), p)
+        if cache is not None:
+            hit = cache.get(fp)
+            if hit is not None:
+                return hit
+        plan = heuristic_mesh_plan(stats_of(M), p)
+        if cache is not None:
+            cache.put(fp, plan)
+            if cache.path is not None:
+                cache.save()
+        return plan

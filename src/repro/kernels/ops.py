@@ -108,8 +108,8 @@ class SpmvOperator:
     def _bind(self, M: CSRC, schedule, coloring=None):
         """Install the schedule and (re)build both jit'd executors through
         the registry — shared by construction and ``update_values``."""
-        with obs.span("kernels.bind", path=self.path):
-            obs.count("spmv_bind_total", path=self.path)
+        with obs.span("kernels.bind", path=self.path, strategy="local"):
+            obs.count("spmv_bind_total", path=self.path, strategy="local")
             self.M = M
             self.schedule = schedule
             self.pack = next(

@@ -39,7 +39,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.csrc import CSRC
 from repro.core.plan import ExecutionPlan
 
@@ -129,6 +131,9 @@ class MeshExecutor(SpmvExecutor):
         self._sched = None
         self.layout = None
         self._structure_digest = None
+        self._diag = None
+        # rows of the vectors a mesh solve runs on: n padded to p shards
+        self.n_rows = -(-M.n // p) * p
         self._build(M)
 
     # the schedule artifact only supplies the row partition here; a
@@ -168,10 +173,43 @@ class MeshExecutor(SpmvExecutor):
             M, self.mesh, self.axis, strategy=strat, schedule=self._sched,
             cache=self.cache, plan=self.plan, interpret=self.interpret,
             layout=self.layout)
+        obs.count("spmv_bind_total", path=self.plan.path, strategy=strat)
 
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         # reduce_scatter pads y to p equal intervals; serve the true rows
         return self._fn(x)[:self.M.n]
+
+    def apply_rows(self, x: jnp.ndarray) -> jnp.ndarray:
+        """A·x on vectors of ``n_rows`` rows (a mesh solve's): the padding
+        rows are read as zeros and come out as zeros."""
+        n = self.M.n
+        if self.n_rows == n:
+            return self(x)
+        pad = ((0, self.n_rows - n),) + ((0, 0),) * (x.ndim - 1)
+        return jnp.pad(self(x[:n]), pad)
+
+    def place(self, v) -> jnp.ndarray:
+        """``v`` ((n,) or (n, r)) as a mesh solve's vector: zero-padded to
+        ``n_rows`` rows and row-sharded over the mesh.  An array already
+        so placed is returned as it is; any other is put on the mesh and
+        its bytes counted in ``mesh_place_bytes_total{site="vector"}``."""
+        spec = P(self.axis) if v.ndim == 1 else P(self.axis, None)
+        target = NamedSharding(self.mesh, spec)
+        if (isinstance(v, jax.Array) and v.shape[0] == self.n_rows
+                and v.sharding.is_equivalent_to(target, v.ndim)):
+            return v
+        if v.shape[0] != self.n_rows:
+            v = jnp.pad(v, ((0, self.n_rows - v.shape[0]),)
+                        + ((0, 0),) * (v.ndim - 1))
+        obs.count("mesh_place_bytes_total", v.size * v.dtype.itemsize,
+                  site="vector")
+        return jax.device_put(v, target)
+
+    def diagonal(self) -> jnp.ndarray:
+        """The matrix diagonal as a mesh solve's vector, placed once."""
+        if self._diag is None:
+            self._diag = self.place(self.M.ad)
+        return self._diag
 
     def sharded_operands(self):
         """The device arrays the shard_map consumes, one shard per mesh
@@ -203,12 +241,38 @@ class MeshExecutor(SpmvExecutor):
         self.layout = schedule_mod.refresh_shard_layout(
             self.layout, M, part=part)
         self.M = M
+        self._diag = None
         self._fn = dist.build_sharded_spmv(
             M, self.mesh, self.axis, strategy=self.plan.accumulation,
             schedule=self._sched, cache=self.cache, plan=self.plan,
             interpret=self.interpret, layout=self.layout)
+        obs.count("spmv_bind_total", path=self.plan.path,
+                  strategy=self.plan.accumulation)
         return self
 
     @property
     def schedule(self):
         return self._sched
+
+
+def mesh_executor_for(M: CSRC, plan: ExecutionPlan, cache=None,
+                      interpret=None) -> MeshExecutor:
+    """The placed :class:`MeshExecutor` of (M, plan), kept across calls.
+
+    With a cache the executor is kept in ``cache.mesh_executors``, keyed
+    as its shard layouts are (fingerprint, value digest, and the plan,
+    which holds p): a second call on the same matrix places no shard
+    array again.  One executor is kept per (fingerprint, plan); a matrix
+    of that class with other values replaces it.  Without a cache every
+    call builds and places afresh."""
+    if cache is None:
+        return MeshExecutor(M, plan, interpret=interpret)
+    from repro.core.schedule import value_digest
+    from repro.core.tuner import fingerprint
+    key, digest = (fingerprint(M), plan.key()), value_digest(M)
+    held = cache.mesh_executors.get(key)
+    if held is None or held[0] != digest:
+        cache.mesh_executors.pop(key, None)     # free the old placement
+        held = cache.mesh_executors[key] = (
+            digest, MeshExecutor(M, plan, cache=cache, interpret=interpret))
+    return held[1]

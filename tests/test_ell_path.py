@@ -91,7 +91,8 @@ def test_ell_matches_segment_oracle(name, make, nrhs):
 
 
 def _bind_count(path: str) -> float:
-    return obs.snapshot().value("spmv_bind_total", path=path)
+    return obs.snapshot().value("spmv_bind_total", path=path,
+                                strategy="local")
 
 
 @pytest.mark.parametrize("second", ["symmetric", "nonsymmetric"])
