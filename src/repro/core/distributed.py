@@ -32,8 +32,8 @@ solver restarts) are zero-precompute.  Every strategy accepts x of shape
 (n,) or (n, B): the multi-RHS product shares one collective per block.
 
 Shard-local compute is itself plan-driven: with a plan (or schedule) whose
-path registers a :class:`~repro.core.paths.ShardSupport` ('flat',
-'nnzsplit'), every strategy runs that path's Pallas kernel per shard —
+path registers a :class:`~repro.core.paths.ShardSupport` ('ell', 'flat',
+'nnzsplit'), every strategy runs that path's product per shard —
 allreduce/reduce_scatter over per-shard global-coordinate sub-packs
 (``schedule.build_path_shards``), halo over local-coordinate per-shard
 packs (``schedule.build_path_halo``) — instead of the default
@@ -122,8 +122,8 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
     """'allreduce' (all-in-one) and 'reduce_scatter' (per-buffer/interval)
     strategies.  x replicated, shape (n,) or (n, B); output replicated or
     row-sharded.  With a plan/schedule whose path registers ShardSupport
-    ('flat', 'nnzsplit') the shard-local partial runs that path's kernel
-    over the shard's sub-pack instead of segment-sum.
+    ('ell', 'flat', 'nnzsplit') the shard-local partial runs that path's
+    product over the shard's sub-pack instead of segment-sum.
 
     ``layout`` injects a prebuilt (or value-refreshed) ShardedSlots /
     path shards layout; otherwise the schedule layer builds it — and,
@@ -167,7 +167,7 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
             return reduce_y(local_y(*args), x.ndim)
 
         sharded = _place(sup.shard_arrays(fs), mesh, P(axis))
-        in_specs = tuple(sup.shard_specs(axis)) + (P(),)
+        in_specs = (P(axis),) * len(sharded) + (P(),)
     else:
         ss = (layout if layout is not None
               else schedule_mod.build_sharded_slots(M, part, cache=cache))
@@ -203,6 +203,25 @@ def build_spmv_allreduce(M: CSRC, mesh: Mesh, axis: str = "rows",
     return apply
 
 
+def halo_shard_fn(local_y: Callable, axis: str, p: int, h: int) -> Callable:
+    """The halo strategy's shard function around a path's shard-local
+    product ``local_y(*shard_arrays, x_ext) -> y_ext``: the left
+    neighbour's tail of x in, the halo rows of y out to it."""
+    def local(*args):
+        x_own = args[-1]
+        # x halo from the LEFT neighbor: its tail h rows
+        left_tail = jax.lax.ppermute(
+            x_own[-h:], axis, [(i, (i + 1) % p) for i in range(p)])
+        x_ext = jnp.concatenate([left_tail, x_own])  # rows [r0-h, r1)
+        y_ext = local_y(*args[:-1], x_ext)
+        # y halo to the LEFT neighbor (it owns rows [r0-h, r0))
+        from_right = jax.lax.ppermute(
+            y_ext[:h], axis, [(i, (i - 1) % p) for i in range(p)])
+        return y_ext[h:].at[-h:].add(from_right)
+
+    return local
+
+
 def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
                     schedule: Optional[SpmvSchedule] = None,
                     cache=None,
@@ -230,22 +249,10 @@ def build_spmv_halo(M: CSRC, mesh: Mesh, axis: str = "rows",
         ns, h, n_local = sup.halo_dims(lay)
         n = M.n
         n_pad = ns * p
-        local_y = sup.local_fn(lay, n_local, interpret, plan.variant)
-
-        def local(*args):
-            x_own = args[-1]
-            # x halo from the LEFT neighbor: its tail h rows
-            left_tail = jax.lax.ppermute(
-                x_own[-h:], axis, [(i, (i + 1) % p) for i in range(p)])
-            x_ext = jnp.concatenate([left_tail, x_own])  # rows [r0-h, r1)
-            y_ext = local_y(*args[:-1], x_ext)
-            # y halo to the LEFT neighbor (it owns rows [r0-h, r0))
-            from_right = jax.lax.ppermute(
-                y_ext[:h], axis, [(i, (i - 1) % p) for i in range(p)])
-            return y_ext[h:].at[-h:].add(from_right)
-
+        local = halo_shard_fn(
+            sup.local_fn(lay, n_local, interpret, plan.variant), axis, p, h)
         sharded = _place(sup.shard_arrays(lay), mesh, P(axis))
-        slot_specs = tuple(sup.shard_specs(axis))
+        slot_specs = (P(axis),) * len(sharded)
     else:
         lay = (layout if layout is not None
                else schedule_mod.build_halo_layout(M, p, cache=cache))
@@ -316,8 +323,9 @@ def build_sharded_spmv(M: CSRC, mesh: Mesh, axis: str = "rows",
     """Factory: y_fn(x) computing A·x (or A·X for (n, B) blocks) across the
     mesh axis.  ``schedule``/``cache`` reuse the precomputed artifact; with
     ``strategy='auto'`` a supplied schedule's (or ``plan``'s) accumulation
-    decides.  A plan/schedule whose path registers ShardSupport ('flat',
-    'nnzsplit') makes every strategy run that path's kernel shard-locally.
+    decides.  A plan/schedule whose path registers ShardSupport ('ell',
+    'flat', 'nnzsplit') makes every strategy run that path's product
+    shard-locally.
     ``layout`` injects a prebuilt shard layout (the serving MeshExecutor's
     value-refresh path)."""
     p = mesh.shape[axis]

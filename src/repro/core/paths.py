@@ -177,7 +177,7 @@ class ShardSupport:
       refresh_shards  (layout, M, starts) -> value-refreshed layout
       refresh_halo    (layout, M) -> value-refreshed layout
       shard_arrays    layout -> tuple of leading-axis-p device arrays
-      shard_specs     axis name -> matching shard_map PartitionSpecs
+                      (the strategies split each on that axis alone)
       local_fn        (layout, n_local, interpret, variant) -> local
                       product: the path's one-hot Pallas kernel or its
                       fused stream form
@@ -193,7 +193,6 @@ class ShardSupport:
     refresh_shards: Callable[..., object]
     refresh_halo: Callable[..., object]
     shard_arrays: Callable[[object], tuple]
-    shard_specs: Callable[[str], tuple]
     local_fn: Callable[..., Callable]
     halo_dims: Callable[[object], tuple]
 
@@ -450,6 +449,63 @@ def _ell_make_spmm(M, schedule, plan, *, interpret=None, coloring=None):
     return functools.partial(ell_mod.ell_spmm, schedule.ell_pack, M.ad)
 
 
+def _ell_layout_classes():
+    from repro.kernels.csrc_spmv_ell import EllHalo, EllShards
+    return {"ell_shards": EllShards, "ell_halo": EllHalo}
+
+
+def _ell_pack_shards(M, starts, plan):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.pack_ell_shards(M, starts)
+
+
+def _ell_pack_halo(M, p, plan):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.pack_ell_halo(M, p)
+
+
+def _ell_refresh_shards(lay, M, starts):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.refresh_ell_shards(lay, M, starts)
+
+
+def _ell_refresh_halo(lay, M):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.refresh_ell_halo(lay, M)
+
+
+def _ell_shard_arrays(lay):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.ell_shard_arrays(lay)
+
+
+def _ell_local_fn(lay, n_local, interpret, variant):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.ell_local_fn(lay, n_local)
+
+
+def _ell_halo_dims(lay):
+    from repro.kernels import csrc_spmv_ell as ell_mod
+    return ell_mod.ell_halo_dims(lay)
+
+
+# each shard's rows as row-padded planes (kernels/csrc_spmv_ell.py): no
+# plan field shapes them, so the layouts are keyed by matrix and rows only
+ELL_SHARD_SUPPORT = ShardSupport(
+    shards_kind="ell_shards",
+    halo_kind="ell_halo",
+    layout_classes=_ell_layout_classes,
+    geometry=_empty_fields,
+    pack_shards=_ell_pack_shards,
+    pack_halo=_ell_pack_halo,
+    refresh_shards=_ell_refresh_shards,
+    refresh_halo=_ell_refresh_halo,
+    shard_arrays=_ell_shard_arrays,
+    local_fn=_ell_local_fn,
+    halo_dims=_ell_halo_dims,
+)
+
+
 register_path(KernelPath(
     name="ell",
     feasible=_square_feasible,
@@ -461,6 +517,7 @@ register_path(KernelPath(
     make_spmv=_ell_make_spmv,
     make_spmm=_ell_make_spmm,
     refresh_values=_ell_refresh,
+    shard_support=ELL_SHARD_SUPPORT,
 ))
 
 
@@ -815,11 +872,6 @@ def _flat_shard_arrays(lay):
     return flat_mod.flat_shard_arrays(lay)
 
 
-def _flat_shard_specs(axis):
-    from repro.kernels import csrc_spmv_flat as flat_mod
-    return flat_mod.flat_shard_specs(axis)
-
-
 def _flat_local_fn(lay, n_local, interpret, variant):
     from repro.kernels import csrc_spmv_flat as flat_mod
     return flat_mod.flat_local_fn(lay, n_local, interpret, variant)
@@ -840,7 +892,6 @@ FLAT_SHARD_SUPPORT = ShardSupport(
     refresh_shards=_flat_refresh_shards,
     refresh_halo=_flat_refresh_halo,
     shard_arrays=_flat_shard_arrays,
-    shard_specs=_flat_shard_specs,
     local_fn=_flat_local_fn,
     halo_dims=_flat_halo_dims,
 )
@@ -1056,11 +1107,6 @@ def _nnzsplit_shard_arrays(lay):
     return nz_mod.nnzsplit_shard_arrays(lay)
 
 
-def _nnzsplit_shard_specs(axis):
-    from repro.kernels import csrc_spmv_nnzsplit as nz_mod
-    return nz_mod.nnzsplit_shard_specs(axis)
-
-
 def _nnzsplit_local_fn(lay, n_local, interpret, variant):
     from repro.kernels import csrc_spmv_nnzsplit as nz_mod
     return nz_mod.nnzsplit_local_fn(lay, n_local, interpret, variant)
@@ -1081,7 +1127,6 @@ NNZSPLIT_SHARD_SUPPORT = ShardSupport(
     refresh_shards=_nnzsplit_refresh_shards,
     refresh_halo=_nnzsplit_refresh_halo,
     shard_arrays=_nnzsplit_shard_arrays,
-    shard_specs=_nnzsplit_shard_specs,
     local_fn=_nnzsplit_local_fn,
     halo_dims=_nnzsplit_halo_dims,
 )

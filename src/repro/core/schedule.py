@@ -360,9 +360,9 @@ _HALO_LAYOUT_MEMO: dict = {}
 
 # ---------------------------------------------------------------------------
 # Shard-layout (de)serialization: the npz layer that ships per-shard
-# sub-artifacts (ShardedSlots / HaloLayout / FlatShards / FlatHalo) to
-# serving workers through the PlanCache, keyed by (fingerprint, value
-# digest, p, strategy kind, pack geometry).
+# sub-artifacts (ShardedSlots / HaloLayout and every registered path's
+# ShardSupport layouts) to serving workers through the PlanCache, keyed
+# by (fingerprint, value digest, p, strategy kind, pack geometry).
 # ---------------------------------------------------------------------------
 
 SHARD_LAYOUT_VERSION = 1
@@ -391,10 +391,11 @@ def shard_layout_key(kind: str, fp: str, digest: str, p: int,
 
 
 def save_shard_layout_npz(path: str, lay):
-    """Serialize any of the four shard-layout dataclasses: scalar fields
-    go to the JSON meta, arrays (and the embedded RowPartition) to npz.
-    bf16 value streams persist widened to f32 (lossless) and re-narrow on
-    load (npz has no native bfloat16)."""
+    """Serialize any registered shard-layout dataclass: scalar fields go
+    to the JSON meta, arrays (and the embedded RowPartition) to npz, and
+    the names of absent (None) arrays to the meta.  bf16 value streams
+    persist widened to f32 (lossless) and re-narrow on load (npz has no
+    native bfloat16)."""
     kinds = _layout_kinds()
     kind = next(k for k, cls in kinds.items() if isinstance(lay, cls))
     meta = {"version": SHARD_LAYOUT_VERSION, "kind": kind}
@@ -406,6 +407,8 @@ def save_shard_layout_npz(path: str, lay):
                 arrays[f"part__{pf.name}"] = np.asarray(getattr(v, pf.name))
         elif isinstance(v, (bool, int, float)):
             meta[f.name] = v
+        elif v is None:
+            meta.setdefault("__none__", []).append(f.name)
         elif str(v.dtype) == "bfloat16":
             meta.setdefault("__bf16__", []).append(f.name)
             arrays[f.name] = np.asarray(v, dtype=np.float32)
@@ -433,6 +436,8 @@ def load_shard_layout_npz(path: str):
         for f in dataclasses.fields(cls):
             if f.name in meta:
                 kwargs[f.name] = meta[f.name]
+            elif f.name in meta.get("__none__", ()):
+                kwargs[f.name] = None
             elif f.name == "part":
                 kwargs["part"] = RowPartition(
                     starts=z["part__starts"], eff_lo=z["part__eff_lo"],

@@ -274,8 +274,9 @@ def enumerate_mesh_plans(stats: MatrixStats, p: int,
     """Distributed candidate plans for a p-way mesh.
 
     Shard-local compute comes from the paths the distributed strategies
-    execute — 'segment' always, 'flat' when the skew gate makes it worth
-    measuring (same enumerator the local tuner uses) — crossed with every
+    execute — 'segment' always, and every path with a ShardSupport where
+    its own enumerator proposes it ('ell' under the padding gate, 'flat'
+    under the skew gate, as the local tuner) — crossed with every
     accumulation strategy whose collective footprint passes the
     bandwidth gate: 'halo' only when the band fits inside one shard, and
     any strategy only when ``collective_bytes_estimate`` stays within
@@ -361,7 +362,8 @@ class PlanCache:
     NOT satisfy ``tune()``, which would otherwise report a never-measured
     plan as the argmin.  Nor does a measured entry whose ``pool_paths``
     lacks a path the current pool offers (or an entry older than the
-    record): it never measured that path, so ``tune()`` measures again.
+    record): it never measured that path, so ``tune()`` (or, for a
+    per-(matrix, p) entry, ``tune_mesh()``) measures again.
 
     Next to each plan the cache stores the **schedule artifact**
     (core/schedule.py): the block-ELL pack, row partition/halo ranges, and
@@ -721,18 +723,19 @@ class TuneResult:
 
 
 def _offered_paths(M: CSRC, fp: str, cache: PlanCache, candidates,
-                   nrhs_options) -> frozenset:
-    """The paths the candidate pool of this tuning request offers.  The
-    enumerated pool's are kept per (fingerprint, RHS widths) in the
-    cache: computing the statistics on every hit would put host work on
-    each call's path."""
+                   nrhs_options, p: Optional[int] = None) -> frozenset:
+    """The paths the candidate pool of this tuning request offers (the
+    ``p``-way mesh pool when ``p`` is given).  The enumerated pool's are
+    kept per (fingerprint, RHS widths) in the cache: computing the
+    statistics on every hit would put host work on each call's path."""
     if candidates is not None:
-        return frozenset(p.path for p in candidates)
+        return frozenset(c.path for c in candidates)
     key = (fp, tuple(nrhs_options))
     if key not in cache.offered_paths:
-        cache.offered_paths[key] = frozenset(
-            p.path for p in enumerate_plans(stats_of(M),
-                                            nrhs_options=key[1]))
+        stats = stats_of(M)
+        pool = (enumerate_plans(stats, nrhs_options=key[1]) if p is None
+                else enumerate_mesh_plans(stats, p, nrhs_options=key[1]))
+        cache.offered_paths[key] = frozenset(c.path for c in pool)
     return cache.offered_paths[key]
 
 
@@ -949,14 +952,20 @@ def tune_mesh(M: CSRC, p: int,
     has a mesh to serve from, and the local entry otherwise.  The process
     must see ``p`` devices (``XLA_FLAGS=--xla_force_host_platform_
     device_count=<p>`` on CPU); a ``measure(fn, x) -> seconds`` injection
-    makes the mode testable on one device with a 1-wide mesh.
+    makes the mode testable on one device with a 1-wide mesh.  Like
+    ``tune``, the entry records its pool's paths: a cached winner whose
+    pool lacked a path the mesh pool offers now is measured again.
     """
     import jax
     from .distributed import build_sharded_spmv, make_mesh
 
     fp = mesh_fingerprint(fingerprint(M), p)
     if cache is not None:
-        hit = cache.get(fp, require_measured=True)
+        # as in tune(): an entry measured over a pool without a path the
+        # mesh pool offers now (or recording none) is measured again
+        hit = cache.get(fp, require_measured=True,
+                        offered=lambda: _offered_paths(
+                            M, fp, cache, candidates, nrhs_options, p=p))
         if hit is not None:
             return TuneResult(plan=hit, fingerprint=fp, timings_s={},
                               cached=True)
@@ -1006,7 +1015,12 @@ def tune_mesh(M: CSRC, p: int,
             f"no distributed candidate survived measurement at p={p}")
 
     if cache is not None:
-        cache.put(fp, best_plan, timings)
+        pool_paths = frozenset(c.path for c in cands)
+        cache.put(fp, best_plan, timings, pool_paths=pool_paths)
+        if candidates is None:
+            # the pool just enumerated is what the next probe's offer
+            # check would compute from the statistics again
+            cache.offered_paths[(fp, tuple(nrhs_options))] = pool_paths
         # ship the winner's schedule + shard-layout artifacts (layout
         # builders re-serve the memoized build and persist it)
         build_sharded_spmv(M, mesh, axis, strategy="auto", cache=cache,

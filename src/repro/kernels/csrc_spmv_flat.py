@@ -574,14 +574,6 @@ def flat_shard_arrays(fs):
             fs.col_local, fs.row_in_win, fs.ad)
 
 
-def flat_shard_specs(axis: str):
-    from jax.sharding import PartitionSpec as P
-    return (P(axis, None), P(axis, None),
-            P(axis, None, None, None), P(axis, None, None, None),
-            P(axis, None, None, None), P(axis, None, None, None),
-            P(axis, None, None))
-
-
 def flat_local_fn(fs, n_local: int, interpret=None, variant="onehot"):
     """Shard-local flat-grid product: rebuild the shard's FlatBlockEll from
     the shard_map-sliced stacked arrays and run the Pallas kernel (SpMV or

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.sharding import PartitionSpec as P
 
 from repro.core.csrc import CSRC, bandwidth, row_of_slot
 from repro.core.blockell import _round_up
@@ -467,11 +466,6 @@ def nnzsplit_shard_arrays(lay):
     """Leading-axis-p arrays a shard_map local function consumes."""
     return (lay.vals, lay.lrow, lay.src, lay.chunk_row0, lay.fixup_idx,
             lay.ad)
-
-
-def nnzsplit_shard_specs(axis: str):
-    return (P(axis, None, None, None), P(axis, None, None, None),
-            P(axis, None), P(axis, None), P(axis, None), P(axis, None))
 
 
 def nnzsplit_local_fn(lay, n_local: int, interpret=None, variant="onehot"):

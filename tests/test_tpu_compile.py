@@ -8,7 +8,8 @@ and SpMM kernels, and grid_tet(48) for the assembly grid.  Interpret-mode
 tests cannot see what this catches: block shapes that break the 8×128
 tiling rule, VMEM over-subscription, ops Mosaic cannot lower.  The
 row-padded ``ell`` product, plain XLA, is compiled at the hpcg27 cell's
-104³ rows, the main path of both benchmark cells.
+104³ rows, the main path of both benchmark cells, and so is its halo
+shard function on a one-wide mesh at the hpcg27x4 cell's slab.
 
 The topology is described inside a module-scoped fixture (never at
 import), and everything is compiled from ShapeDtypeStructs in this
@@ -18,14 +19,19 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax import shard_map
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.core.blockell import BlockEll
+from repro.core.distributed import halo_shard_fn
 from repro.kernels import assembly_scatter as akern
 from repro.kernels.csrc_spmv import (ONEHOT_MAX_WINDOW, blockell_spmm,
                                      blockell_spmv)
-from repro.kernels.csrc_spmv_ell import EllPack, ell_spmm, ell_spmv
+from repro.kernels.csrc_spmv_ell import (EllHalo, EllPack, ell_local_fn,
+                                        ell_spmm, ell_spmv)
 from repro.kernels.csrc_spmv_flat import FlatBlockEll, flat_spmm, flat_spmv
 from repro.kernels.csrc_spmv_nnzsplit import (NnzSplitPack, nnzsplit_spmm,
                                               nnzsplit_spmv)
@@ -36,6 +42,7 @@ TET_SIZE = 1_707_697        # grid_tet(48): n + 2k of the unified vector
 TET_CONTRIBS = 10_616_832   # grid_tet(48): ne * edof^2
 HPCG_ROWS = 104 ** 3        # the hpcg27 cell's 27-point stencil
 HPCG_WIDTH = 13             # its most lower slots of a row
+HPCG_HALO = 10_928          # hpcg27x4's band 10,921, 8-aligned
 
 
 @pytest.fixture(scope="module")
@@ -160,6 +167,32 @@ def test_ell_product_compiles(shape, nrhs):
                          shape((w, n), jnp.float32), shape((n,), jnp.float32),
                          shape((n, nrhs) if nrhs > 1 else (n,),
                                jnp.float32))
+    assert "scatter" in txt and "gather" in txt
+
+
+@pytest.mark.parametrize("nrhs", [1, SERVE_NRHS])
+def test_ell_halo_shard_fn_compiles(topo, nrhs):
+    """The halo strategy's shard function around the row-padded shard
+    product, on a one-wide mesh of one v5e chip: its two permutes, the
+    gather with its reduction and the one scatter-add."""
+    ns, h, w = HPCG_ROWS, HPCG_HALO, HPCG_WIDTH
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("rows",),
+                axis_types=(AxisType.Auto,))
+    lay = EllHalo(p=1, ns=ns, h=h, n_local=ns + h, width=w, ja=None,
+                  al=None, au=None, ad=None, plane_of_slot=None)
+    local = halo_shard_fn(ell_local_fn(lay, lay.n_local), "rows", 1, h)
+    fn = shard_map(local, mesh=mesh, in_specs=(P("rows"),) * 4,
+                   out_specs=P("rows"), check_vma=False)
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, P("rows")))
+
+    txt = _compiled_text(fn, arg((1, w, ns), jnp.int32),
+                         arg((1, w, ns), jnp.float32),
+                         arg((1, ns), jnp.float32),
+                         arg((ns, nrhs) if nrhs > 1 else (ns,),
+                             jnp.float32))
     assert "scatter" in txt and "gather" in txt
 
 
