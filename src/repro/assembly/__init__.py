@@ -11,7 +11,7 @@ matrices the SpMV stack consumes (docs/DESIGN.md §5).
              selection (kernels live in repro.kernels.assembly_scatter)
 
 End to end:  mesh → stiffness → assemble → tune → solve
-(examples/assemble_tune_solve.py; benchmarks/run.py --only assembly).
+(examples/assemble_tune_solve.py; the benchmark cell fem_tet.step).
 """
 from .mesh import (Mesh, grid_quad, grid_tet, grid_tri,          # noqa: F401
                    poisson_stiffness, synthetic_stiffness)

@@ -131,10 +131,8 @@ def grid_tet(nx: int, ny: int = 0, nz: int = 0) -> Mesh:
     return Mesh(name=f"tet{nx}x{ny}x{nz}", dim=3, coords=coords, conn=conn)
 
 
-# The benchmark/CI mesh suite: one entry per generator, parameterized by a
-# common size knob (tet scales down — 6 elements per cube).  The assembly
-# benchmark iterates this table, so a new generator added here is
-# benchmarked and oracle-checked with no benchmark edits.
+# One entry per mesh generator, parameterized by a common size knob (tet
+# scales down — 6 elements per cube).
 MESH_GENERATORS = (
     ("tri", lambda s: grid_tri(s)),
     ("quad", lambda s: grid_quad(s)),

@@ -370,7 +370,7 @@ def collective_bytes_from_stats(n: int, band: int, p: int, strategy: str,
 
 def collective_bytes_estimate(M: CSRC, p: int, strategy: str,
                               nrhs: int = 1) -> int:
-    """Napkin model used by §Roofline and the benchmarks: bytes crossing
+    """Napkin model the mesh tuner gates on: bytes crossing
     links per shard per product (scales linearly with the RHS block)."""
     return collective_bytes_from_stats(M.n, bandwidth(M), p, strategy,
                                        nrhs=nrhs)

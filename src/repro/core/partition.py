@@ -83,7 +83,7 @@ def partition_rows_by_nnz(M: CSRC, p: int) -> RowPartition:
 
 
 def partition_rows_by_count(M: CSRC, p: int) -> RowPartition:
-    """Row-count split (the paper's inferior baseline — kept for benchmarks)."""
+    """Row-count split (the paper's inferior baseline)."""
     n = M.n
     starts = np.linspace(0, n, p + 1).astype(np.int64)
     ja = np.asarray(M.ja)

@@ -742,7 +742,7 @@ FLAT_SKEW_MIN = 0.25
 
 
 def flat_worth_measuring(stats) -> bool:
-    """The flat enumerator's skew gate, shared with benchmarks: is the
+    """The flat enumerator's skew gate, shared with the tests: is the
     nnz-per-row spread large enough that per-tile-exact packing could
     beat the rectangular grid?"""
     return stats.nnz_row_dev > FLAT_SKEW_MIN * max(stats.nnz_row_mean, 1.0)
@@ -932,7 +932,7 @@ NNZSPLIT_SPREAD_MIN = 0.25
 
 
 def nnzsplit_worth_measuring(stats) -> bool:
-    """The nnzsplit enumerator's gate, shared with benchmarks: is the
+    """The nnzsplit enumerator's gate, shared with the tests: is the
     matrix unstructured enough (heavy row-length tail OR non-banded column
     spread) that nnz-balanced chunking could beat the windowed paths?"""
     if stats.n != stats.m:

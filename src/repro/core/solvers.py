@@ -10,7 +10,7 @@ residual method").  We provide the two solver families its benchmark models:
   * bicgstab  — for structurally-symmetric but numerically non-symmetric
                 matrices (uses the O(1) CSRC transpose when needed).
 
-Both are jax.lax.while_loop-based (jit-able end to end, dry-run lowerable)
+Both are jax.lax.while_loop-based (jit-able end to end)
 and accept any ``spmv`` callable — single-chip kernel or the distributed
 shard_map product — so the whole paper stack composes.
 

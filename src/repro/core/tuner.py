@@ -23,8 +23,8 @@ Pieces:
                              selection from the collective-bytes model)
   PlanCache                  JSON plan cache keyed by fingerprint; a hit
                              skips re-measurement entirely
-  tune / plan_for            the tuning entry points used by solvers,
-                             the serve engine, and benchmarks
+  tune / plan_for            the tuning entry points used by solvers
+                             and the serve engine
   enumerate_mesh_plans /     the mesh-aware mode: distributed candidates
   tune_mesh / mesh_plan_for  (strategy='mesh', every accumulation x
                              shard-compute path, gated by the
@@ -35,17 +35,13 @@ Pieces:
 
 Mesh-aware tuning needs the process to see ``p`` devices — launch with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=<p>`` on CPU (device
-count is locked at first jax init, so benchmarks run it in a subprocess).
+count is locked at first jax init, so tests run it in a subprocess).
 
 Windowed candidates with ``value_dtype='bfloat16'`` (enumerated only for
 numerically-symmetric matrices) additionally pass an accuracy check
 against the exact segment-sum product before they may win
 (``VALUE_DTYPE_TOL``): the tuner trades precision for value-stream
 bandwidth only where the matrix class tolerates it.
-
-The timing harness is benchmarks/util.time_fn when importable (running
-from the repo root); a same-contract fallback is inlined so the tuner
-works from any installed location.
 """
 from __future__ import annotations
 
@@ -63,22 +59,20 @@ from . import paths as paths_mod
 from .csrc import CSRC, bandwidth as csrc_bandwidth, nnz_per_row
 from .plan import ExecutionPlan, feasible, kernel_window
 
-try:                                          # repo-root layout
-    from benchmarks.util import time_fn as _time_fn
-except ImportError:                           # installed / src-only path
-    def _time_fn(fn, *args, warmup: int = 3, repeats: int = 10) -> float:
-        """Median wall-clock seconds per call (benchmarks/util.py contract)."""
-        import jax
-        for _ in range(warmup):
-            out = fn(*args)
+def _time_fn(fn, *args, warmup: int = 3, repeats: int = 10) -> float:
+    """Median wall-clock seconds per call: ``warmup`` calls, then
+    ``repeats`` calls each fenced with ``block_until_ready``."""
+    import jax
+    for _ in range(warmup):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    ts = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        out = fn(*args)
         jax.block_until_ready(out)
-        ts = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            out = fn(*args)
-            jax.block_until_ready(out)
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts))
+        ts.append(time.perf_counter() - t0)
+    return float(np.median(ts))
 
 
 # ---------------------------------------------------------------------------
@@ -758,7 +752,7 @@ def tune(M: CSRC,
 
     ``cache`` short-circuits: a fingerprint hit returns the stored plan
     with zero measurements.  ``measure(op, x) -> seconds`` is injectable
-    for tests; the default is the benchmarks/util timing harness with a
+    for tests; the default is ``_time_fn``, the median of fenced calls, with a
     small budget (the tuner runs at operator-construction time).
 
     ``predict=True`` (default) is the predict-then-measure mode: every

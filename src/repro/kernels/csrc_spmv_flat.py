@@ -14,8 +14,7 @@ padding (pad_ratio).  Here each row tile gets only the k-steps it needs:
 
 Cross-tile padding drops from (max_b nk_b)·NT to Σ_b nk_b k-steps — on a
 skewed FEM matrix this is the difference between pad_ratio ~3 and ~1.1
-(see tests/test_flat_path.py and docs/DESIGN.md §4; `benchmarks.run
---only flat` records the rect-vs-flat gap in results/BENCH_flat.json).
+(see tests/test_flat_path.py and docs/DESIGN.md §4).
 
 The flat path is a first-class registered KernelPath (core/paths.py):
 tuner-enumerable on skewed matrices, schedule-cached (`FlatBlockEll` is

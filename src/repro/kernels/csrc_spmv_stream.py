@@ -1,8 +1,7 @@
 """Streaming executors for the block-ELL / flat / nnz-split CSRC products.
 
 The one-hot Pallas kernels realize gather/scatter as (S, W) one-hot MXU
-contractions — O(W) work per slot, which is why the tuned local path sat
-~40000x above the mesh segment path (BENCH_serving, PR 5).  The paper's
+contractions — O(W) work per slot, so they are compute-bound.  The paper's
 whole premise is that CSRC SpMV is *memory-bound*: per slot the kernel
 must stream 12-16 bytes (value + local index [+ transpose value]) and do
 O(1) arithmetic.  This module is that streaming formulation, selected by

@@ -3,7 +3,7 @@
 One process-global :data:`REGISTRY` of labeled Counter/Gauge/Histogram
 families, nested :func:`span`\\ s recorded in a ring-buffer trace log and
 as profiler host annotations, JSON + Prometheus exporters, and a
-``snapshot()/diff`` API so tests and benchmarks assert on deltas.  See
+``snapshot()/diff`` API so tests assert on deltas.  See
 docs/DESIGN.md §9 for the metric-name table and label conventions.
 
 Quickstart::

@@ -20,7 +20,7 @@ Design constraints (docs/DESIGN.md §9):
 * bounded label cardinality: past ``MAX_CARDINALITY`` children per
   family, new label sets collapse into one ``_overflow`` child instead
   of growing without bound (a counter records the drops);
-* ``snapshot()`` / ``Snapshot.diff`` let tests and benchmarks assert on
+* ``snapshot()`` / ``Snapshot.diff`` let tests assert on
   deltas instead of absolute values, so suites compose;
 * exporters to structured JSON (``to_json``) and Prometheus text format
   (``to_prometheus``) — the scrape surface the serving-fleet router's
@@ -178,7 +178,7 @@ class Histogram:
 def quantile_from_counts(bounds, counts, total, q: float) -> float:
     """Quantile estimate from (bounds, per-bucket counts): geometric
     interpolation inside the winning bucket.  Shared by live histograms
-    and merged/snapshotted samples (benchmarks/trajectory.py)."""
+    and merged/snapshotted samples."""
     if total <= 0:
         return 0.0
     target = q * total

@@ -12,10 +12,9 @@ evaluated on MatrixStats instead of a built pack:
           writes + overlap-add re-reads;
   flops   O(1)-per-slot multiply-adds for the streaming/segment variants;
           the one-hot variants additionally pay the (S, W) mask build
-          (iota + compare + convert, one op per mask element — the same
-          ops roofline/hlo_cost.py now counts) and the dot_general
-          contractions, 2·S·W·nrhs flops each — which is exactly why
-          one-hot is compute-bound and stream is not;
+          (iota + compare + convert, one op per mask element) and the
+          dot_general contractions, 2·S·W·nrhs flops each — which is
+          exactly why one-hot is compute-bound and stream is not;
   predicted_s = max(bytes / HBM_BW, flops / PEAK_FLOPS_BF16), priced on
           the target chip's peaks (the v5e row of ``DEVICE_PEAKS``).
 

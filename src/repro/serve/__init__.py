@@ -1,9 +1,8 @@
 """The serving subsystem (docs/DESIGN.md §6).
 
-  engine.py     continuous-batching engines: token generation
-                (``ServingEngine``) and the paper's SpMV-as-a-service
-                (``SpmvServingEngine``), which coalesces same-matrix
-                requests into one multi-RHS SpMM per tick
+  engine.py     the paper's SpMV-as-a-service (``SpmvServingEngine``),
+                which coalesces same-matrix requests into one
+                multi-RHS SpMM per tick
   executor.py   pluggable execution behind a registered matrix:
                 ``LocalExecutor`` (single-device SpmvOperator) and
                 ``MeshExecutor`` (distributed strategies over mesh_p
@@ -11,11 +10,10 @@
   placement.py  plan resolution (local vs per-(matrix, p) mesh cache
                 entries) and executor construction
 """
-from .engine import (Request, ServingEngine, SpmvRequest, SpmvResult,
-                     SpmvServingEngine)
+from .engine import SpmvRequest, SpmvResult, SpmvServingEngine
 from .executor import LocalExecutor, MeshExecutor, SpmvExecutor
 
 __all__ = [
-    "Request", "ServingEngine", "SpmvRequest", "SpmvResult",
-    "SpmvServingEngine", "LocalExecutor", "MeshExecutor", "SpmvExecutor",
+    "SpmvRequest", "SpmvResult", "SpmvServingEngine",
+    "LocalExecutor", "MeshExecutor", "SpmvExecutor",
 ]

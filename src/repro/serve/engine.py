@@ -1,26 +1,13 @@
-"""Batched continuous serving engines.
+"""The paper's workload as a continuous-batching service.
 
-Two engines share the continuous-batching discipline:
-
-* ``ServingEngine`` — token generation.  Fixed-slot batching (the standard
-  TPU serving shape discipline): the decode step always runs at
-  (max_slots, 1); finished or empty slots hold padding.  Requests are
-  admitted into free slots between steps, prefill fills the slot's cache
-  region, greedy/temperature sampling produces tokens until EOS or
-  max_new_tokens.
-
-* ``SpmvServingEngine`` — the paper's workload as a service: clients
-  submit (matrix_id, x) products; matrices are registered once and get an
-  :class:`ExecutionPlan` from the plan-cache/tuner (a cache hit means a
-  known matrix class is never re-tuned), and each tick answers all pending
-  requests per matrix with one batched multi-RHS product through a
-  pluggable :class:`~repro.serve.executor.SpmvExecutor` — single-device
-  (``LocalExecutor``) or distributed across a mesh (``MeshExecutor``),
-  chosen by the plan's ``strategy``/``mesh_p`` fields
-  (serve/placement.py).
-
-The decode step is the same function the launch layer lowers for the
-256-chip serve dry-run.
+``SpmvServingEngine``: clients submit (matrix_id, x) products; matrices
+are registered once and get an :class:`ExecutionPlan` from the
+plan-cache/tuner (a cache hit means a known matrix class is never
+re-tuned), and each tick answers all pending requests per matrix with
+one batched multi-RHS product through a pluggable
+:class:`~repro.serve.executor.SpmvExecutor` — single-device
+(``LocalExecutor``) or distributed across a mesh (``MeshExecutor``),
+chosen by the plan's ``strategy``/``mesh_p`` fields (serve/placement.py).
 """
 from __future__ import annotations
 
@@ -28,110 +15,11 @@ import dataclasses
 import time
 from typing import Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 
-
-@dataclasses.dataclass
-class Request:
-    uid: int
-    prompt: np.ndarray            # (S,) int32
-    max_new_tokens: int = 16
-    temperature: float = 0.0      # 0 => greedy
-    out_tokens: Optional[List[int]] = None
-
-
-class ServingEngine:
-    def __init__(self, model, params, max_slots: int, max_len: int,
-                 eos_id: int = 1, seed: int = 0):
-        self.model = model
-        self.params = params
-        self.max_slots = max_slots
-        self.max_len = max_len
-        self.eos_id = eos_id
-        self.key = jax.random.PRNGKey(seed)
-        self.queue: List[Request] = []
-        self.active: Dict[int, Request] = {}       # slot -> request
-        self.remaining: Dict[int, int] = {}
-        # one decode state per slot (batch=1 states merged by stacking would
-        # complicate ring caches; slots are independent for clarity)
-        self._states: Dict[int, object] = {}
-        self._decode = jax.jit(model.decode_step)
-        self._prefill = jax.jit(model.prefill,
-                                static_argnames=("max_len",))
-
-    def submit(self, req: Request):
-        req.out_tokens = []
-        self.queue.append(req)
-
-    def _admit(self):
-        """Admit queued requests into free slots; returns the requests
-        that finished at prefill (EOS straight from the prompt, or a
-        one-token budget) — those never occupy a decode slot."""
-        finished = []
-        for slot in range(self.max_slots):
-            if slot in self.active or not self.queue:
-                continue
-            req = self.queue.pop(0)
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None]
-            state, logits = self._prefill(self.params, prompt,
-                                          max_len=self.max_len)
-            tok = self._sample(logits[:, -1], req.temperature)
-            req.out_tokens.append(int(tok[0]))
-            # the prefill token counts toward max_new_tokens; retire here
-            # when it is EOS or exhausts the budget, instead of burning a
-            # decode tick on an already-finished request
-            if int(tok[0]) == self.eos_id or req.max_new_tokens <= 1:
-                finished.append(req)
-                continue
-            self.active[slot] = req
-            self.remaining[slot] = req.max_new_tokens - 1
-            self._states[slot] = (state, tok)
-        return finished
-
-    def _sample(self, logits, temperature: float):
-        if temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self.key, k = jax.random.split(self.key)
-        return jax.random.categorical(
-            k, logits.astype(jnp.float32) / temperature, axis=-1
-        ).astype(jnp.int32)
-
-    def step(self):
-        """One engine tick: admit, decode every active slot, retire."""
-        finished = self._admit()
-        done = []
-        for slot, req in self.active.items():
-            state, last_tok = self._states[slot]
-            state, logits = self._decode(self.params, state,
-                                         last_tok[:, None])
-            tok = self._sample(logits[:, 0], req.temperature)
-            req.out_tokens.append(int(tok[0]))
-            self._states[slot] = (state, tok)
-            self.remaining[slot] -= 1
-            if int(tok[0]) == self.eos_id or self.remaining[slot] <= 0:
-                done.append(slot)
-        for slot in done:
-            finished.append(self.active.pop(slot))
-            self._states.pop(slot)
-            self.remaining.pop(slot)
-        return finished
-
-    def run_until_drained(self, max_ticks: int = 1000):
-        out = []
-        for _ in range(max_ticks):
-            out.extend(self.step())
-            if not self.queue and not self.active:
-                break
-        return out
-
-
-# ---------------------------------------------------------------------------
-# SpMV serving (the paper's kernel as a traffic-serving endpoint)
-# ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
 class SpmvRequest:

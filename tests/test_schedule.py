@@ -156,8 +156,8 @@ def test_tune_stores_winning_schedule():
 # Coloring quality: largest-degree-first + RACE-style balancing
 # ---------------------------------------------------------------------------
 
-# Small-scale analogs of every benchmark-suite matrix class
-# (benchmarks/suite.py) — the invariant set for coloring quality.
+# Small-scale analogs of the generator matrix classes — the invariant set
+# for coloring quality.
 COLORING_SET = [
     ("poisson", lambda: csrc.poisson2d(8)),
     ("narrow_band1", lambda: csrc.fem_band(120, 1, seed=1)),

@@ -1,192 +1,6 @@
-"""Serving: engine batching/draining, greedy determinism, ring-buffer
-sliding-window decode correctness."""
+"""SpMV serving: overflow ordering and coalesced-batch metadata."""
 import numpy as np
-import jax
-import jax.numpy as jnp
-import pytest
 
-from repro.configs.base import get_config
-from repro.models.transformer import build_model
-from repro.models import attention as A
-from repro.serve.engine import ServingEngine, Request
-
-
-@pytest.fixture(scope="module")
-def small_model():
-    cfg = get_config("granite-3-2b", reduced=True)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    return cfg, model, params
-
-
-@pytest.mark.slow
-def test_engine_drains_and_batches(small_model):
-    cfg, model, params = small_model
-    eng = ServingEngine(model, params, max_slots=3, max_len=64, eos_id=0)
-    rng = np.random.default_rng(0)
-    for i in range(7):
-        eng.submit(Request(uid=i, prompt=rng.integers(2, 64, 5 + i % 3),
-                           max_new_tokens=5))
-    done = eng.run_until_drained()
-    assert len(done) == 7
-    assert all(1 <= len(r.out_tokens) <= 5 for r in done)
-
-
-@pytest.mark.slow
-def test_greedy_determinism(small_model):
-    cfg, model, params = small_model
-    outs = []
-    for _ in range(2):
-        eng = ServingEngine(model, params, max_slots=1, max_len=64,
-                            eos_id=0)
-        eng.submit(Request(uid=0, prompt=np.arange(2, 10),
-                           max_new_tokens=8))
-        outs.append(eng.run_until_drained()[0].out_tokens)
-    assert outs[0] == outs[1]
-
-
-def test_ring_positions():
-    from repro.models.attention import ring_positions
-    t = 8
-    # after writing token 11 at slot 3, slot i holds max p<=11, p≡i (mod 8)
-    pos = np.asarray(ring_positions(jnp.asarray(11), t))
-    assert pos[3] == 11 and pos[4] == 4 and pos[0] == 8
-    # short fill: unwritten slots masked with INT32_MAX
-    pos = np.asarray(ring_positions(jnp.asarray(2), t))
-    assert pos[2] == 2 and pos[7] == np.iinfo(np.int32).max
-
-
-@pytest.mark.slow
-def test_ring_decode_matches_window_attention():
-    """Sliding-window ring decode == full attention restricted to the
-    window, for positions beyond the buffer size."""
-    d, h, kv, hd = 32, 4, 4, 8
-    params = A.init_attn(jax.random.PRNGKey(0), d, h, kv, hd,
-                         dtype=jnp.float32)
-    rng = np.random.default_rng(0)
-    seq = jnp.asarray(rng.standard_normal((1, 20, d)), jnp.float32)
-    window = 6
-    # reference: full-sequence attention with sliding window
-    pos = jnp.arange(20)
-    y_ref, _ = A.attn_forward(params, seq, pos, n_heads=h, n_kv=kv,
-                              head_dim=hd, window=window)
-    # ring decode token by token with a buffer of exactly `window`
-    k = jnp.zeros((1, window, kv, hd), jnp.float32)
-    v = jnp.zeros((1, window, kv, hd), jnp.float32)
-    for t in range(20):
-        y_t, k, v = A.attn_decode_ring(
-            params, seq[:, t:t + 1], k, v, jnp.asarray(t), n_heads=h,
-            n_kv=kv, head_dim=hd, window=window)
-        np.testing.assert_allclose(
-            np.asarray(y_t[0, 0]), np.asarray(y_ref[0, t]),
-            rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.slow
-def test_int8_kv_decode_consistency():
-    """§Perf cell C lever: int8 KV cache decode matches bf16 within
-    quantization noise."""
-    import dataclasses
-    from repro.configs.base import get_config
-    from repro.models.transformer import build_model
-    cfg = get_config("qwen3-8b", reduced=True)
-    rng = np.random.default_rng(1)
-    b, s = 2, 12
-    seq = jnp.asarray(rng.integers(0, cfg.vocab, (b, s + 1)), jnp.int32)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(1))
-    ref = model.forward(params, seq)[:, s].astype(jnp.float32)
-    mq = build_model(dataclasses.replace(cfg, kv_cache_dtype="int8"))
-    state, _ = mq.prefill(params, seq[:, :s], max_len=s + 8)
-    state, logits = mq.decode_step(params, state, seq[:, s:s + 1])
-    got = logits[:, 0].astype(jnp.float32)
-    err = float(jnp.abs(got - ref).max()) / (float(jnp.abs(ref).max()) + 1e-6)
-    assert err < 0.1, err
-
-
-def test_quantize_kv_roundtrip():
-    from repro.models.attention import quantize_kv, dequantize_kv
-    rng = np.random.default_rng(0)
-    k = jnp.asarray(rng.standard_normal((2, 5, 4, 16)) * 3.0, jnp.float32)
-    q, s = quantize_kv(k)
-    back = dequantize_kv(q, s, jnp.float32)
-    assert q.dtype == jnp.int8
-    np.testing.assert_allclose(np.asarray(back), np.asarray(k),
-                               atol=float(jnp.abs(k).max()) / 100)
-
-
-# ---------------------------------------------------------------------------
-# Token accounting (the off-by-one/prefill-EOS regression pins)
-# ---------------------------------------------------------------------------
-
-class _CountingModel:
-    """Deterministic toy LM: the next token is always (prev + 1) mod vocab,
-    so tests can steer exactly when EOS appears without a real model."""
-    vocab = 16
-
-    def prefill(self, params, prompt, max_len):
-        nxt = (prompt[:, -1:] + 1) % self.vocab
-        return jnp.zeros(()), jax.nn.one_hot(nxt, self.vocab)
-
-    def decode_step(self, params, state, tok):
-        nxt = (tok[:, -1:] + 1) % self.vocab
-        return state, jax.nn.one_hot(nxt, self.vocab)
-
-
-def _counting_engine(eos_id, max_slots=2):
-    return ServingEngine(_CountingModel(), params=None,
-                         max_slots=max_slots, max_len=32, eos_id=eos_id)
-
-
-def test_max_new_tokens_one_emits_exactly_one_token():
-    """Regression: the prefill token counts toward the budget — a budget
-    of one must not burn a decode tick and emit a second token."""
-    eng = _counting_engine(eos_id=15)
-    eng.submit(Request(uid=0, prompt=np.array([2, 3]), max_new_tokens=1))
-    done = eng.step()
-    assert [r.uid for r in done] == [0]
-    assert done[0].out_tokens == [4]          # exactly one, no decode
-    assert not eng.active and not eng.queue
-
-
-def test_prefill_eos_retires_without_decode_tick():
-    """Regression: a prompt whose very first sampled token is EOS must
-    retire at admission, not occupy a slot for one more decode."""
-    eng = _counting_engine(eos_id=9)
-    eng.submit(Request(uid=0, prompt=np.array([3, 8]),   # prefill -> 9
-                       max_new_tokens=10))
-    done = eng.step()
-    assert [r.uid for r in done] == [0]
-    assert done[0].out_tokens == [9]
-    assert not eng.active and not eng.queue
-
-
-def test_exact_token_budget_without_eos():
-    """max_new_tokens is exact when EOS never fires: k tokens, not k+1."""
-    eng = _counting_engine(eos_id=15)
-    for k in (1, 2, 5):
-        eng.submit(Request(uid=k, prompt=np.array([0]), max_new_tokens=k))
-    done = eng.run_until_drained()
-    assert {r.uid: len(r.out_tokens) for r in done} == {1: 1, 2: 2, 5: 5}
-    assert all(r.out_tokens == list(range(1, r.uid + 1)) for r in done)
-
-
-def test_prefill_eos_frees_slot_for_queue():
-    """A prefill-finished request never occupies a slot, so a queued
-    request behind it is admitted the same tick."""
-    eng = _counting_engine(eos_id=9, max_slots=1)
-    eng.submit(Request(uid=0, prompt=np.array([8]), max_new_tokens=10))
-    eng.submit(Request(uid=1, prompt=np.array([0]), max_new_tokens=3))
-    done = eng.run_until_drained()
-    assert sorted(r.uid for r in done) == [0, 1]
-    by_uid = {r.uid: r.out_tokens for r in done}
-    assert by_uid[0] == [9]
-    assert by_uid[1] == [1, 2, 3]
-
-
-# ---------------------------------------------------------------------------
-# SpMV serving: overflow ordering and coalesced-batch metadata
-# ---------------------------------------------------------------------------
 
 def _spmv_engine(max_batch):
     from repro.core import csrc, tuner

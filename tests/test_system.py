@@ -1,19 +1,10 @@
 """End-to-end behaviour tests for the paper's system: the full CSRC stack
-(build → pack → kernel → accumulate → solver) and the dry-run cell driver
-on a small mesh."""
-import os
-import subprocess
-import sys
-import textwrap
-
+(build → pack → kernel → accumulate → solver)."""
 import numpy as np
 import jax.numpy as jnp
-import pytest
 
 from repro.core import csrc, solvers
 from repro.kernels import ops
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_end_to_end_fem_solve():
@@ -45,55 +36,3 @@ def test_paper_bandwidth_claim():
     p_ns = blockell.pack(M, tm=64)
     p_s = blockell.pack(Ms, tm=64)
     assert p_s.streamed_bytes() < p_ns.streamed_bytes()
-
-
-@pytest.mark.slow
-def test_dryrun_cell_on_test_mesh():
-    """The launch driver lowers+compiles a real cell on a small placeholder
-    mesh (subprocess: 8 fake devices) — the same path the 512-chip run
-    uses."""
-    code = """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax
-        from repro.launch.dryrun import lower_cell
-        mesh = jax.make_mesh((4, 2), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        rec = lower_cell("qwen1.5-0.5b", "train_4k", mesh, "4x2",
-                         verbose=False)
-        assert rec["status"] == "ok", rec
-        r = rec["roofline"]
-        assert r["hlo_flops"] > 0 and r["collective_bytes"] > 0
-        print("OK", r["bottleneck"])
-    """
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                         capture_output=True, text=True, env=env,
-                         timeout=600)
-    assert out.returncode == 0, out.stderr[-3000:]
-
-
-def test_all_cells_have_records_or_skips():
-    """After the full dry-run sweep, every (arch × shape × mesh) cell must
-    have a record: ok or a documented skip.  Runs only when results exist
-    (the sweep is executed by `python -m repro.launch.dryrun`)."""
-    outdir = os.path.join(ROOT, "results", "dryrun")
-    if not os.path.isdir(outdir) or len(os.listdir(outdir)) < 80:
-        pytest.skip("full dry-run sweep not yet executed")
-    import json
-    from repro.configs.base import registry
-    from repro.configs.shapes import SHAPES
-    bad = []
-    for arch in registry():
-        for shape in SHAPES:
-            for mesh in ("16x16", "2x16x16"):
-                p = os.path.join(outdir, f"{arch}__{shape}__{mesh}.json")
-                if not os.path.exists(p):
-                    bad.append((arch, shape, mesh, "missing"))
-                    continue
-                rec = json.load(open(p))
-                if rec["status"] not in ("ok", "skipped"):
-                    bad.append((arch, shape, mesh, rec["status"]))
-    assert not bad, bad
