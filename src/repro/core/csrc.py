@@ -25,14 +25,18 @@ ready for jit'd products.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.runtime import to_host
 
 Array = np.ndarray
+
+ARRAY_FIELDS = ("ad", "ia", "ja", "al", "au", "iar", "jar", "ar")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +56,10 @@ class CSRC:
     jar: jnp.ndarray            # (kr,) column indices in [0, m-n)
     ar: jnp.ndarray             # (kr,)
     numerically_symmetric: bool = False
+    # digests of this instance (memo_digest); not part of its value, and
+    # a dataclasses.replace copy starts with none
+    _digests: dict = dataclasses.field(init=False, repr=False,
+                                       compare=False, default_factory=dict)
 
     @property
     def k(self) -> int:
@@ -70,13 +78,33 @@ class CSRC:
     def working_set_bytes(self) -> int:
         """Paper Table 1's ``ws`` column: bytes touched by one product."""
         total = 0
-        for a in (self.ad, self.ia, self.ja, self.al, self.au,
-                  self.iar, self.jar, self.ar):
+        for f in ARRAY_FIELDS:
+            a = getattr(self, f)
             total += a.size * a.dtype.itemsize
         # source + destination vectors
         total += self.m * self.ad.dtype.itemsize
         total += self.n * self.ad.dtype.itemsize
         return total
+
+
+def memo_digest(M: CSRC, kind: str, compute: Callable[[CSRC], str]) -> str:
+    """``compute(M)``, computed once per instance when every array of ``M``
+    is a ``jax.Array``.
+
+    Such arrays cannot change, so a digest of the instance holds for its
+    lifetime: a hit returns the stored string with no span and no copy.
+    A matrix with any host array (editable in place) is digested every
+    call.  Counts ``digest_memo_total{kind, outcome=hit|miss|uncached}``.
+    """
+    if not all(isinstance(getattr(M, f), jax.Array) for f in ARRAY_FIELDS):
+        obs.count("digest_memo_total", kind=kind, outcome="uncached")
+        return compute(M)
+    digest = M._digests.get(kind)
+    obs.count("digest_memo_total", kind=kind,
+              outcome="miss" if digest is None else "hit")
+    if digest is None:
+        digest = M._digests[kind] = compute(M)
+    return digest
 
 
 def _dedup_coo(rows: Array, cols: Array, vals: Array, n: int, m: int):

@@ -51,7 +51,7 @@ from repro.runtime import to_host
 from . import paths as paths_mod
 from .blockell import BlockEll
 from .coloring import Coloring
-from .csrc import CSRC, row_of_slot
+from .csrc import CSRC, memo_digest, row_of_slot
 from .partition import (RowPartition, halo_widths, partition_rows_by_count,
                         partition_rows_by_nnz)
 # the build probe lives with the registry (path builders count into it);
@@ -165,7 +165,12 @@ def value_digest(M: CSRC) -> str:
     matrix values (pack value streams, per-slot al/au), so its cache key
     additionally pins the exact matrix — a same-class matrix with different
     values rebuilds instead of silently reusing another matrix's values.
+    Computed once per device-backed instance (``csrc.memo_digest``).
     """
+    return memo_digest(M, "value", _value_digest)
+
+
+def _value_digest(M: CSRC) -> str:
     h = hashlib.sha1()
     with obs.span("schedule.value_digest"):
         for a in (M.ia, M.ja, M.ad, M.al, M.au, M.iar, M.jar, M.ar):
@@ -181,8 +186,13 @@ def structure_digest(M: CSRC) -> str:
     time-stepping shape (re-assembled stiffness on a fixed mesh).  For
     such a pair every structural schedule artifact (partition, halo,
     coloring, pack index streams) is identical; only the value streams
-    need refreshing (:func:`refresh_schedule`).
+    need refreshing (:func:`refresh_schedule`).  Computed once per
+    device-backed instance (``csrc.memo_digest``).
     """
+    return memo_digest(M, "structure", _structure_digest)
+
+
+def _structure_digest(M: CSRC) -> str:
     h = hashlib.sha1()
     h.update(np.asarray([M.n, M.m], np.int64).tobytes())
     with obs.span("schedule.structure_digest"):

@@ -56,7 +56,8 @@ import numpy as np
 
 from repro import obs
 from . import paths as paths_mod
-from .csrc import CSRC, bandwidth as csrc_bandwidth, nnz_per_row
+from .csrc import (CSRC, bandwidth as csrc_bandwidth, memo_digest,
+                   nnz_per_row)
 from .plan import ExecutionPlan, feasible, kernel_window
 
 def _time_fn(fn, *args, warmup: int = 3, repeats: int = 10) -> float:
@@ -110,7 +111,12 @@ def fingerprint(M: CSRC) -> str:
     """Stable key of the matrix *class*: (n, m, k, bandwidth) in the clear
     plus a digest of the nnz-per-row histogram and symmetry flag.  Two
     matrices of the same class (same generator, same size) share a key, so
-    solvers and the serve engine never re-tune a known class."""
+    solvers and the serve engine never re-tune a known class.  Computed
+    once per device-backed instance (``csrc.memo_digest``)."""
+    return memo_digest(M, "fingerprint", _fingerprint)
+
+
+def _fingerprint(M: CSRC) -> str:
     with obs.span("tune.fingerprint"):
         w = nnz_per_row(M, site="fingerprint")
         hist = np.bincount(np.minimum(w, 255).astype(np.int64),

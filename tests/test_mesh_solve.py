@@ -128,16 +128,36 @@ def test_mesh_solve_span_tree_and_labels():
                                maxiter=3)
     b = ex.place(rhs(A, seed=2))
     obs.clear_trace()
+    s0 = obs.snapshot()
     solvers.cg_solve(M, b, mesh_p=1, cache=cache, tol=0.0, maxiter=3)
-    fp = ("tune.fingerprint", {}, [])
+    # warm call: the fingerprint and value digest are the matrix's memo
     bind = {"path": ex.plan.path, "strategy": ex.plan.accumulation}
     assert _tree(obs.trace()) == [
         ("solver.cg_solve", {"mesh_p": "1"}, [
-            ("tune.resolve", {}, [fp]),
-            ("kernels.bind", bind,
-             [fp, ("schedule.value_digest", {}, [])]),
+            ("tune.resolve", {}, []),
+            ("kernels.bind", bind, []),
             ("solver.place", {}, []),
             ("solver.dispatch", {}, [])])]
+    d = obs.snapshot().diff(s0)
+    assert d.value("digest_memo_total", kind="fingerprint",
+                   outcome="hit") == 2
+    assert d.value("digest_memo_total", kind="value", outcome="hit") == 1
+
+
+@pytest.mark.parametrize("acc", STRATEGIES)
+def test_warm_mesh_solve_digests_nothing(acc):
+    M, A = stencil27(4, 4, 4)
+    cache = tuner.PlanCache()
+    plan = mesh_plan(acc)
+    solvers.cg_solve(M, rhs(A), plan=plan, mesh_p=1, cache=cache,
+                     tol=0.0, maxiter=2)
+    s0 = obs.snapshot()
+    solvers.cg_solve(M, rhs(A, seed=1), plan=plan, mesh_p=1, cache=cache,
+                     tol=0.0, maxiter=2)
+    d = obs.snapshot().diff(s0)
+    assert d.total("digest_memo_total", outcome="miss") == 0
+    assert d.total("digest_memo_total", outcome="hit") == 2
+    assert d.total("host_copy_bytes_total") == 0
 
 
 def test_local_route_binds_with_strategy_local():

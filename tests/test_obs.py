@@ -258,6 +258,14 @@ RESOLVED_PLAN = ("tune.resolve", [FP])
 CG_CALL_TAIL = [("kernels.bind", []), ("solver.dispatch", [])]
 
 
+def _memo(d):
+    """{(kind, outcome): count} of ``digest_memo_total`` in a diff."""
+    return {(k, o): d.value("digest_memo_total", kind=k, outcome=o)
+            for k in ("fingerprint", "structure", "value")
+            for o in ("hit", "miss", "uncached")
+            if d.value("digest_memo_total", kind=k, outcome=o)}
+
+
 def _segment_solve(M, b, cache):
     from repro.core import solvers
     from repro.core.plan import ExecutionPlan
@@ -277,18 +285,20 @@ def test_cg_solve_span_tree_and_one_trace_a_call():
     s0 = obs.snapshot()
     for _ in range(2):
         _segment_solve(M, b, cache)
+    # warm calls: the fingerprint and value digest are the matrix's memo
     call = ("solver.cg_solve",
-            [RESOLVED_PLAN,
-             ("schedule.resolve", [FP, ("schedule.value_digest", [])])]
+            [("tune.resolve", []), ("schedule.resolve", [])]
             + CG_CALL_TAIL)
     assert _tree(obs.trace()) == [call, call]
     d = obs.snapshot().diff(s0)
     assert d.value("solver_traces_total") == 2
-    # every span's record tallies what its subtree copied and traced
+    assert _memo(d) == {("fingerprint", "hit"): 4, ("value", "hit"): 2}
+    # every span's record tallies what its subtree digested and traced
     (root, _) = obs.trace("solver.cg_solve")
     assert root["counts"]["solver_traces_total"] == 1
-    assert root["counts"]["host_copy_bytes_total"] == (
-        d.total("host_copy_bytes_total") / 2)
+    assert root["counts"]["digest_memo_total"] == 3
+    assert "host_copy_bytes_total" not in root["counts"]
+    assert d.total("host_copy_bytes_total") == 0
 
 
 def test_host_copy_bytes_of_fingerprint_and_digests():
@@ -320,6 +330,92 @@ def test_host_copy_bytes_of_fingerprint_and_digests():
             getattr(M, f)) for f in ("n", "m", "ad", "ia", "ja", "al",
                                      "au", "iar", "jar", "ar")}))
     assert obs.snapshot().diff(s1).total("host_copy_bytes_total") == 0
+
+
+def _digests(M):
+    from repro.core import schedule, tuner
+    return (tuner.fingerprint(M), schedule.value_digest(M),
+            schedule.structure_digest(M))
+
+
+def _host_copy(M):
+    """``M`` with every array a NumPy array."""
+    from repro.core import csrc
+    return csrc.CSRC(n=M.n, m=M.m, numerically_symmetric=(
+        M.numerically_symmetric), **{f: np.array(getattr(M, f))
+                                     for f in csrc.ARRAY_FIELDS})
+
+
+@pytest.mark.parametrize("make, want", [
+    (lambda c: c.poisson2d(8),
+     ("n64m64k112b8-50d9bc43c1e3", "9e86227e76e6c429", "f35dc08eed9d3018")),
+    (lambda c: c.fem_band(40, 5, seed=3),
+     ("n40m40k118b5-db479b3143a8", "bc55baae403daf5d", "1538a9d20b414b62")),
+    (lambda c: c.rectangular_fem(30, 7, 4, seed=1),
+     ("n30m37k65b4-c5b633b4fae3", "646104746035d14d", "296ca02783ee5536")),
+])
+def test_digest_strings_are_stable_and_memoised(make, want):
+    """The strings key plan caches and schedule files on disk: a memoised
+    digest, a second call and a host-backed copy all give them."""
+    from repro.core import csrc
+    M = make(csrc)
+    s0 = obs.snapshot()
+    assert _digests(M) == want
+    assert _digests(M) == want
+    assert _digests(_host_copy(M)) == want
+    assert _memo(obs.snapshot().diff(s0)) == {
+        (k, o): 1 for k in ("fingerprint", "structure", "value")
+        for o in ("hit", "miss", "uncached")}
+
+
+def test_warm_cg_solve_copies_nothing_to_the_host():
+    import jax.numpy as jnp
+    from repro.core import csrc, tuner
+    M = csrc.poisson2d(8)
+    cache = tuner.PlanCache()
+    b = jnp.ones(M.n, jnp.float32)
+    s0 = obs.snapshot()
+    _segment_solve(M, b, cache)
+    cold = obs.snapshot().diff(s0)
+    assert cold.total("host_copy_bytes_total") > 0
+    assert _memo(cold)[("fingerprint", "miss")] == 1
+    s1 = obs.snapshot()
+    _segment_solve(M, b, cache)
+    warm = obs.snapshot().diff(s1)
+    assert warm.total("host_copy_bytes_total") == 0
+    assert _memo(warm) == {("fingerprint", "hit"): 2, ("value", "hit"): 1}
+
+
+@pytest.mark.parametrize("derive", ["replace", "transpose"])
+def test_a_derived_matrix_starts_with_no_digests(derive):
+    import dataclasses
+    from repro.core import csrc
+    M = csrc.fem_band(40, 5, seed=3)
+    before = _digests(M)
+    if derive == "replace":
+        N = dataclasses.replace(M, al=M.al * 2, au=M.au * 3)
+    else:
+        N = csrc.transpose(M)
+    s0 = obs.snapshot()
+    got = _digests(N)
+    assert _memo(obs.snapshot().diff(s0)) == {
+        (k, "miss"): 1 for k in ("fingerprint", "structure", "value")}
+    assert got == _digests(_host_copy(N))
+    assert got[1] != before[1] and got[2] == before[2]
+
+
+def test_host_backed_matrix_is_digested_every_call():
+    from repro.core import csrc, schedule
+    H = _host_copy(csrc.fem_band(40, 5, seed=3))
+    s0 = obs.snapshot()
+    first = schedule.value_digest(H)
+    H.al[0] += 1.0                     # a host array edits in place
+    assert schedule.value_digest(H) != first
+    assert _memo(obs.snapshot().diff(s0)) == {("value", "uncached"): 2}
+    assert H._digests == {}
+    obs.clear_trace()
+    schedule.value_digest(H)
+    assert len(obs.trace("schedule.value_digest")) == 1
 
 
 def test_assemble_spans_and_value_copy():
@@ -354,17 +450,21 @@ def test_cg_solve_on_new_values_refreshes_the_schedule():
         return _segment_solve(M, jnp.ones(M.n, jnp.float32), cache)
     step(0.5)
     obs.clear_trace()
+    s0 = obs.snapshot()
     step(0.75)
-    digests = [("schedule.structure_digest", []),
-               ("schedule.value_digest", [])]
+    # the new matrix is digested once a kind; schedule_for's fingerprint
+    # and the refresh's digests are hits
     solve = ("solver.cg_solve",
              [RESOLVED_PLAN,
               ("schedule.resolve",
-               [FP, ("schedule.value_digest", []),
+               [("schedule.value_digest", []),
                 ("schedule.structure_digest", []),
-                ("schedule.value_refresh", digests)])]
+                ("schedule.value_refresh", [])])]
              + CG_CALL_TAIL)
     assert _tree(obs.trace())[1] == solve
+    assert _memo(obs.snapshot().diff(s0)) == {
+        (k, o): 1 for k in ("fingerprint", "structure", "value")
+        for o in ("hit", "miss")}
 
 
 # ---------------------------------------------------------------------------
