@@ -31,7 +31,7 @@ required to agree with the oracle **bit-for-bit** (tests and the CI
 assembly smoke assert equality, not closeness).
 
 All structure-dependent precompute — slot layout, contribution targets,
-element coloring, buffer grouping — lives in the npz-serializable
+element coloring, private-buffer count — lives in the npz-serializable
 :class:`AssemblySchedule`, stored in the tuner's PlanCache next to the
 SpMV schedules and keyed by a **connectivity digest**: FEM time stepping
 re-assembles with unchanged connectivity and must reuse every artifact
@@ -62,11 +62,13 @@ from repro.runtime import jit_hoisted, to_host
 from .conflict import color_elements, element_dofs
 from .mesh import Mesh
 
-# version 3: the schedule carries the kernel slot packs — per-color
+# version 4: the greedy element coloring is the vectorised one of
+# assembly/conflict.py, and the slot packs index contributions in
+# component-major order.  Version 3 added the kernel slot packs — per-color
 # (slots, targets) streams and the destination-sorted permutation — with
-# overflow-gated int16 index dtypes.  Version-2 files load as misses and
-# are rebuilt transparently (version 2 added the coloring provider).
-ASSEMBLY_VERSION = 3
+# overflow-gated int16 index dtypes, version 2 the coloring provider.
+# Files of another version load as misses and are rebuilt transparently.
+ASSEMBLY_VERSION = 4
 
 STRATEGIES = ("colored", "sorted", "private", "serial")
 
@@ -79,6 +81,11 @@ ASSEMBLY_CANDIDATES = (
 
 _DEFAULT_VARIANT = {"colored": "stream", "sorted": "stream",
                     "private": "vmap", "serial": "numpy"}
+
+# the one-hot body runs one program per (output tile, contribution chunk),
+# a count that grows as size × contributions: past this many a measurement
+# cannot finish, and the tuner refuses the candidate
+ONEHOT_MAX_PROGRAMS = 1 << 22
 
 # int16 index streams iff every representable value (including the
 # sentinel one past the real range) fits — same overflow gate as the
@@ -105,25 +112,34 @@ class AssemblySchedule:
     ne: int                     # elements
     edof: int                   # DOFs per element
     ndof_per_node: int
-    num_buffers: int            # private-buffer strategy width
+    # private-buffer strategy width: buffer b holds the elements
+    # [b·epb, (b+1)·epb), epb = ceil(ne / num_buffers)
+    num_buffers: int
     ia: np.ndarray              # (n+1,) CSRC lower-triangle row pointers
     ja: np.ndarray              # (k,)
     # contribution (e, a, b) at flat index e·edof² + a·edof + b scatters to
     # targets[...] in the unified [ad | al | au] vector of length n + 2k
     targets: np.ndarray         # (ne·edof²,) int32
     coloring: Coloring          # element coloring (conflict.color_elements)
-    buffer_elements: np.ndarray  # (num_buffers, epb) int32, -1 = padding
     # --- kernel slot packs (version 3) -------------------------------
     # per-color contribution streams, padded to a rectangular (C, Lmax)
-    # table: slots index the flat ke (sentinel = ne·edof², gathers an
-    # appended zero), targets index the unified vector (sentinel = size,
-    # the segment-sum drop slot).  int16 when the overflow gate allows.
+    # table: slots index the component-major contributions (entry
+    # j·ne + e is ke[e].flat[j], kernels.assembly_scatter.component_major;
+    # sentinel = ne·edof², gathers an appended zero), targets index the
+    # unified vector (sentinel = size, the segment-sum drop slot).  int16
+    # when the overflow gate allows.
     color_slots: np.ndarray      # (C, Lmax) int16|int32
     color_targets: np.ndarray    # (C, Lmax) int16|int32
     # destination-sorted permutation of all contributions (sorted-slot
-    # strategy): perm gathers ke.flat, sorted_targets is monotone
+    # strategy): perm gathers the component-major contributions,
+    # sorted_targets is monotone
     sorted_perm: np.ndarray      # (ne·edof²,) int16|int32
     sorted_targets: np.ndarray   # (ne·edof²,) int16|int32
+    # the jitted executor of each (strategy, variant) assemble() has run,
+    # its packs placed on the device once (not part of the schedule's
+    # value: left out of equality, repr and the npz)
+    _executors: Dict[Tuple[str, str], object] = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
 
     @property
     def size(self) -> int:
@@ -160,7 +176,6 @@ class AssemblySchedule:
             color_of_row=np.asarray(self.coloring.color_of_row),
             rows_by_color=np.asarray(self.coloring.rows_by_color),
             color_ptr=np.asarray(self.coloring.color_ptr),
-            buffer_elements=np.asarray(self.buffer_elements),
             color_slots=np.asarray(self.color_slots),
             color_targets=np.asarray(self.color_targets),
             sorted_perm=np.asarray(self.sorted_perm),
@@ -176,8 +191,10 @@ class AssemblySchedule:
                 self.coloring.group_of_row)
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         tmp = path + ".tmp.npz"
+        # stored, not deflated: at 10**8 contributions zlib would take
+        # minutes to write what the build takes a minute to make
         with open(tmp, "wb") as f:
-            np.savez_compressed(f, __meta__=np.frombuffer(
+            np.savez(f, __meta__=np.frombuffer(
                 json.dumps(meta, sort_keys=True).encode(), dtype=np.uint8),
                 **arrays)
         os.replace(tmp, path)
@@ -207,7 +224,6 @@ class AssemblySchedule:
                        num_buffers=meta["num_buffers"],
                        ia=z["ia"], ja=z["ja"], targets=z["targets"],
                        coloring=coloring,
-                       buffer_elements=z["buffer_elements"],
                        color_slots=z["color_slots"],
                        color_targets=z["color_targets"],
                        sorted_perm=z["sorted_perm"],
@@ -218,7 +234,7 @@ def structure_digest(conn: np.ndarray, ndof_per_node: int = 1,
                      num_nodes: Optional[int] = None) -> str:
     """Digest of the element connectivity (the assembly-side analog of
     ``schedule.structure_digest``): unchanged connectivity ⇒ identical
-    slot layout, targets, coloring, and buffer grouping."""
+    slot layout, targets, coloring and packs."""
     conn = np.ascontiguousarray(np.asarray(conn, np.int64))
     num_nodes = int(conn.max()) + 1 if num_nodes is None else num_nodes
     h = hashlib.sha1()
@@ -235,14 +251,22 @@ def _index_dtype(max_value: int):
     return np.int16 if max_value <= _INT16_MAX else np.int32
 
 
+def _component_major(flat: np.ndarray, ne: int, edof2: int) -> np.ndarray:
+    """Row-major contribution indices e·edof² + j as component-major ones,
+    j·ne + e (the order the kernels read ke in)."""
+    dt = np.int32 if ne * edof2 <= np.iinfo(np.int32).max else np.int64
+    e, j = np.divmod(flat.astype(dt, copy=False), dt(edof2))
+    return j * dt(ne) + e
+
+
 def _pack_colored(targets: np.ndarray, coloring: Coloring, edof2: int,
                   size: int) -> Tuple[np.ndarray, np.ndarray]:
     """The colored-batch kernel's (C, Lmax) slot/target streams.
 
-    Row c lists color c's contribution indices (element-major) and their
-    destinations, lane-aligned to a multiple of 128 and padded with the
-    sentinels the kernels drop (slot = G reads the appended zero, target
-    = size lands in the drop segment)."""
+    Row c lists color c's contributions (element-major, as component-major
+    indices) and their destinations, lane-aligned to a multiple of 128 and
+    padded with the sentinels the kernels drop (slot = G reads the
+    appended zero, target = size lands in the drop segment)."""
     num_contribs = int(targets.size)
     counts = [len(coloring.rows(c)) * edof2
               for c in range(coloring.num_colors)]
@@ -259,19 +283,21 @@ def _pack_colored(targets: np.ndarray, coloring: Coloring, edof2: int,
         if els.size == 0:
             continue
         sl = (els[:, None] * edof2 + lane).reshape(-1)
-        color_slots[c, :sl.size] = sl.astype(slot_dt)
+        color_slots[c, :sl.size] = _component_major(
+            sl, num_contribs // edof2, edof2).astype(slot_dt)
         color_targets[c, :sl.size] = targets[sl].astype(tgt_dt)
     return color_slots, color_targets
 
 
-def _pack_sorted(targets: np.ndarray,
+def _pack_sorted(targets: np.ndarray, edof2: int,
                  size: int) -> Tuple[np.ndarray, np.ndarray]:
     """The sorted-slot strategy's destination order: a stable argsort of
     the targets (build-time work) so the value refresh is one monotone
     segment-sum with no coloring at all."""
     num_contribs = int(targets.size)
     perm = np.argsort(targets, kind="stable")
-    sorted_perm = perm.astype(_index_dtype(num_contribs))
+    sorted_perm = _component_major(perm, num_contribs // edof2, edof2
+                                   ).astype(_index_dtype(num_contribs))
     sorted_targets = targets[perm].astype(_index_dtype(size))
     return sorted_perm, sorted_targets
 
@@ -333,9 +359,7 @@ def build_assembly_schedule(mesh_or_conn: Union[Mesh, np.ndarray],
 
         if coloring is None:
             BUILD_COUNTS.inc("element_coloring")
-            with obs.span("assembly.element_coloring",
-                          provider=coloring_provider):
-                coloring = color_elements(conn, provider=coloring_provider)
+            coloring = color_elements(conn, provider=coloring_provider)
 
         size = n + 2 * k
         BUILD_COUNTS.inc("assembly_color_pack")
@@ -345,21 +369,14 @@ def build_assembly_schedule(mesh_or_conn: Union[Mesh, np.ndarray],
                 targets, coloring, edof * edof, size)
         BUILD_COUNTS.inc("assembly_sorted_pack")
         with obs.span("assembly.sorted_pack", contributions=targets.size):
-            sorted_perm, sorted_targets = _pack_sorted(targets, size)
-
-    # private-buffer grouping: contiguous element chunks (locality), padded
-    # to a rectangular (B, epb) table with -1 sentinels
-    B = max(1, min(num_buffers, ne))
-    epb = -(-ne // B)
-    buffer_elements = np.full((B, epb), -1, dtype=np.int32)
-    flat = buffer_elements.reshape(-1)
-    flat[:ne] = np.arange(ne, dtype=np.int32)
+            sorted_perm, sorted_targets = _pack_sorted(targets, edof * edof,
+                                                       size)
 
     return AssemblySchedule(
         structure_digest=structure_digest(conn, d, num_nodes),
-        n=n, k=k, ne=ne, edof=edof, ndof_per_node=d, num_buffers=B,
+        n=n, k=k, ne=ne, edof=edof, ndof_per_node=d,
+        num_buffers=max(1, min(num_buffers, ne)),
         ia=ia, ja=ja, targets=targets, coloring=coloring,
-        buffer_elements=buffer_elements,
         color_slots=color_slots, color_targets=color_targets,
         sorted_perm=sorted_perm, sorted_targets=sorted_targets)
 
@@ -404,6 +421,11 @@ def assembly_schedule_for(mesh_or_conn, ndof_per_node: int = 1,
 # Accumulation strategies
 # ---------------------------------------------------------------------------
 
+def _rows(sched: AssemblySchedule, ke) -> jnp.ndarray:
+    """Element blocks as (ne, edof²) float32 rows."""
+    return jnp.asarray(ke, jnp.float32).reshape(sched.ne, -1)
+
+
 def scatter_colored_percolor(sched: AssemblySchedule, ke) -> jnp.ndarray:
     """The legacy per-color discipline: one XLA ``.at[].add`` scatter per
     color class, serialized — C dispatches per refresh.  Kept as the
@@ -435,9 +457,8 @@ def scatter_colored(sched: AssemblySchedule, ke, variant: str = "stream",
     if variant == "percolor":
         return scatter_colored_percolor(sched, ke)
     return akern.colored_scatter(
-        sched.color_slots, sched.color_targets,
-        jnp.asarray(ke, jnp.float32), sched.size,
-        variant=variant, interpret=interpret)
+        sched.color_slots, sched.color_targets, _rows(sched, ke),
+        sched.size, variant=variant, interpret=interpret)
 
 
 def scatter_sorted(sched: AssemblySchedule, ke) -> jnp.ndarray:
@@ -446,30 +467,19 @@ def scatter_sorted(sched: AssemblySchedule, ke) -> jnp.ndarray:
     is one color-free gather + monotone segment-sum — a single fused
     launch with no palette term.  jit-compatible."""
     return akern.sorted_scatter(
-        sched.sorted_perm, sched.sorted_targets,
-        jnp.asarray(ke, jnp.float32), sched.size)
+        sched.sorted_perm, sched.sorted_targets, _rows(sched, ke),
+        sched.size)
 
 
 def scatter_private(sched: AssemblySchedule, ke) -> jnp.ndarray:
-    """Private-buffer accumulation: each buffer scatter-adds its element
-    chunk into its own full-length partial (duplicates within a buffer are
-    fine — the buffer is private), then the partials are reduced — the
-    paper's local-buffers / all-in-one strategy (§3.1) as a vmap +
-    tree-sum.  Padded slots target a dump entry past the vector end."""
-    kflat = jnp.asarray(ke, jnp.float32).reshape(sched.ne, -1)
-    t2 = jnp.asarray(sched.targets.reshape(sched.ne, -1))
-    be = jnp.asarray(sched.buffer_elements)             # (B, epb)
-    valid = (be >= 0)[..., None]
-    el = jnp.maximum(be, 0)
-    v3 = jnp.where(valid, kflat[el], 0.0)               # (B, epb, edof²)
-    t3 = jnp.where(valid, t2[el], sched.size)           # dump slot
-
-    def one_buffer(tg, vv):
-        return jnp.zeros(sched.size + 1, jnp.float32).at[
-            tg.reshape(-1)].add(vv.reshape(-1))
-
-    partials = jax.vmap(one_buffer)(t3, v3)             # (B, size+1)
-    return partials.sum(axis=0)[:sched.size]
+    """Private-buffer accumulation: each buffer scatter-adds its
+    contiguous chunk of elements into its own full-length partial
+    (duplicates within a buffer are fine — the buffer is private), then
+    the partials are reduced — the paper's local-buffers / all-in-one
+    strategy (§3.1), as one segment-sum over (buffer, target) pairs and a
+    sum over buffers."""
+    return akern.private_scatter(sched.targets, _rows(sched, ke),
+                                 sched.num_buffers, sched.size)
 
 
 def scatter_serial(sched: AssemblySchedule, ke) -> np.ndarray:
@@ -494,14 +504,14 @@ def values_to_csrc(sched: AssemblySchedule, vals) -> csrc.CSRC:
 
 
 def assemble(sched: AssemblySchedule, ke, strategy: str = "colored",
-             variant: Optional[str] = None,
-             interpret=None) -> csrc.CSRC:
+             variant: Optional[str] = None) -> csrc.CSRC:
     """Assemble the global CSRC matrix from per-element dense blocks
     ``ke`` of shape (ne, edof, edof) with the chosen accumulation
     strategy.
 
-    This IS the value-refresh fast path: every call reuses the
-    schedule's precomputed packs (zero structural work — the
+    This IS the value-refresh fast path: every call runs the strategy's
+    jitted executor, whose packs go to the device on the schedule's first
+    call and stay there (zero structural work — the
     ``BUILD_COUNTS['assembly_value_refresh']`` probe counts exactly one
     refresh per call and nothing else moves), runs under an obs span,
     and lands its wall time in ``assembly_scatter_seconds{strategy,
@@ -513,15 +523,14 @@ def assemble(sched: AssemblySchedule, ke, strategy: str = "colored",
     with obs.span("assembly.value_refresh", strategy=strategy,
                   variant=variant):
         with obs.span("assembly.scatter"):
-            if strategy == "colored":
-                vals = scatter_colored(sched, ke, variant=variant,
-                                       interpret=interpret)
-            elif strategy == "sorted":
-                vals = scatter_sorted(sched, ke)
-            elif strategy == "private":
-                vals = scatter_private(sched, ke)
-            else:
+            if strategy == "serial":
                 vals = scatter_serial(sched, ke)
+            else:
+                key = (strategy, variant)
+                if key not in sched._executors:
+                    sched._executors[key] = _scatter_fn(sched, strategy,
+                                                        variant)
+                vals = sched._executors[key](jnp.asarray(ke, jnp.float32))
             # wait here, so the scatter's device work stays in its span
             # and ``assembly.to_csrc`` times the round trip alone
             vals = jax.block_until_ready(vals)
@@ -577,6 +586,20 @@ def _scatter_fn(sched: AssemblySchedule, strategy: str, variant: str):
     raise ValueError(f"no tunable executor for strategy {strategy!r}")
 
 
+def assembly_candidates(sched: AssemblySchedule) -> list:
+    """``ASSEMBLY_CANDIDATES`` less those this schedule's sizes rule out:
+    ``colored/onehot`` when its grid passes ``ONEHOT_MAX_PROGRAMS``, and
+    ``private/vmap`` when its buffers' segment ids (buffers × size) pass
+    int32."""
+    refused = set()
+    if (akern.onehot_programs(int(sched.color_slots.size), sched.size)
+            > ONEHOT_MAX_PROGRAMS):
+        refused.add(("colored", "onehot"))
+    if sched.num_buffers * sched.size > np.iinfo(np.int32).max:
+        refused.add(("private", "vmap"))
+    return [sv for sv in ASSEMBLY_CANDIDATES if sv not in refused]
+
+
 def _time_scatter(fn, kej, warmup: int = 2, repeats: int = 5) -> float:
     for _ in range(warmup):
         jax.block_until_ready(fn(kej))
@@ -592,7 +615,8 @@ def tune_assembly(sched: AssemblySchedule, ke, cache=None,
                   measure=None, repeats: int = 5,
                   force: bool = False) -> AssemblyTuneResult:
     """Pick the assembly (strategy, variant) for this schedule: price the
-    whole candidate pool with the roofline model, measure the cheapest
+    candidates its sizes allow (:func:`assembly_candidates`) with the
+    roofline model, measure the cheapest
     half (plus each strategy's best-predicted variant, so no family is
     pruned unseen), argmin, and record predicted-vs-measured provenance.
 
@@ -613,8 +637,10 @@ def tune_assembly(sched: AssemblySchedule, ke, cache=None,
                 roofline_fraction=dict(hit.get("roofline_fraction", {})),
                 cached=True)
 
-    priced = cost_model.rank_assembly_candidates(sched,
-                                                 ASSEMBLY_CANDIDATES)
+    pool = assembly_candidates(sched)
+    obs.counter("assembly_tuner_candidates_total",
+                outcome="refused").inc(len(ASSEMBLY_CANDIDATES) - len(pool))
+    priced = cost_model.rank_assembly_candidates(sched, pool)
     predictions = {f"{s}/{v}": est.predicted_s for (s, v), est in priced}
     ests = {f"{s}/{v}": est for (s, v), est in priced}
     obs.counter("assembly_tuner_candidates_total",

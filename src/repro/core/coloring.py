@@ -377,9 +377,10 @@ def color_graph(adj: list, include_indirect: bool = False,
     """Coloring of an arbitrary conflict graph given as adjacency lists.
 
     This is the machinery behind :func:`color_rows` factored over the
-    graph instead of the matrix, so other conflict graphs — notably the
-    FEM *element* conflict graph of ``repro.assembly.conflict`` — reuse
-    the identical pipeline.
+    graph instead of the matrix, so other conflict graphs reuse it: the
+    ``race`` provider of ``repro.assembly.conflict`` colors the FEM
+    *element* conflict graph with it (its ``greedy`` provider colors from
+    the node–element incidence without building the graph).
 
     ``provider``: 'greedy' (sequential first-fit, the default) or 'race'
     (the recursive level-group scheme, :func:`race_color_graph`).

@@ -74,6 +74,11 @@ class Span:
         self.duration_s = None
         self._live = False
 
+    def set_labels(self, **labels):
+        """Add labels known only inside the block (a count, a size); the
+        record keeps them, the profiler event has those given at entry."""
+        self.labels.update({k: str(v) for k, v in labels.items()})
+
     def __enter__(self) -> "Span":
         if not STATE.enabled:
             return self

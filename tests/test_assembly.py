@@ -120,11 +120,134 @@ def test_element_coloring_conflict_free(name, make):
 
 
 def test_element_coloring_balancing_preserves_invariant():
+    """Balancing adds no color and does not enlarge the largest class."""
     mesh = amesh.grid_tri(6)
     raw = color_elements(mesh.conn, balance=False)
     bal = color_elements(mesh.conn, balance=True)
     assert bal.num_colors <= raw.num_colors
+    assert bal.class_sizes().max() <= raw.class_sizes().max()
     assert verify_element_coloring(mesh.conn, bal)
+
+
+# Kuhn meshes from 2^3 to 16^3, a box of unequal sides, and every other
+# generator of assembly/mesh.py
+COLORING_MESHES = [
+    ("tet2", lambda: amesh.grid_tet(2)),
+    ("tet4", lambda: amesh.grid_tet(4)),
+    ("tet8", lambda: amesh.grid_tet(8)),
+    ("tet16", lambda: amesh.grid_tet(16)),
+    ("tet3x5x2", lambda: amesh.grid_tet(3, 5, 2)),
+    ("tri", lambda: amesh.grid_tri(7)),
+    ("quad", lambda: amesh.grid_quad(6)),
+]
+COLORING_IDS = [n for n, _ in COLORING_MESHES]
+
+
+def _oracle_verify(conn, col):
+    """The plain per-element loop the vectorised verifier replaced: within
+    a color, a node may be touched by one serial chunk only."""
+    grp = col.group_of_row
+    for c in range(col.num_colors):
+        owner = {}
+        for e in col.rows(c).tolist():
+            g = int(grp[e]) if grp is not None else e
+            for v in conn[e].tolist():
+                if owner.setdefault(v, g) != g:
+                    return False
+    return True
+
+
+def _old_greedy_colors(conn):
+    """The palette of the element coloring before the vectorised one: the
+    row colorer's greedy over the explicit element graph,
+    largest-degree-first guarded by natural order, then balanced."""
+    from repro.assembly.conflict import element_adjacency
+    from repro.core.coloring import color_graph
+    return color_graph(element_adjacency(conn), include_indirect=False,
+                       order="degree", balance=True).num_colors
+
+
+@pytest.mark.parametrize("name,make", COLORING_MESHES, ids=COLORING_IDS)
+def test_vectorised_coloring_valid_and_deterministic(name, make):
+    mesh = make()
+    a = color_elements(mesh.conn)
+    b = color_elements(mesh.conn)
+    np.testing.assert_array_equal(a.color_of_row, b.color_of_row)
+    np.testing.assert_array_equal(a.rows_by_color, b.rows_by_color)
+    assert _oracle_verify(mesh.conn, a)
+    assert verify_element_coloring(mesh.conn, a)
+    assert sorted(a.rows_by_color.tolist()) == list(range(mesh.ne))
+    assert (a.class_sizes() > 0).all()
+
+
+@pytest.mark.parametrize("name,make", COLORING_MESHES, ids=COLORING_IDS)
+def test_vectorised_palette_within_old_greedy(name, make):
+    mesh = make()
+    new = color_elements(mesh.conn).num_colors
+    assert new <= 1.25 * _old_greedy_colors(mesh.conn), name
+
+
+@pytest.mark.parametrize("ordered_rounds", [0, 2, 4096])
+def test_random_priority_rounds_color_any_connectivity(ordered_rounds,
+                                                       monkeypatch):
+    """Rounds past ``ORDERED_MAX_ROUNDS`` take seeded random priorities;
+    on an irregular connectivity (nodes of any degree, one element
+    listing a node twice) the result is valid and the same on every
+    call."""
+    from repro.assembly import conflict
+    monkeypatch.setattr(conflict, "ORDERED_MAX_ROUNDS", ordered_rounds)
+    rng = np.random.default_rng(7)
+    conn = rng.integers(0, 40, (300, 3)).astype(np.int32)
+    conn[5] = [9, 9, 11]
+    a = color_elements(conn)
+    b = color_elements(conn)
+    np.testing.assert_array_equal(a.color_of_row, b.color_of_row)
+    assert _oracle_verify(conn, a)
+    assert verify_element_coloring(conn, a)
+
+
+def test_vectorised_coloring_needs_more_than_one_mask_word():
+    """More than 64 elements at one node: the per-node masks grow a word,
+    and the palette passes 64."""
+    conn = np.stack([np.zeros(70, np.int32),
+                     np.arange(1, 71, dtype=np.int32)], axis=1)
+    col = color_elements(conn)
+    assert col.num_colors == 70
+    assert _oracle_verify(conn, col)
+
+
+def _moved(col, e, c):
+    """``col`` with element e put in class c (structure rebuilt)."""
+    from repro.core.coloring import Coloring
+    color = col.color_of_row.copy()
+    color[e] = c
+    order = np.argsort(color, kind="stable")
+    ptr = np.concatenate([[0], np.cumsum(np.bincount(
+        color, minlength=col.num_colors))])
+    return Coloring(color_of_row=color, num_colors=col.num_colors,
+                    rows_by_color=order, color_ptr=ptr,
+                    provider=col.provider, level_of_row=col.level_of_row,
+                    group_of_row=col.group_of_row)
+
+
+@pytest.mark.parametrize("provider", ["greedy", "race"])
+def test_verifier_agrees_with_the_oracle_loop(provider):
+    mesh = amesh.grid_tet(3)
+    col = color_elements(mesh.conn, provider=provider)
+    assert verify_element_coloring(mesh.conn, col)
+    assert _oracle_verify(mesh.conn, col)
+    # break it: element 0 joins the class of an element sharing its node,
+    # in another serial chunk
+    e = 0
+    grp = (col.group_of_row if col.group_of_row is not None
+           else np.arange(mesh.ne))
+    f = next(f for f in range(1, mesh.ne)
+             if set(mesh.conn[f].tolist()) & set(mesh.conn[e].tolist())
+             and grp[f] != grp[e]
+             and col.color_of_row[f] != col.color_of_row[e])
+    broken = _moved(col, e, int(col.color_of_row[f]))
+    assert not _oracle_verify(mesh.conn, broken)
+    assert not verify_element_coloring(mesh.conn, broken)
 
 
 # ---------------------------------------------------------------------------
@@ -236,8 +359,8 @@ def test_assembly_schedule_npz_roundtrip_through_disk_cache(tmp_path):
     cache2 = tuner.PlanCache(path=path)            # "new process"
     s2, d = _build_delta(lambda: assembly_schedule_for(mesh, cache=cache2))
     assert d == {}, f"disk hit rebuilt: {d}"
-    for f in ("ia", "ja", "targets", "buffer_elements", "color_slots",
-              "color_targets", "sorted_perm", "sorted_targets"):
+    for f in ("ia", "ja", "targets", "color_slots", "color_targets",
+              "sorted_perm", "sorted_targets"):
         np.testing.assert_array_equal(getattr(s1, f), getattr(s2, f))
         assert getattr(s1, f).dtype == getattr(s2, f).dtype, f
     np.testing.assert_array_equal(csrc.to_dense(assemble(s1, ke)),

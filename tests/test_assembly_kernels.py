@@ -78,6 +78,22 @@ def test_every_executor_bit_identical(name, make, strategy, variant):
     np.testing.assert_array_equal(got, ref)
 
 
+@pytest.mark.parametrize("strategy,variant", COMBOS, ids=COMBO_IDS)
+@pytest.mark.parametrize("ndof", [1, 3])
+@pytest.mark.parametrize("nx", [2, 3])
+def test_every_executor_bit_identical_on_kuhn_meshes(nx, ndof, strategy,
+                                                      variant):
+    """Every executor, fed the vectorised element coloring of a Kuhn mesh
+    with scalar and elasticity DOFs, matches the serial oracle."""
+    mesh = amesh.grid_tet(nx, 2, 2)
+    ke = amesh.synthetic_stiffness(mesh, ndof_per_node=ndof, seed=11)
+    sched = build_assembly_schedule(mesh, ndof_per_node=ndof)
+    assert sched.coloring.provider == "greedy"
+    np.testing.assert_array_equal(
+        np.asarray(_scatter(sched, ke, strategy, variant)),
+        scatter_serial(sched, ke))
+
+
 @pytest.mark.parametrize("variant", ["onehot"])
 @pytest.mark.parametrize("name,make", [MESHES[0], MESHES[2]],
                          ids=["tri", "tet"])
@@ -279,6 +295,36 @@ def test_assembly_cost_prices_every_candidate():
             > by_key["colored/stream"].predicted_s)
     # sorted-slot streams the fewest bytes — no pack padding at all
     assert by_key["sorted/stream"].bytes <= by_key["colored/stream"].bytes
+
+
+def test_onehot_refused_past_its_grid_gate(monkeypatch):
+    """``colored/onehot`` leaves the pool when its (tile, chunk) grid
+    passes ONEHOT_MAX_PROGRAMS, decided by the schedule's sizes alone."""
+    from repro.assembly import scatter as scatter_mod
+    sched = build_assembly_schedule(amesh.grid_tet(2))
+    programs = akern.onehot_programs(sched.color_slots.size, sched.size)
+    assert scatter_mod.assembly_candidates(sched) == list(
+        ASSEMBLY_CANDIDATES)
+    monkeypatch.setattr(scatter_mod, "ONEHOT_MAX_PROGRAMS", programs - 1)
+    pool = scatter_mod.assembly_candidates(sched)
+    assert ("colored", "onehot") not in pool
+    assert len(pool) == len(ASSEMBLY_CANDIDATES) - 1
+    d0 = obs.snapshot()
+    res = tune_assembly(sched, amesh.synthetic_stiffness(
+        amesh.grid_tet(2), seed=1), measure=lambda fn, kej: 1.0)
+    assert "colored/onehot" not in res.predictions_s
+    d = obs.snapshot().diff(d0)
+    assert d.value("assembly_tuner_candidates_total",
+                   outcome="refused") == 1
+
+
+def test_onehot_programs_is_the_kernels_grid():
+    sched = build_assembly_schedule(amesh.grid_tri(5))
+    slots = sched.color_slots.size
+    chunk = min(akern.ONEHOT_CHUNK, -(-slots // 128) * 128)
+    tiles = -(-(sched.size + 1) // akern.ONEHOT_TILE)
+    assert akern.onehot_programs(slots, sched.size) == (
+        tiles * -(-slots // chunk))
 
 
 def test_tune_assembly_picks_injected_winner_and_caches(tmp_path):

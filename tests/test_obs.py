@@ -698,3 +698,27 @@ def test_repro_metrics_env_prints_prometheus(tmp_path):
     assert out.returncode == 0, out.stderr
     assert 'smoke_total{job="env"} 2' in out.stdout
     assert "# TYPE smoke_total counter" in out.stdout
+
+
+def test_element_coloring_spans_and_rounds_counter():
+    """The greedy element coloring opens its span with one child a phase,
+    labels it with the palette and the rounds, and counts the rounds."""
+    from repro.assembly import color_elements, mesh as amesh
+    obs.clear_trace()
+    s0 = obs.snapshot()
+    col = color_elements(amesh.grid_tet(3).conn)
+    d = obs.snapshot().diff(s0)
+    recs = {r["name"]: r for r in obs.trace()}
+    top = recs["assembly.element_coloring"]
+    assert top["labels"]["provider"] == "greedy"
+    assert top["labels"]["num_colors"] == str(col.num_colors)
+    rounds = int(top["labels"]["rounds"])
+    assert rounds > 0
+    for child in ("incidence", "rounds", "balance"):
+        r = recs[f"assembly.element_coloring.{child}"]
+        assert r["parent_id"] == top["id"]
+    assert recs["assembly.element_coloring.rounds"]["labels"]["rounds"] \
+        == str(rounds)
+    assert d.value("element_coloring_rounds_total",
+                   provider="greedy") == rounds
+    assert top["counts"] == {"element_coloring_rounds_total": rounds}

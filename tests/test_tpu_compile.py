@@ -4,9 +4,11 @@ The TPU compiler is installed with jax, so a described (not attached)
 ``v5e:2x2`` topology lets every one-hot kernel be lowered and compiled
 ahead of time at the widths ``chip_smoke.py`` runs: fem_band(2**20, 16)
 (n = 1 048 576 rows, bandwidth 16, ~10 k-step slots per row) for the SpMV
-and SpMM kernels, and grid_tet(48) for the assembly grid.  Interpret-mode
-tests cannot see what this catches: block shapes that break the 8×128
-tiling rule, VMEM over-subscription, ops Mosaic cannot lower.  The
+and SpMM kernels, and grid_tet(48) for the assembly grid; the assembly
+executors the tuner measures are compiled at fem_tet113's 8.66 M tets.
+Interpret-mode tests cannot see what this catches: block shapes that
+break the 8×128 tiling rule, VMEM over-subscription, ops Mosaic cannot
+lower, programs over a chip's memory.  The
 row-padded ``ell`` product, plain XLA, is compiled at the hpcg27 cell's
 104³ rows, the main path of both benchmark cells, and so is its halo
 shard function on a one-wide mesh at the hpcg27x4 cell's slab.
@@ -40,6 +42,8 @@ N = 2 ** 20                 # chip_smoke phase A/B rows
 SERVE_NRHS = 8              # SpmvServingEngine.serve_nrhs default
 TET_SIZE = 1_707_697        # grid_tet(48): n + 2k of the unified vector
 TET_CONTRIBS = 10_616_832   # grid_tet(48): ne * edof^2
+FEM113_NE = 6 * 113 ** 3    # fem_tet113: 8,657,382 tets
+FEM113_SIZE = 21_912_622    # its n + 2k
 HPCG_ROWS = 104 ** 3        # the hpcg27 cell's 27-point stencil
 HPCG_WIDTH = 13             # its most lower slots of a row
 HPCG_HALO = 10_928          # hpcg27x4's band 10,921, 8-aligned
@@ -208,3 +212,32 @@ def test_assembly_onehot_grid_compiles(shape):
                          shape((colors, lmax), jnp.int32),
                          shape((TET_CONTRIBS // 16, 4, 4), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("strategy", ["colored", "sorted", "private"])
+def test_assembly_executors_fit_one_chip_at_113(shape, strategy):
+    """Each executor the assembly tuner measures at StocF-1465's size
+    compiles for one v5e and leaves room beside the step runner's ring of
+    4 element-matrix sets, which set-up holds twice: the contributions are
+    read component-major, never through an (ne, 4, 4) copy whose minor
+    axis pads to 128 lanes (17.7 GB)."""
+    ne, size = FEM113_NE, FEM113_SIZE
+    g = ne * 16
+    colors = 28
+    lmax = -(-(-(-ne // colors)) * 16 // 128) * 128
+    ke = shape((ne, 4, 4), jnp.float32)
+    i32 = jnp.int32
+    fn, args = {
+        "colored": (lambda sl, t, k: akern.colored_scatter_fused(
+            sl, t, k, size), (shape((colors, lmax), i32),
+                              shape((colors, lmax), i32), ke)),
+        "sorted": (lambda p, t, k: akern.sorted_scatter(p, t, k, size),
+                   (shape((g,), i32), shape((g,), i32), ke)),
+        "private": (lambda t, k: akern.private_scatter(t, k, 8, size),
+                    (shape((g,), i32), ke)),
+    }[strategy]
+    m = jax.jit(fn).lower(*args).compile().memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+    ring_held_twice = 2 * 4 * g * 4
+    assert used + ring_held_twice < 16e9, used
